@@ -25,9 +25,10 @@ _STAGE_MODULES = [
 import importlib as _importlib
 
 for _m in _STAGE_MODULES:
-    try:
-        _importlib.import_module(_m)
-    except ModuleNotFoundError as _e:
-        # tolerate partially-built trees during bring-up only
-        if not str(_e).startswith("No module named 'mmlspark_tpu"):
-            raise
+    _importlib.import_module(_m)
+
+# one persistent compile cache for every entry point (fit, transform,
+# serving): a config write, no backend is initialized by importing
+from .parallel.distributed import configure_compile_cache as _cfg_cache
+
+_cfg_cache()

@@ -27,10 +27,13 @@ Load-time integrity is graded, not all-or-nothing:
 * torn/missing **model or meta** shard -> the bundle is unusable;
   :func:`load_bundle` raises (there is nothing to serve);
 * torn/missing **executable** shard (or an injected
-  ``serving.bundle_load`` fault, or a backend/jax-version mismatch) ->
-  that bucket falls back to a cold compile, counted on
+  ``serving.bundle_load`` fault, or a jax-version / device-placement
+  mismatch) -> that bucket falls back to a cold compile, counted on
   ``mmlspark_serving_bundle_exec_failures_total`` — degraded warmth,
-  never a wrong answer.
+  never a wrong answer;
+* a bundle built for another **backend** (a ``tpu`` bundle where this
+  process runs ``cpu``) -> :func:`load_bundle` raises: serving a chip's
+  model from whatever backend came up instead is not degraded warmth.
 """
 
 from __future__ import annotations
@@ -94,7 +97,7 @@ def save_bundle(directory: str, step: FusedServingStep,
         "kind": kind,
         "backend": jax.default_backend(),
         "jax": jax.__version__,
-        "device_count": jax.device_count(),
+        "exec_devices": [d.id for d in step.devices()],
         "model_config": step.model_config,
         "row_shape": list(step.row_shape),
         "in_dtype": step.in_dtype.name,
@@ -160,7 +163,8 @@ def load_bundle(directory: str, policy: Optional[BucketPolicy] = None,
     :class:`~...resilience.ckpt.CorruptCheckpoint` when the model/meta
     shards are torn — both counted. Torn *executable* shards degrade to
     cold compiles for their buckets (counted), never an error: a worker
-    with intact weights must come up even if warmth was lost.
+    with intact weights must come up even if warmth was lost. A bundle
+    built for a different backend raises ``RuntimeError``.
     """
     import jax
     from flax import serialization
@@ -188,6 +192,11 @@ def load_bundle(directory: str, policy: Optional[BucketPolicy] = None,
         raise ckpt.CorruptCheckpoint(
             f"serving bundle in {directory} has a torn meta shard")
     meta = json.loads(meta_blob.decode("utf-8"))
+    if meta.get("backend") != jax.default_backend():
+        raise RuntimeError(
+            f"serving bundle in {directory} was built for backend "
+            f"{meta.get('backend')!r}; this process runs "
+            f"{jax.default_backend()!r}")
     kind = meta.get("kind", "model")
     model_blob = _read_shard(
         directory,
@@ -215,10 +224,13 @@ def load_bundle(directory: str, policy: Optional[BucketPolicy] = None,
                                 row_shape=tuple(meta["row_shape"]),
                                 in_dtype=np.dtype(meta["in_dtype"]),
                                 output=meta["output"], **step_kwargs)
-    compatible = (meta.get("backend") == jax.default_backend()
-                  and meta.get("jax") == jax.__version__
-                  and int(meta.get("device_count", 0))
-                  == jax.device_count())
+    # a serialized executable reloads onto the devices it was compiled
+    # for (deserialize_and_load would otherwise spread a one-device
+    # program over every device of the backend)
+    devices = step.devices()
+    device_ids = [d.id for d in devices]
+    compatible = (meta.get("jax") == jax.__version__
+                  and meta.get("exec_devices") == device_ids)
     loaded = 0
     with telemetry.trace.span("serving/bundle_load",
                               buckets=len(policy.buckets)):
@@ -233,18 +245,17 @@ def load_bundle(directory: str, policy: Optional[BucketPolicy] = None,
                 faults.inject("serving.bundle_load")
                 if not compatible:
                     raise RuntimeError(
-                        f"bundle built for backend={meta.get('backend')} "
-                        f"jax={meta.get('jax')} x"
-                        f"{meta.get('device_count')} devices; this "
-                        f"process runs {jax.default_backend()} "
-                        f"jax={jax.__version__}")
+                        f"bundle built with jax={meta.get('jax')} for "
+                        f"devices {meta.get('exec_devices')}; this "
+                        f"process runs jax={jax.__version__} on devices "
+                        f"{device_ids}")
                 blob = _read_shard(directory, _exec_shard(b))
                 if blob is None:
                     raise RuntimeError(f"executable shard for bucket {b} "
                                        f"torn or missing")
                 ser, in_tree, out_tree = pickle.loads(blob)
                 compiled = serialize_executable.deserialize_and_load(
-                    ser, in_tree, out_tree)
+                    ser, in_tree, out_tree, execution_devices=devices)
                 step.preload_bucket(b, compiled)
                 loaded += 1
                 _m_execs_loaded.inc()

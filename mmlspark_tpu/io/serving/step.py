@@ -163,6 +163,13 @@ class FusedServingStep:
         return step
 
     # ---- warmup / bundle surface ----
+    def devices(self) -> list:
+        """The devices every bucket executable runs on (where the params
+        live), in id order — what a bundle records and reloads onto."""
+        import jax
+        leaf = jax.tree_util.tree_leaves(self._params_dev)[0]
+        return sorted(leaf.devices(), key=lambda d: d.id)
+
     def bucket_spec(self, bucket: int):
         import jax
         return jax.ShapeDtypeStruct((bucket,) + self.row_shape,

@@ -298,7 +298,6 @@ def make_sharded_builder_lw(mesh, *, num_leaves, n_bins, lambda_l2,
     ring, TrainUtils.scala:141, as ICI collectives)."""
     from jax.sharding import PartitionSpec as P
 
-    from ...parallel.compat import shard_map
 
     def body(bins, g, h, rm, fm, cat):
         from .engine import _stack_class_axis
@@ -315,7 +314,7 @@ def make_sharded_builder_lw(mesh, *, num_leaves, n_bins, lambda_l2,
         return _stack_class_axis([one(g[:, k], h[:, k])
                                   for k in range(g.shape[1])])
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis_name, None), P(axis_name, None), P(axis_name, None),
                   P(axis_name), P(None), P(None)),
@@ -323,7 +322,7 @@ def make_sharded_builder_lw(mesh, *, num_leaves, n_bins, lambda_l2,
         # like the rows it describes
         out_specs=(P(None), P(None), P(None), P(None), P(None), P(None),
                    P(None, axis_name)),
-        check=False)
+        check_vma=False)
     return jax.jit(fn)
 
 

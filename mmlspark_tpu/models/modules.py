@@ -365,8 +365,8 @@ class TransformerEncoder(nn.Module):
 
     TPU sizing note: pick ``d_model/heads`` (head_dim) = 128 where model
     quality allows — the MXU contracts 128-deep, so head_dim 64 runs the
-    attention matmuls at roughly half rate (measured: BASELINE.md round-4
-    flash-attention row; the deficit is structural, not a kernel issue).
+    attention matmuls at roughly half rate (the deficit is structural,
+    not a kernel issue).
 
     Input: int32 token ids (B, T). Output: (B, num_classes) when
     ``pool='mean'``, else per-token (B, T, num_classes).
@@ -397,10 +397,10 @@ class TransformerEncoder(nn.Module):
             return self.attn_fn(q, k, v)
         impl = self.attn_impl
         if impl == "auto":
-            # measured on v5e (T=4096): flash 39-58 TF/s vs blockwise 12.7 —
-            # the Pallas kernel wins whenever a real TPU is attached
-            impl = ("flash" if jax.default_backend() == "tpu"
-                    else "blockwise")
+            # the Pallas kernel on tpu; blockwise on the cpu test backend
+            # (interpret-mode Pallas is a correctness tool, not a path)
+            from ..parallel.mesh import on_tpu
+            impl = "flash" if on_tpu() else "blockwise"
         if impl == "flash":
             from ..ops.pallas_kernels import flash_attention
             return flash_attention(q, k, v, causal=self.causal)

@@ -201,8 +201,11 @@ class TpuModel(Transformer):
         cur = (tuple(sorted((k, str(v)) for k, v in self.getModelConfig().items())),
                self.getOutputLayer(), tp)
         if key != cur or not hasattr(self, "_apply_jit"):
+            from ..parallel.sequence import batch_parallel_flash
             from .modules import build_model
-            module = build_model(self.getModelConfig())
+            cfg = self.getModelConfig()
+            module = build_model(cfg, attn_fn=batch_parallel_flash(
+                self._cached_mesh(), cfg))
             ol = self.getOutputLayer() or None
             kw = {}
             if tp > 1:

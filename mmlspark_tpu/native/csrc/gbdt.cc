@@ -4,9 +4,9 @@
 // this kernel walks row-major memory once with a branchless lower_bound
 // per cell (the per-feature edge tables are a few KB and stay in L1/L2)
 // and threads over row ranges — single-core 5.9x the numpy loop at
-// 10M x 28 (46.8 s -> 8.0 s, BASELINE.md), and it
-// scales with cores on real TPU-VM hosts where the ingest binning is the
-// 10M-row fit's largest fixed cost (BASELINE.md).
+// 10M x 28 (46.8 s -> 8.0 s, a host measurement from rounds 1-5), and it
+// scales with cores on TPU-VM hosts where the ingest binning is the
+// 10M-row fit's largest fixed cost.
 //
 // Semantics are bit-identical to engine.bin_data: bin = count of edges
 // strictly less than x (searchsorted side='left'), NaN -> bin 0,

@@ -24,8 +24,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .compat import shard_map
-
 
 def stack_stage_params(stage_params: list):
     """Stack per-stage pytrees (identical treedefs) along a new leading axis —
@@ -97,9 +95,9 @@ def pipeline_apply(stage_fn: Callable, stacked_params, x, mesh: Mesh,
         outbuf = jnp.where(s_idx == last, outbuf, jnp.zeros_like(outbuf))
         return lax.psum(outbuf, axis_name)
 
-    out = shard_map(local, mesh=mesh,
-                    in_specs=(p_spec, x_spec), out_specs=out_spec,
-                    check=False)(stacked_params, x_mb)
+    out = jax.shard_map(local, mesh=mesh,
+                        in_specs=(p_spec, x_spec), out_specs=out_spec,
+                        check_vma=False)(stacked_params, x_mb)
     return out.reshape(N, *out.shape[2:])
 
 

@@ -39,8 +39,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .compat import axis_size, shard_map
-
 NEG_INF = -1e30
 
 
@@ -144,7 +142,7 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = False,
     LightGBM socket ring TrainUtils.scala:141 and the MPI ring
     CommandBuilders.scala:241, both CPU-side; here the ring IS the compute).
     """
-    sp = axis_size(axis_name)
+    sp = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
@@ -191,7 +189,7 @@ def ulysses_attention(q, k, v, axis_name: str, causal: bool = False,
     two ``lax.all_to_all`` re-shard to (B, T, H/sp, D) — full sequence,
     head-sharded — where dense local attention runs, then back. Requires
     H % sp == 0."""
-    sp = axis_size(axis_name)
+    sp = lax.axis_size(axis_name)
     H = q.shape[2]
     if H % sp != 0:
         raise ValueError(f"ulysses needs heads ({H}) divisible by sp ({sp})")
@@ -236,8 +234,30 @@ def make_sp_attention(mesh: Mesh, axis_name: str = "seq",
         raise ValueError(f"unknown sp mode {mode!r} (ring|ulysses)")
 
     def attn(q, k, v):
-        return shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec, check=False)(q, k, v)
+        return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                             out_specs=spec, check_vma=False)(q, k, v)
+    return attn
+
+
+def batch_parallel_flash(mesh: Mesh, cfg: dict, batch_axis: str = "data"):
+    """The attention callable a transformer config needs to run the Pallas
+    flash kernel under a multi-device mesh: GSPMD cannot partition a Mosaic
+    call ("wrap the call in a shard_map"), so the kernel runs per batch
+    shard. None where the module's own choice already works: a one-device
+    mesh, the cpu backend (blockwise is plain XLA) or an explicit
+    ``attn_impl='blockwise'``."""
+    from .mesh import on_tpu
+    if (cfg.get("type") != "transformer" or mesh.size == 1
+            or cfg.get("attn_impl", "auto") == "blockwise" or not on_tpu()):
+        return None
+    from ..ops.pallas_kernels import flash_attention
+    spec = P(batch_axis, None, None, None)
+    local = functools.partial(flash_attention,
+                              causal=cfg.get("causal", False))
+
+    def attn(q, k, v):
+        return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                             out_specs=spec, check_vma=False)(q, k, v)
     return attn
 
 

@@ -10,7 +10,7 @@ delta), 2 — usage error. ``--format json`` emits the full
     python bench.py --all > run.json && mmlspark-tpu-perf --check run.json
 
     # re-validate a committed round against the rounds before it
-    mmlspark-tpu-perf --check BENCH_r05.json
+    mmlspark-tpu-perf --check BENCH_rNN.json
 """
 
 from __future__ import annotations

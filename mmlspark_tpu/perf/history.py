@@ -123,7 +123,7 @@ def load_history(directory: str,
                  exclude: Optional[str] = None) -> list:
     """Every parseable round record in ``directory``, oldest first
     (by round number, then filename). ``exclude`` drops one file by
-    path — checking ``BENCH_r05.json`` must not compare it against
+    path — checking ``BENCH_r05.json`` (say) must not compare it against
     itself."""
     out = []
     skip = os.path.abspath(exclude) if exclude else None

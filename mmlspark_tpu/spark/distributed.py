@@ -111,7 +111,6 @@ class BarrierFitTask:
 
         from ..parallel import distributed as dist
         if n > 1:
-            dist.configure_xla_cache()
             try:
                 dist.initialize(coordinator_address=coordinator,
                                 num_processes=n, process_id=pid)

@@ -78,9 +78,9 @@ _live_peak = 0.0
 _peak_flops_override: Optional[float] = None
 _functions: dict = {}      # tag -> ProfiledFunction (for report())
 
-#: rough bf16 peak FLOP/s by TPU device kind (public spec numbers);
-#: roofline utilization is attribution, not a benchmark — an unknown kind
-#: falls back to a CPU-class estimate so the gauge stays meaningful.
+#: bf16 peak FLOP/s by TPU ``device_kind`` prefix (public spec numbers).
+#: libtpu 0.0.34 reports a v5e chip as "TPU v5 lite". A kind that is not
+#: here is an error on an accelerator backend, not a default.
 _PEAK_BY_KIND = {
     "TPU v2": 45e12, "TPU v3": 123e12, "TPU v4": 275e12,
     "TPU v5 lite": 197e12, "TPU v5e": 197e12, "TPU v5p": 459e12,
@@ -110,24 +110,24 @@ def set_peak_flops(value: Optional[float]):
     _peak_flops_override = value
 
 
-def peak_flops() -> float:
-    """Best-effort device peak FLOP/s for the roofline denominator."""
+def peak_flops() -> Optional[float]:
+    """Device peak FLOP/s for the roofline denominator: the pinned
+    override, else the device-kind table times the device count. None on
+    the cpu backend (no peak is claimed for it, so no utilization is
+    reported); an accelerator of unknown kind raises."""
     if _peak_flops_override:
         return _peak_flops_override
     import jax
-    try:
-        kind = jax.devices()[0].device_kind
-        n = jax.device_count()
-        for prefix, peak in _PEAK_BY_KIND.items():
-            if kind.startswith(prefix):
-                return peak * n
-    except Exception:
-        pass
-    # CPU-class fallback: cores x (assumed) 8-wide FMA at ~3 GHz — an
-    # order-of-magnitude denominator so utilization is comparable
-    # across runs on the same host, not an authoritative peak
-    import os
-    return max(1.0, (os.cpu_count() or 1) * 2 * 8 * 3e9)
+    dev = jax.devices()[0]
+    for prefix, peak in _PEAK_BY_KIND.items():
+        if dev.device_kind.startswith(prefix):
+            return peak * jax.device_count()
+    if dev.platform == "cpu":
+        return None
+    raise RuntimeError(
+        f"no peak FLOP/s known for device kind {dev.device_kind!r} "
+        f"(platform {dev.platform!r}); add it to _PEAK_BY_KIND or call "
+        f"set_peak_flops()")
 
 
 def sample_live_buffers() -> float:
@@ -306,7 +306,9 @@ class ProfiledFunction:
         if cost["flops"]:
             achieved = cost["flops"] / dt
             _m_achieved.labels(fn=self.tag).set(achieved)
-            _m_roofline.labels(fn=self.tag).set(achieved / peak_flops())
+            peak = peak_flops()
+            if peak:
+                _m_roofline.labels(fn=self.tag).set(achieved / peak)
         sample_live_buffers()
         return out
 
@@ -342,7 +344,7 @@ def report() -> dict:
             "calls": pf.calls,
             "last_call_seconds": round(pf.last_call_seconds, 6),
             "achieved_flops_per_sec": achieved,
-            "roofline_utilization": (achieved / peak if peak else 0.0),
+            "roofline_utilization": (achieved / peak if peak else None),
         }
     return {"functions": fns, "peak_flops": peak,
             "live_buffer_bytes": _m_live_bytes.value,

@@ -264,7 +264,19 @@ class TestProfiler:
         assert rep["compile_seconds"] > 0
         assert rep["calls"] == 3
         assert rep["achieved_flops_per_sec"] > 0
-        assert 0 < rep["roofline_utilization"] < 1
+        # the cpu backend claims no peak: no utilization, no gauge series
+        assert rep["roofline_utilization"] is None
+        assert not telemetry.snapshot().get(
+            "mmlspark_profiler_roofline_utilization", {}).get("series")
+        # a pinned peak brings the gauge back; an unknown accelerator kind
+        # is an error, never an invented denominator
+        prof.set_peak_flops(1e15)
+        try:
+            pf(jnp.ones((8, 8), jnp.float32))
+            assert 0 < prof.report()["functions"]["t.obs.fn"][
+                "roofline_utilization"] < 1
+        finally:
+            prof.set_peak_flops(None)
         # counters landed in the shared registry too
         snap = telemetry.snapshot()
         series = snap["mmlspark_profiler_compiles"]["series"]

@@ -554,9 +554,13 @@ class TestOpenLoopBench:
         assert not gate.lower_is_better("serving_open_loop_goodput_rps",
                                         "req/s")
         assert gate.lower_is_better("serving_open_loop_p999_ms", "ms")
-        hist_dir = history.find_history_dir()
-        assert hist_dir is not None
-        rounds = history.load_history(hist_dir)
+        # earlier rounds that never recorded these metrics
+        (tmp_path / "BENCH_r01.json").write_text(json.dumps({
+            "n": 1, "parsed": {"metric": "train_imgs_per_sec",
+                               "value": 100.0, "unit": "imgs/sec"}}))
+        hist_dir = history.find_history_dir(str(tmp_path))
+        assert hist_dir == str(tmp_path)
+        rounds = history.load_history(hist_dir, exclude=str(path))
         report = gate.check_run(run, rounds)
         assert report.ok                  # first round: recorded, not gated
         assert all(e["status"] == "no-history" for e in report.entries)

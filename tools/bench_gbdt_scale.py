@@ -1,8 +1,8 @@
-"""At-scale GBDT wall-clock measurements (the BASELINE.md scale rows).
+"""At-scale GBDT wall-clock measurements (the 10M-row scale rows).
 
 Default: 10M x 28 level-wise (cold + warm 10-iter fits, synced) plus a
 3-iter leaf-wise probe. LEAFWISE_1M=1 measures the 1M-row leaf-wise
-per-iteration cost instead (the BASELINE leaf-wise row)."""
+per-iteration cost instead (the leaf-wise row)."""
 
 import os
 import sys

@@ -8,7 +8,7 @@ sklearn's UCI handwritten-digit scans (the only real image data a
 zero-egress image ships), split train/test at the ORIGINAL-scan level
 and augmented to ~50k rows with label-preserving transforms
 (testing.datagen.digits_rgb32_augmented); the held-out set is untouched
-original scans. The committed number lives in BASELINE.md.
+original scans.
 
 Reproduce (runs on the attached TPU; CPU works but is slow):
 
