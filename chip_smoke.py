@@ -22,14 +22,12 @@ Any failed check raises: there is no try/except that carries on. The script
 sets no platform: where JAX finds no TPU it exits non-zero and prints no
 result. The last line of stdout is one JSON object,
 ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
-The stage functions take their row counts as arguments (and
-``compiled=False``) only so that they can be dry-run on the CPU backend at a
-tiny size before a chip call is spent; ``main`` always runs the full sizes.
+There is one path and there are no options: every stage runs, at the sizes
+fixed below.
 
-    python3 chip_smoke.py [--stages ABCDK]
+    python3 chip_smoke.py
 """
 
-import argparse
 import base64
 import functools
 import json
@@ -97,6 +95,11 @@ def _finite(a, what):
 # ------------------------------------------------------------- A · trainer
 
 RESNET_CFG = {"type": "resnet", "num_classes": 10}      # ResNet-20, 16/32/64
+BATCH = 1024
+TRAIN_ROWS = 16384          # x EPOCHS = 80 optimizer steps on the scan path
+EPOCHS = 5
+FEED_STEPS = 4              # host-feed + prefetch path
+SCORE_ROWS = 2048
 
 
 def _image_rows(n, rng):
@@ -122,23 +125,23 @@ def _image_df(x, y=None):
     return DataFrame(data)
 
 
-def stage_trainer(batch=1024, n=16384, n_score=2048, epochs=5):
+def stage_trainer():
     import jax
     from mmlspark_tpu.models import TpuLearner
     from mmlspark_tpu.parallel import mesh as meshlib
     rng = np.random.default_rng(SEED)
-    x, y = _image_rows(n, rng)
+    x, y = _image_rows(TRAIN_ROWS, rng)
     df = _image_df(x, y)
 
     def learner():
         return (TpuLearner().setModelConfig(RESNET_CFG)
                 .setFeaturesCol("image").setLabelCol("label")
-                .setBatchSize(batch).setOptimizer("adam")
+                .setBatchSize(BATCH).setOptimizer("adam")
                 .setLearningRate(2e-3).setSeed(SEED))
 
     # how the framework places one batch: sharded over every device
     mesh = meshlib.create_mesh()
-    placed = meshlib.shard_batch(x[:batch], mesh)
+    placed = meshlib.shard_batch(x[:BATCH], mesh)
     shards = placed.addressable_shards
     print(f"    batch placement: {len(shards)} addressable shard(s) of "
           f"{shards[0].data.shape} over mesh {dict(mesh.shape)}", flush=True)
@@ -146,26 +149,27 @@ def stage_trainer(batch=1024, n=16384, n_score=2048, epochs=5):
           f"batch sharded over {len(shards)} of {len(jax.devices())} devices")
 
     # host-feed + DevicePrefetcher path: the data cap forced below the
-    # dataset, 4 steps — also the early-training loss the scan fit must beat
-    feed = learner().setEpochs(1).setDeviceDataCap(1).fit(df.limit(4 * batch))
+    # dataset — also the early-training loss the scan fit must beat
+    feed = (learner().setEpochs(1).setDeviceDataCap(1)
+            .fit(df.limit(FEED_STEPS * BATCH)))
     loss_early = float(feed._final_loss)
-    # device-resident scan path: epochs x n/batch = 5 x 16 optimizer steps
-    model = learner().setEpochs(epochs).fit(df)
+    # device-resident scan path
+    model = learner().setEpochs(EPOCHS).fit(df)
     loss_end = float(model._final_loss)
-    print(f"    loss after 4 feed-path steps {loss_early:.4f}; after "
-          f"{epochs * (n // batch)} scan-path steps {loss_end:.4f}",
-          flush=True)
+    print(f"    loss after {FEED_STEPS} feed-path steps {loss_early:.4f}; "
+          f"after {EPOCHS * (TRAIN_ROWS // BATCH)} scan-path steps "
+          f"{loss_end:.4f}", flush=True)
     check(math.isfinite(loss_early) and math.isfinite(loss_end),
           "non-finite training loss")
     check(loss_end < loss_early and loss_end < math.log(10),
           "loss did not go down")
 
-    xt, yt = _image_rows(n_score, rng)
+    xt, yt = _image_rows(SCORE_ROWS, rng)
     scores = _finite(np.stack(list(
         model.transform(_image_df(xt)).col("scores"))), "transform scores")
-    check(scores.shape == (n_score, 10), f"scores shape {scores.shape}")
+    check(scores.shape == (SCORE_ROWS, 10), f"scores shape {scores.shape}")
     acc = float((scores.argmax(1) == yt).mean())
-    print(f"    transform: {n_score} rows -> {scores.shape}, accuracy "
+    print(f"    transform: {SCORE_ROWS} rows -> {scores.shape}, accuracy "
           f"{acc:.3f}", flush=True)
     check(acc > 0.5, f"accuracy {acc} after training")
     return model, xt, scores
@@ -186,7 +190,10 @@ def _metric(text, name):
     return sum(vals)
 
 
-def stage_server(model, rows, scores, n_req=48):
+N_REQUESTS = 48
+
+
+def stage_server(model, rows, scores):
     from mmlspark_tpu import telemetry
     from mmlspark_tpu.io.serving import (BucketPolicy, FusedServingStep,
                                          load_bundle, save_bundle,
@@ -198,14 +205,14 @@ def stage_server(model, rows, scores, n_req=48):
                             output="argmax")
     source, loop = serve_continuous(step, warm=True)
     try:
-        replies = [None] * n_req
+        replies = [None] * N_REQUESTS
 
         def client(i):
             replies[i] = _post(source.url,
                                base64.b64encode(rows[i].tobytes()))
 
         threads = [threading.Thread(target=client, args=(i,))
-                   for i in range(n_req)]
+                   for i in range(N_REQUESTS)]
         for t in threads:
             t.start()
         for t in threads:
@@ -217,10 +224,10 @@ def stage_server(model, rows, scores, n_req=48):
         # the reference is TpuModel.transform on the same rows; a bucket of
         # 8..64 rows and a 2048-row chunk are different XLA programs, so
         # only a near-tie between the top two scores may flip the argmax
-        top2 = np.sort(scores[:n_req], axis=1)[:, -2:]
+        top2 = np.sort(scores[:N_REQUESTS], axis=1)[:, -2:]
         clear = (top2[:, 1] - top2[:, 0]) > 0.05
         check(clear.mean() >= 0.8, "too few rows with a clear argmax")
-        same = labels == scores[:n_req].argmax(1)
+        same = labels == scores[:N_REQUESTS].argmax(1)
         check(same[clear].all(),
               f"served labels differ from transform on rows "
               f"{np.nonzero(~same & clear)[0].tolist()}")
@@ -228,9 +235,10 @@ def stage_server(model, rows, scores, n_req=48):
             metrics = r.read().decode()
         misses = _metric(metrics, "mmlspark_serving_exec_cache_misses_total")
         hits = _metric(metrics, "mmlspark_serving_exec_cache_hits_total")
-        print(f"    {n_req} concurrent POSTs: all 200, {int(same.sum())}/"
-              f"{n_req} labels equal transform ({int(clear.sum())} clear), "
-              f"exec cache hits {hits:.0f} misses {misses:.0f}", flush=True)
+        print(f"    {N_REQUESTS} concurrent POSTs: all 200, "
+              f"{int(same.sum())}/{N_REQUESTS} labels equal transform "
+              f"({int(clear.sum())} clear), exec cache hits {hits:.0f} "
+              f"misses {misses:.0f}", flush=True)
         check(misses == 0, f"{misses} buckets compiled on live traffic")
     finally:
         loop.stop()
@@ -256,11 +264,15 @@ def stage_server(model, rows, scores, n_req=48):
 
 # ---------------------------------------------------------------- C · gbdt
 
-def stage_gbdt(n=262_144, d=28, compiled=True):
+GBDT_ROWS, GBDT_FEATURES = 262_144, 28
+
+
+def stage_gbdt():
     from mmlspark_tpu import DataFrame
     from mmlspark_tpu.models.gbdt import LightGBMClassifier
     rng = np.random.default_rng(SEED)
-    x = rng.normal(size=(n, d)).astype(np.float32)
+    n = GBDT_ROWS
+    x = rng.normal(size=(n, GBDT_FEATURES)).astype(np.float32)
     logit = x[:, 0] * x[:, 1] + np.sin(2 * x[:, 2]) + 0.5 * x[:, 3]
     y = (logit + 0.3 * rng.normal(size=n) > 0).astype(np.int64)
     df = DataFrame({"features": x, "label": y})
@@ -285,20 +297,20 @@ def stage_gbdt(n=262_144, d=28, compiled=True):
         # the default scores through the quantized kernel (bf16 leaf
         # tables): inside the documented band, and not bit-identical to
         # the f32 dense walk — identical would mean the kernel never ran
-        check((gap > 0) == compiled and gap <= 2e-3,
-              f"{name}: default vs dense gap {gap}")
+        check(0 < gap <= 2e-3, f"{name}: default vs dense gap {gap}")
         check((prob.argmax(1) == dense.argmax(1)).mean() > 0.9999,
               f"{name}: argmax differs between default and dense")
 
 
 # ----------------------------------------------------------- D · attention
 
+SEQ_LEN, SEQ_BATCH, SEQ_ROWS = 4096, 8, 32
 LONGCTX_CFG = {"type": "transformer", "vocab_size": 32000, "d_model": 512,
-               "heads": 4, "layers": 4, "num_classes": 8, "max_len": 4096,
+               "heads": 4, "layers": 4, "num_classes": 8, "max_len": SEQ_LEN,
                "causal": True, "remat": True, "attn_impl": "auto"}
 
 
-def stage_attention(T=4096, batch=8, n=32, compiled=True):
+def stage_attention():
     import jax
     import jax.numpy as jnp
     from mmlspark_tpu import DataFrame
@@ -306,16 +318,16 @@ def stage_attention(T=4096, batch=8, n=32, compiled=True):
     from mmlspark_tpu.ops.pallas_kernels import flash_attention
     from mmlspark_tpu.parallel.sequence import blockwise_attention
     rng = np.random.default_rng(SEED)
-    cfg = dict(LONGCTX_CFG, max_len=T)
-    tokens = rng.integers(0, 32000, size=(n, T)).astype(np.int32)
+    cfg, T, batch = LONGCTX_CFG, SEQ_LEN, SEQ_BATCH
+    tokens = rng.integers(0, 32000, size=(SEQ_ROWS, T)).astype(np.int32)
     labels = (tokens[:, 0] % 8).astype(np.int64)
     model = (TpuLearner().setModelConfig(cfg).setBatchSize(batch)
              .setEpochs(1).setOptimizer("adam").setLearningRate(1e-3)
              .setSeed(SEED)
              .fit(DataFrame({"features": tokens, "label": labels})))
     loss = float(model._final_loss)
-    print(f"    transformer d512 h4 L4 T={T} batch {batch}: {n // batch} "
-          f"steps, loss {loss:.4f}", flush=True)
+    print(f"    transformer d512 h4 L4 T={T} batch {batch}: "
+          f"{SEQ_ROWS // batch} steps, loss {loss:.4f}", flush=True)
     check(math.isfinite(loss), "non-finite transformer loss")
     scores = _finite(np.stack(list(model.transform(
         DataFrame({"features": tokens[:batch]})).col("scores"))),
@@ -325,7 +337,7 @@ def stage_attention(T=4096, batch=8, n=32, compiled=True):
     hlo = jax.jit(build_model(cfg).apply).lower(
         jax.tree_util.tree_map(jnp.asarray, model.getModelParams()),
         jnp.asarray(tokens[:1])).as_text()
-    check(("tpu_custom_call" in hlo) == compiled,
+    check("tpu_custom_call" in hlo,
           "attn_impl='auto' lowered without a Mosaic call")
 
     # one batch, kernel against the blockwise reference in f32; the
@@ -403,11 +415,7 @@ def stage_kernels():
 
 # -------------------------------------------------------------------- main
 
-def main(argv=None) -> int:
-    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    ap.add_argument("--stages", default="ABCDK",
-                    help="which stages to run (B needs A)")
-    args = ap.parse_args(argv)
+def main() -> int:
     t_start = time.perf_counter()
 
     import jax
@@ -434,17 +442,11 @@ def main(argv=None) -> int:
           f"native_library_built: {native.available()}\n"
           f"package: {mmlspark_tpu.__file__}", flush=True)
 
-    stages = args.stages.upper()
-    if "A" in stages:
-        model, rows, scores = clock.run("A trainer", stage_trainer)
-        if "B" in stages:
-            clock.run("B server", stage_server, model, rows, scores)
-    if "C" in stages:
-        clock.run("C gbdt", stage_gbdt)
-    if "D" in stages:
-        clock.run("D attention", stage_attention)
-    if "K" in stages:
-        clock.run("K kernels", stage_kernels)
+    model, rows, scores = clock.run("A trainer", stage_trainer)
+    clock.run("B server", stage_server, model, rows, scores)
+    clock.run("C gbdt", stage_gbdt)
+    clock.run("D attention", stage_attention)
+    clock.run("K kernels", stage_kernels)
 
     print(json.dumps({
         "stages": clock.stages,

@@ -90,6 +90,21 @@ def fault_seed() -> int:
         return 0
 
 
+def on_tpu() -> bool:
+    """True on the tpu backend, False on the cpu backend the tests run on.
+    Any other backend raises: every platform-dependent choice (compiled
+    vs interpreted Pallas, flash vs blockwise attention, the GBDT
+    histogram and predict kernels) is made for one of those two, and a
+    third must not be quietly served by the CPU reference paths."""
+    import jax
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"unsupported JAX backend {backend!r}: mmlspark_tpu runs on "
+            f"'tpu' (and on 'cpu' for its tests)")
+    return backend == "tpu"
+
+
 def accelerator_count() -> int:
     """Attached accelerator chips (the GPUCount analog — no nvidia-smi
     subprocess: the JAX runtime already knows)."""

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ... import telemetry
+from ...core.env import on_tpu
 
 # boosting-loop telemetry (no-ops unless MMLSPARK_TPU_TELEMETRY=1). The
 # hist+split work runs inside ONE jitted program per iteration, so the
@@ -562,7 +563,6 @@ def make_sharded_builder(mesh, tree_learner: str, *, depth: int, n_bins: int,
     """
     from jax.sharding import PartitionSpec as P
 
-
     if tree_learner == "data":
         def body(bins, g, h, rm, fm):
             return _stack_class_axis([
@@ -962,17 +962,17 @@ def _fit_gbdt_impl(x: np.ndarray, y: np.ndarray, params: GBDTParams,
     # histogram backend: auto = the round-5 "mxu" kernel on TPU (node axis
     # in the matmul M dim, one-hot width fixed at n_bins: 14.6 ms per
     # 1M x 28 x 16-node build vs segment_sum's 384 ms and the v1 pallas
-    # one-hot's 4.0 s, all synced — see mxu_node_histogram's docstring for
-    # the measured table); on the cpu test backend the "compare" hybrid
+    # one-hot's 4.0 s, all synced, on an earlier runtime — ROADMAP S1/S4);
+    # on the cpu test backend the "compare" hybrid
     # (compare-reduce for uint8 id spaces, segment_sum beyond — CPU CI
     # shouldn't pay Pallas interpret-mode costs). "segment" = pure
     # segment_sum (A/B + bit-reproducing older fits); "pallas" = the v1
     # one-hot kernel (A/B); explicit values never re-route.
-    from ...parallel import mesh as _meshlib
     hist_impl = p.hist_impl
     if hist_impl == "auto":
-        hist_impl = "mxu" if _meshlib.on_tpu() else "compare"
+        hist_impl = "mxu" if on_tpu() else "compare"
     real = slice(None) if sample_weight is None else sample_weight > 0
+    from ...parallel import mesh as _meshlib
     nproc = _meshlib.effective_process_count()
     if binned is not None and nproc > 1:
         raise ValueError(
@@ -1475,7 +1475,6 @@ def _resolve_predict_impl(requested: str, eligible: bool, why: str) -> str:
             raise ValueError(f"predict_impl={requested!r} unavailable: "
                              f"{why}")
         return requested
-    from ...parallel.mesh import on_tpu
     return "pallas" if eligible and on_tpu() else "dense"
 
 

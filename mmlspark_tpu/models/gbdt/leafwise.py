@@ -298,7 +298,6 @@ def make_sharded_builder_lw(mesh, *, num_leaves, n_bins, lambda_l2,
     ring, TrainUtils.scala:141, as ICI collectives)."""
     from jax.sharding import PartitionSpec as P
 
-
     def body(bins, g, h, rm, fm, cat):
         from .engine import _stack_class_axis
 

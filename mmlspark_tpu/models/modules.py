@@ -19,11 +19,13 @@ static; no Python control flow on data.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Optional, Sequence
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 
 class _LayerTap:
@@ -383,6 +385,7 @@ class TransformerEncoder(nn.Module):
     dtype: Any = jnp.bfloat16
     attn_fn: Optional[Callable] = None
     attn_impl: str = "auto"        # auto | blockwise | flash (Pallas kernel)
+    mesh: Any = None               # the Mesh the caller jits under (flash)
     block_size: int = 512
     num_experts: int = 0           # > 0 swaps the FFN for a MoE block (EP)
     expert_top_k: int = 2
@@ -399,11 +402,22 @@ class TransformerEncoder(nn.Module):
         if impl == "auto":
             # the Pallas kernel on tpu; blockwise on the cpu test backend
             # (interpret-mode Pallas is a correctness tool, not a path)
-            from ..parallel.mesh import on_tpu
+            from ..core.env import on_tpu
             impl = "flash" if on_tpu() else "blockwise"
         if impl == "flash":
             from ..ops.pallas_kernels import flash_attention
-            return flash_attention(q, k, v, causal=self.causal)
+            flash = functools.partial(flash_attention, causal=self.causal)
+            mesh = self.mesh
+            if (mesh is None or mesh.size == 1
+                    or q.shape[0] % mesh.shape["data"] != 0):
+                # one-device programs, flax's 2-row eager init included
+                return flash(q, k, v)
+            # GSPMD cannot partition a Mosaic call ("wrap the call in a
+            # shard_map"): under a multi-device mesh the kernel runs per
+            # batch shard
+            spec = P("data", None, None, None)
+            return jax.shard_map(flash, mesh=mesh, in_specs=(spec,) * 3,
+                                 out_specs=spec, check_vma=False)(q, k, v)
         from ..parallel.sequence import blockwise_attention
         return blockwise_attention(q, k, v, block_size=self.block_size,
                                    causal=self.causal)
@@ -496,7 +510,7 @@ MODEL_BUILDERS: dict[str, Callable[..., nn.Module]] = {
         hidden=cfg.get("hidden", 128),
         num_classes=cfg.get("num_classes", 8),
         dtype=jnp.dtype(cfg.get("dtype", jnp.bfloat16))),
-    "transformer": lambda cfg, attn_fn=None: TransformerEncoder(
+    "transformer": lambda cfg, attn_fn=None, mesh=None: TransformerEncoder(
         vocab_size=cfg.get("vocab_size", 10000),
         d_model=cfg.get("d_model", 128),
         heads=cfg.get("heads", 4),
@@ -513,23 +527,26 @@ MODEL_BUILDERS: dict[str, Callable[..., nn.Module]] = {
         capacity_factor=cfg.get("capacity_factor", 1.25),
         remat=cfg.get("remat", False),
         dtype=jnp.dtype(cfg.get("dtype", jnp.bfloat16)),
-        attn_fn=attn_fn),
+        attn_fn=attn_fn, mesh=mesh),
 }
 
 
-def build_model(config: dict, attn_fn: Optional[Callable] = None) -> nn.Module:
+def build_model(config: dict, attn_fn: Optional[Callable] = None,
+                mesh=None) -> nn.Module:
     """config: {"type": <family>, ...family kwargs...} -> flax module.
 
     ``attn_fn`` (transformer only): inject a sequence-parallel attention
     callable (parallel.sequence.make_sp_attention) — kept out of the config
-    dict so configs stay JSON-serializable."""
+    dict so configs stay JSON-serializable. ``mesh`` (transformer only): the
+    mesh the caller jits the module under, so the flash kernel can run per
+    batch shard; without it the module lowers for one device."""
     cfg = dict(config)
     mtype = cfg.pop("type")
     if mtype not in MODEL_BUILDERS:
         raise KeyError(f"unknown model type {mtype!r}; "
                        f"have {sorted(MODEL_BUILDERS)}")
     if mtype == "transformer":
-        return MODEL_BUILDERS[mtype](cfg, attn_fn=attn_fn)
+        return MODEL_BUILDERS[mtype](cfg, attn_fn=attn_fn, mesh=mesh)
     return MODEL_BUILDERS[mtype](cfg)
 
 

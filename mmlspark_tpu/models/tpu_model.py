@@ -193,6 +193,19 @@ class TpuModel(Transformer):
             self._dev_params_mesh = mesh
         return self._dev_params
 
+    def _forward(self, mesh):
+        """``module.apply`` as transform dispatches it, the module built for
+        ``mesh`` (None: a one-device program — what exportStableHLO writes)."""
+        from .modules import build_model
+        module = build_model(self.getModelConfig(), mesh=mesh)
+        ol = self.getOutputLayer() or None
+        if self._is_moe():
+            # MoE routing must know which rows are mesh padding: they
+            # may not claim expert capacity (same contract as training)
+            return lambda p, x, m: module.apply(p, x, output_layer=ol,
+                                                row_mask=m)
+        return lambda p, x: module.apply(p, x, output_layer=ol)
+
     # one jitted program per (config, output_layer, tp); reused across
     # transforms
     def _apply_fn(self):
@@ -201,12 +214,6 @@ class TpuModel(Transformer):
         cur = (tuple(sorted((k, str(v)) for k, v in self.getModelConfig().items())),
                self.getOutputLayer(), tp)
         if key != cur or not hasattr(self, "_apply_jit"):
-            from ..parallel.sequence import batch_parallel_flash
-            from .modules import build_model
-            cfg = self.getModelConfig()
-            module = build_model(cfg, attn_fn=batch_parallel_flash(
-                self._cached_mesh(), cfg))
-            ol = self.getOutputLayer() or None
             kw = {}
             if tp > 1:
                 # the last Dense's columns land model-axis-sharded under
@@ -215,15 +222,8 @@ class TpuModel(Transformer):
                 from jax.sharding import NamedSharding, PartitionSpec as P
                 kw["out_shardings"] = NamedSharding(self._cached_mesh(),
                                                     P("data"))
-            if self._is_moe():
-                # MoE routing must know which rows are mesh padding: they
-                # may not claim expert capacity (same contract as training)
-                self._apply_jit = jax.jit(
-                    lambda p, x, m: module.apply(p, x, output_layer=ol,
-                                                 row_mask=m), **kw)
-            else:
-                self._apply_jit = jax.jit(
-                    lambda p, x: module.apply(p, x, output_layer=ol), **kw)
+            self._apply_jit = jax.jit(self._forward(self._cached_mesh()),
+                                      **kw)
             self._apply_cache_key = cur
         return self._apply_jit
 
@@ -274,7 +274,7 @@ class TpuModel(Transformer):
         p_spec = jax.tree_util.tree_map(
             lambda a: jax.ShapeDtypeStruct(np.shape(a), np.result_type(a)),
             self.getModelParams())
-        fn = self._apply_fn()
+        fn = jax.jit(self._forward(None))
         args = ((p_spec, x_spec,
                  jax.ShapeDtypeStruct((b,), np.float32))
                 if self._is_moe() else (p_spec, x_spec))

@@ -27,6 +27,7 @@ import optax
 from flax import serialization
 
 from ..core.dataframe import DataFrame
+from ..core.env import on_tpu
 from ..core.params import (BooleanParam, DictParam, FloatParam, IntParam,
                            ListParam, StringParam)
 from ..core.pipeline import Estimator
@@ -153,7 +154,7 @@ def _device_data_cap() -> int:
         limit = int((dev.memory_stats() or {}).get("bytes_limit", 0))
         if limit > 0:
             _device_data_cap_cache = limit // 2
-        elif meshlib.on_tpu():
+        elif on_tpu():
             raise RuntimeError(
                 f"{dev.device_kind} reports no memory_stats()['bytes_limit']"
                 f"; set TpuLearner.deviceDataCap explicitly")
@@ -1492,9 +1493,7 @@ class TpuLearner(Estimator):
             mesh = meshlib.make_mesh({"data": n_dev // pp, "pipe": pp})
         else:
             mesh = meshlib.create_mesh(model=tp, devices=devices)
-        if attn_fn is None and pp <= 1:
-            attn_fn = sequence.batch_parallel_flash(mesh, cfg)
-        module = build_model(cfg, attn_fn=attn_fn)
+        module = build_model(cfg, attn_fn=attn_fn, mesh=mesh)
         rng = jax.random.PRNGKey(self.getSeed())
         # init batch must satisfy the shard_map divisibility of the sp
         # attention (batch % data-axis == 0); data-axis size always works
@@ -1509,11 +1508,9 @@ class TpuLearner(Estimator):
                 tuple(jax.ShapeDtypeStruct((init_b,) + r.shape[1:],
                                            r.dtype) for r in raws))
             params = module.init(rng, jnp.zeros(xb_s.shape, xb_s.dtype))
-        elif attn_fn is not None:
-            # an injected attention is a shard_map: over a process-spanning
-            # mesh flax's EAGER init cannot execute it collectively, and the
-            # batch-parallel flash wrapper wants a batch the data axis
-            # divides, which the 2-row init batch is not. The
+        elif attn_fn is not None and meshlib.effective_process_count() > 1:
+            # the sp attention is a shard_map over a process-spanning mesh —
+            # flax's EAGER init cannot execute that collectively. The
             # attention callable holds no params (projections are separate
             # Dense modules), so a plain-attention twin inits the identical
             # tree; the shard_map module only ever runs inside the jitted
@@ -1760,11 +1757,7 @@ class TpuLearner(Estimator):
         elif first is None:
             raise ValueError("batches_fn() yielded no batches")
 
-        attn_fn = sequence.batch_parallel_flash(mesh, cfg)
-        module = build_model(cfg, attn_fn=attn_fn)
-        # the 1-row eager init cannot run a batch-sharded shard_map; the
-        # attention holds no params, so a plain twin inits the same tree
-        init_module = module if attn_fn is None else build_model(cfg)
+        module = build_model(cfg, mesh=mesh)
         feat_fn = None
         if plan is not None:
             # init from the featurized batch SHAPE (eval_shape — nothing
@@ -1775,11 +1768,11 @@ class TpuLearner(Estimator):
                 feat_fn, plan.params,
                 tuple(jax.ShapeDtypeStruct((1,) + r.shape[1:], r.dtype)
                       for r in raw0))
-            params = init_module.init(jax.random.PRNGKey(self.getSeed()),
-                                      jnp.zeros(xb_s.shape, xb_s.dtype))
+            params = module.init(jax.random.PRNGKey(self.getSeed()),
+                                 jnp.zeros(xb_s.shape, xb_s.dtype))
         else:
-            params = init_module.init(jax.random.PRNGKey(self.getSeed()),
-                                      jnp.asarray(x0[:1]))
+            params = module.init(jax.random.PRNGKey(self.getSeed()),
+                                 jnp.asarray(x0[:1]))
         tx = make_optimizer(self.getOptimizer(), self.getLearningRate(),
                             self.getMomentum(), self.getWeightDecay())
         loss_fn = make_loss(self.getLoss(), per_example=True)

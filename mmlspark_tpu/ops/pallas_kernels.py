@@ -33,7 +33,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..parallel.mesh import on_tpu
+from ..core.env import on_tpu
 
 NEG_INF = -1e30
 
@@ -85,27 +85,26 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
             valid = jnp.logical_and(valid, qpos >= kpos)
         s = jnp.where(valid, s, NEG_INF)
 
-        # softmax state stays (bq, 1) end to end: Mosaic keeps rows on
-        # sublanes, so no 1-D relayouts between the row stats and s
-        m_prev = m_ref[:]                               # (bq, 1)
-        m_cur = jnp.max(s, axis=1, keepdims=True)
+        m_prev = m_ref[:, 0]                            # (bq,)
+        m_cur = jnp.max(s, axis=1)
         m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        p = jnp.where(m_new <= NEG_INF / 2, 0.0, p)
+        p = jnp.exp(s - m_new[:, None])
+        p = jnp.where(m_new[:, None] <= NEG_INF / 2, 0.0, p)
         corr = jnp.exp(m_prev - m_new)
         corr = jnp.where(m_prev <= NEG_INF / 2, 0.0, corr)
-        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[:] = m_new
-        acc_ref[:] = (acc_ref[:] * corr
+        l_ref[:, 0] = l_ref[:, 0] * corr + jnp.sum(p, axis=1)
+        m_ref[:, 0] = m_new
+        acc_ref[:] = (acc_ref[:] * corr[:, None]
                       + jnp.dot(p.astype(v.dtype), v,
                                 preferred_element_type=jnp.float32))
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        denom = jnp.maximum(l_ref[:], 1e-30)
+        denom = jnp.maximum(l_ref[:, 0], 1e-30)[:, None]
         o_ref[0] = (acc_ref[:] / denom).astype(o_ref.dtype)
-        lse = m_ref[:] + jnp.log(denom)
-        lse_ref[0] = jnp.where(m_ref[:] <= NEG_INF / 2, NEG_INF, lse)
+        lse = m_ref[:, 0] + jnp.log(jnp.maximum(l_ref[:, 0], 1e-30))
+        lse_ref[0] = jnp.where(m_ref[:, 0] <= NEG_INF / 2, NEG_INF,
+                               lse)[:, None]
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
@@ -131,8 +130,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
         k = k_ref[0]
         v = v_ref[0]
         do = do_ref[0]
-        lse = lse_ref[0]                                 # (bq, 1)
-        dvec = dvec_ref[0]                               # (bq, 1)
+        lse = lse_ref[0][:, 0]                           # (bq,)
+        dvec = dvec_ref[0][:, 0]                         # (bq,)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         kpos = k_start + jax.lax.broadcasted_iota(jnp.int32,
@@ -143,11 +142,11 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
                                                       (block_q, block_k), 0)
             valid = jnp.logical_and(valid, qpos >= kpos)
         s = jnp.where(valid, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        p = jnp.where(lse <= NEG_INF / 2, 0.0, p)            # padded q rows
+        p = jnp.exp(s - lse[:, None])
+        p = jnp.where(lse[:, None] <= NEG_INF / 2, 0.0, p)   # padded q rows
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - dvec)
+        ds = p * (dp - dvec[:, None])
         acc_ref[:] += jnp.dot(ds.astype(k.dtype), k,
                               preferred_element_type=jnp.float32)
 
@@ -181,8 +180,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
         k = k_ref[0]
         v = v_ref[0]
         do = do_ref[0]
-        lse = lse_ref[0]                                 # (bq, 1)
-        dvec = dvec_ref[0]
+        lse = lse_ref[0][:, 0]
+        dvec = dvec_ref[0][:, 0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         kpos = k_start + jax.lax.broadcasted_iota(jnp.int32,
@@ -193,14 +192,14 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
                                                       (block_q, block_k), 0)
             valid = jnp.logical_and(valid, qpos >= kpos)
         s = jnp.where(valid, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        p = jnp.where(lse <= NEG_INF / 2, 0.0, p)
+        p = jnp.exp(s - lse[:, None])
+        p = jnp.where(lse[:, None] <= NEG_INF / 2, 0.0, p)
         dv_acc[:] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)              # (bk, D)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - dvec)
+        ds = p * (dp - dvec[:, None])
         dk_acc[:] += jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)              # (bk, D)
@@ -433,43 +432,43 @@ def _split3_bf16(a):
     return hi, mid, r2.astype(jnp.bfloat16)
 
 
-def _node_hist_kernel(bins_ref, node_ref, g_ref, h_ref, hist_ref, *,
-                      n_nodes: int, feat_chunk: int, width: int, rows: int):
+def _node_hist_kernel(bins_ref, node_ref, g_ref, h_ref, hg_ref, hh_ref, *,
+                      n_nodes: int, feat_chunk: int, width: int):
     """Grid = (feature_chunks, row_blocks), rows innermost so the output
     block (one feature chunk's histograms) stays VMEM-resident across the
-    whole row sweep. Everything is laid out rows-along-lanes: the masked
-    grad/hess operand A and the per-feature bin one-hot B are built
-    broadcast-natural, and the MXU contraction runs over the shared lane
-    (row) dimension — no transposes anywhere.
-
-    Per feature the output holds ``rows`` sublanes: grad sums for nodes
-    [0, n), hess sums for [n, 2n), zeros above. ``rows`` is 2n rounded up
-    to the bf16 sublane tile (16), so the three bf16 split parts stack,
-    the matmul result splits and the accumulating store lands all on tile
-    boundaries whatever the node count (1, 2, 3, 4 ... are not 8-aligned).
-    """
+    whole row sweep. Everything is laid out rows-along-lanes: the node
+    one-hot, the masked grad/hess operand A, and the per-feature bin
+    one-hot B are all built broadcast-natural, and the MXU contraction
+    runs over the shared lane (row) dimension — no transposes anywhere."""
     i = pl.program_id(1)
 
     @pl.when(i == 0)
     def _init():
-        hist_ref[:] = jnp.zeros_like(hist_ref)
+        hg_ref[:] = jnp.zeros_like(hg_ref)
+        hh_ref[:] = jnp.zeros_like(hh_ref)
 
-    node = node_ref[:]                                      # (1, bn) int32
-    bn = node.shape[1]
-    r = jax.lax.broadcasted_iota(jnp.int32, (rows, bn), 0)
-    is_h = r >= n_nodes
-    hit = (node == jnp.where(is_h, r - n_nodes, r)) & (r < 2 * n_nodes)
-    a = jnp.where(hit, jnp.where(is_h, h_ref[:], g_ref[:]), 0.0)
-    A = jnp.concatenate(_split3_bf16(a), axis=0)            # (3*rows, bn)
+    node = node_ref[:].astype(jnp.int32)                    # (bn,)
+    bn = node.shape[0]
+    g = g_ref[:]                                            # (bn,) f32
+    h = h_ref[:]
+    node1h = (node[None, :] == jax.lax.broadcasted_iota(
+        jnp.int32, (n_nodes, bn), 0))                       # (n_nodes, bn)
+    ag = jnp.where(node1h, g[None, :], 0.0)
+    ah = jnp.where(node1h, h[None, :], 0.0)
+    a = jnp.concatenate([ag, ah], axis=0)                   # (2n, bn) f32
+    hi, mid, lo = _split3_bf16(a)
+    A = jnp.concatenate([hi, mid, lo], axis=0)              # (6n, bn) bf16
 
     for fc in range(feat_chunk):
-        B = (bins_ref[fc:fc + 1, :] == jax.lax.broadcasted_iota(
+        bf = bins_ref[fc, :].astype(jnp.int32)              # (bn,)
+        B = (bf[None, :] == jax.lax.broadcasted_iota(
             jnp.int32, (width, bn), 0)).astype(jnp.bfloat16)
         out = jax.lax.dot_general(
             A, B, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (3*rows, width)
-        hist_ref[fc * rows:(fc + 1) * rows, :] += (
-            out[:rows] + out[rows:2 * rows] + out[2 * rows:])
+            preferred_element_type=jnp.float32)             # (6n, width)
+        out = out.reshape(3, 2 * n_nodes, width).sum(axis=0)
+        hg_ref[fc * n_nodes:(fc + 1) * n_nodes, :] += out[:n_nodes]
+        hh_ref[fc * n_nodes:(fc + 1) * n_nodes, :] += out[n_nodes:]
 
 
 def mxu_node_histogram(bins_t, node, g, h, *, n_nodes: int,
@@ -506,13 +505,12 @@ def mxu_node_histogram(bins_t, node, g, h, *, n_nodes: int,
     interpret = _interpret() if interpret is None else interpret
     assert n_nodes <= 256, "node axis rides the matmul M dim; cap at 256"
     width = max(128, -(-n_bins // 128) * 128)
-    rows = -(-2 * n_nodes // 16) * 16       # see _node_hist_kernel
-    # VMEM budget: the A operand ((3*rows, block_n) bf16 + its f32
+    # VMEM budget: the A operand ((6*n_nodes, block_n) bf16 + its f32
     # staging) scales with n_nodes — shrink the row block as the node
-    # count grows so deep levels stay under the scoped limit instead of
-    # failing Mosaic allocation. feat_chunk stays 8: Mosaic requires the
-    # bins block's sublane dim be 8-divisible (or equal F).
-    block_n = min(block_n, max(128, (4 << 20) // (12 * rows) // 128 * 128))
+    # count grows so deep levels stay under the ~16 MB scoped limit
+    # instead of failing Mosaic allocation. feat_chunk stays 8: Mosaic
+    # requires the bins block's sublane dim be 8-divisible (or equal F).
+    block_n = min(block_n, max(128, (2 << 20) // (12 * n_nodes) // 128 * 128))
     block_n = min(block_n, max(128, -(-N // 128) * 128))
     feat_chunk = min(feat_chunk, F)
     pad_n = (-N) % block_n
@@ -530,21 +528,30 @@ def mxu_node_histogram(bins_t, node, g, h, *, n_nodes: int,
     nblk = bins_t.shape[1] // block_n
 
     kernel = functools.partial(_node_hist_kernel, n_nodes=n_nodes,
-                               feat_chunk=feat_chunk, width=width, rows=rows)
-    row_spec = pl.BlockSpec((1, block_n), lambda j, i: (0, i))
-    hist = pl.pallas_call(
+                               feat_chunk=feat_chunk, width=width)
+    hg, hh = pl.pallas_call(
         kernel,
         grid=(nfc, nblk),
-        in_specs=[pl.BlockSpec((feat_chunk, block_n), lambda j, i: (j, i)),
-                  row_spec, row_spec, row_spec],
-        out_specs=pl.BlockSpec((feat_chunk * rows, width),
-                               lambda j, i: (j, 0)),
-        out_shape=jax.ShapeDtypeStruct((F_pad * rows, width), jnp.float32),
+        in_specs=[
+            pl.BlockSpec((feat_chunk, block_n), lambda j, i: (j, i)),
+            pl.BlockSpec((block_n,), lambda j, i: (i,)),
+            pl.BlockSpec((block_n,), lambda j, i: (i,)),
+            pl.BlockSpec((block_n,), lambda j, i: (i,)),
+        ],
+        out_specs=(
+            pl.BlockSpec((feat_chunk * n_nodes, width), lambda j, i: (j, 0)),
+            pl.BlockSpec((feat_chunk * n_nodes, width), lambda j, i: (j, 0)),
+        ),
+        out_shape=(
+            jax.ShapeDtypeStruct((F_pad * n_nodes, width), jnp.float32),
+            jax.ShapeDtypeStruct((F_pad * n_nodes, width), jnp.float32),
+        ),
         interpret=interpret,
-    )(bins_t.astype(jnp.int32), node.astype(jnp.int32)[None, :],
-      g.astype(jnp.float32)[None, :], h.astype(jnp.float32)[None, :])
-    hist = hist.reshape(F_pad, rows, width)[:F, :, :n_bins].transpose(1, 0, 2)
-    return hist[:n_nodes], hist[n_nodes:2 * n_nodes]
+    )(bins_t.astype(jnp.int32), node.astype(jnp.int32),
+      g.astype(jnp.float32), h.astype(jnp.float32))
+    hg = hg.reshape(F_pad, n_nodes, width)[:F, :, :n_bins]
+    hh = hh.reshape(F_pad, n_nodes, width)[:F, :, :n_bins]
+    return hg.transpose(1, 0, 2), hh.transpose(1, 0, 2)
 
 
 # ------------------------------------------------- GBDT quantized predict

@@ -456,17 +456,19 @@ def _init_elastic_client(address: str, num_processes: int, process_id: int,
     off plus a log-only missed-heartbeat callback as the last line of
     defense."""
     from jax._src import distributed as dist_internal
-    from jax._src.lib import _jax as xe
+    from jax._src.lib import xla_extension as xe
     st = dist_internal.global_state
     if st.client is not None:
         raise RendezvousError("previous incarnation still attached; "
                               "teardown_for_rendezvous() first")
     # ~11 days of missed heartbeats before the redundant detector acts
-    hb_timeout = 1_000_000
+    hb_interval, hb_tolerance = 10, 100_000
     if process_id == 0:
         port = address.rsplit(":", 1)[1]
         st.service = xe.get_distributed_runtime_service(
-            f"[::]:{port}", num_processes, heartbeat_timeout=hb_timeout)
+            f"[::]:{port}", num_processes,
+            heartbeat_interval=hb_interval,
+            max_missing_heartbeats=hb_tolerance)
 
     def _on_peer_trouble(*status):
         log.warning("coordination-service error (peer died or network "
@@ -476,7 +478,8 @@ def _init_elastic_client(address: str, num_processes: int, process_id: int,
     st.client = xe.get_distributed_runtime_client(
         address, process_id, init_timeout=init_timeout,
         shutdown_timeout=10,
-        heartbeat_timeout=hb_timeout,
+        heartbeat_interval=hb_interval,
+        max_missing_heartbeats=hb_tolerance,
         missed_heartbeat_callback=_on_peer_trouble,
         shutdown_on_destruction=False, use_compression=True)
     st.client.connect()

@@ -57,20 +57,6 @@ def _observe_put(t0: float, tree):
 # order on each process.
 collective_fit_lock = threading.RLock()
 
-
-def on_tpu() -> bool:
-    """True on the tpu backend, False on the cpu backend the tests run on.
-    Any other backend raises: every platform-dependent choice (compiled
-    vs interpreted Pallas, flash vs blockwise attention, the GBDT
-    histogram and predict kernels) is made for one of those two, and a
-    third must not be quietly served by the CPU reference paths."""
-    backend = jax.default_backend()
-    if backend not in ("tpu", "cpu"):
-        raise RuntimeError(
-            f"unsupported JAX backend {backend!r}: mmlspark_tpu runs on "
-            f"'tpu' (and on 'cpu' for its tests)")
-    return backend == "tpu"
-
 # ---- local-fit mode -------------------------------------------------------
 # Embarrassingly-parallel search on a fleet (TuneHyperparameters) assigns
 # whole trials to processes; each process then fits ITS trials with no

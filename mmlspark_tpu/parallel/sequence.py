@@ -239,28 +239,6 @@ def make_sp_attention(mesh: Mesh, axis_name: str = "seq",
     return attn
 
 
-def batch_parallel_flash(mesh: Mesh, cfg: dict, batch_axis: str = "data"):
-    """The attention callable a transformer config needs to run the Pallas
-    flash kernel under a multi-device mesh: GSPMD cannot partition a Mosaic
-    call ("wrap the call in a shard_map"), so the kernel runs per batch
-    shard. None where the module's own choice already works: a one-device
-    mesh, the cpu backend (blockwise is plain XLA) or an explicit
-    ``attn_impl='blockwise'``."""
-    from .mesh import on_tpu
-    if (cfg.get("type") != "transformer" or mesh.size == 1
-            or cfg.get("attn_impl", "auto") == "blockwise" or not on_tpu()):
-        return None
-    from ..ops.pallas_kernels import flash_attention
-    spec = P(batch_axis, None, None, None)
-    local = functools.partial(flash_attention,
-                              causal=cfg.get("causal", False))
-
-    def attn(q, k, v):
-        return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                             out_specs=spec, check_vma=False)(q, k, v)
-    return attn
-
-
 def plain_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None):
     """Dense reference attention (for tests and tiny sequences)."""

@@ -76,14 +76,14 @@ def test_unknown_backend_is_an_error(monkeypatch):
     """The cpu paths are taken because the backend IS cpu, not because it
     is not tpu."""
     import jax
+    from mmlspark_tpu.core import env
     from mmlspark_tpu.ops import pallas_kernels
-    from mmlspark_tpu.parallel import mesh as meshlib
-    assert meshlib.on_tpu() is False and pallas_kernels._interpret() is True
+    assert env.on_tpu() is False and pallas_kernels._interpret() is True
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert meshlib.on_tpu() is True and pallas_kernels._interpret() is False
+    assert env.on_tpu() is True and pallas_kernels._interpret() is False
     monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
     with pytest.raises(RuntimeError, match="unsupported JAX backend"):
-        meshlib.on_tpu()
+        env.on_tpu()
     with pytest.raises(RuntimeError, match="unsupported JAX backend"):
         pallas_kernels._interpret()
 
@@ -148,39 +148,38 @@ def test_chip_smoke_refuses_a_machine_without_a_tpu(tmp_path):
     assert "not 'tpu'" in r.stderr
 
 
-def test_flash_runs_per_batch_shard_on_a_multi_device_mesh(monkeypatch):
-    """GSPMD cannot partition a Mosaic call, so under a multi-device tpu
-    mesh the trainer and TpuModel hand the transformer a shard_map'd flash
-    kernel (found on the four-chip host, PR 21). Here: the 8-device CPU
-    mesh, the wrapper forced on, the kernel in interpret mode."""
+def test_flash_runs_per_batch_shard_on_a_multi_device_mesh():
+    """GSPMD cannot partition a Mosaic call, so a transformer built for a
+    multi-device mesh runs its flash kernel per batch shard (found on the
+    four-chip host, PR 21). Here: the 8-device CPU mesh, the kernel pinned
+    (``attn_impl='flash'``) and therefore in interpret mode."""
     import jax
     import numpy as np
     from mmlspark_tpu import DataFrame
-    from mmlspark_tpu.models import TpuLearner
-    # imported BEFORE the patch below: the kernels keep the real on_tpu and
-    # so stay in interpret mode while the wrapper believes it is on a tpu
-    from mmlspark_tpu.ops import pallas_kernels  # noqa: F401
+    from mmlspark_tpu.models import TpuLearner, build_model
     from mmlspark_tpu.parallel import mesh as meshlib
-    from mmlspark_tpu.parallel import sequence
     cfg = {"type": "transformer", "vocab_size": 50, "d_model": 16,
            "heads": 2, "layers": 1, "num_classes": 3, "max_len": 16,
-           "causal": True}
+           "causal": True, "attn_impl": "flash"}
     mesh = meshlib.create_mesh()
-    assert sequence.batch_parallel_flash(mesh, cfg) is None     # cpu
-    monkeypatch.setattr(meshlib, "on_tpu", lambda: True)
-    attn = sequence.batch_parallel_flash(mesh, cfg)
     rng = np.random.default_rng(0)
-    q, k, v = (rng.normal(size=(8, 16, 2, 8)).astype(np.float32)
-               for _ in range(3))
-    np.testing.assert_allclose(
-        np.asarray(jax.jit(attn)(q, k, v)),
-        np.asarray(sequence.plain_attention(q, k, v, causal=True)),
-        atol=1e-5, rtol=1e-5)
     tokens = rng.integers(0, 50, size=(32, 16)).astype(np.int32)
+    meshed, plain = build_model(cfg, mesh=mesh), build_model(cfg)
+    # the 2-row eager init is a one-device program: no shard_map, same tree
+    params = meshed.init(jax.random.PRNGKey(0), tokens[:2])
+    placed = (meshlib.put_replicated(params, mesh),
+              meshlib.shard_batch(tokens[:16], mesh))
+    fn = jax.jit(meshed.apply)
+    assert "sdy.manual_computation" in fn.lower(*placed).as_text()
+    assert "sdy.manual_computation" not in jax.jit(plain.apply).lower(
+        params, tokens[:16]).as_text()
+    np.testing.assert_allclose(        # the module computes in bfloat16
+        np.asarray(fn(*placed)),
+        np.asarray(jax.jit(plain.apply)(params, tokens[:16])), atol=2e-2)
+    # and through the entry points, which hand the module their mesh
     df = DataFrame({"features": tokens, "label": tokens[:, 0] % 3})
-    model = (TpuLearner().setModelConfig(cfg).setBatchSize(16).setEpochs(1)
-             .setDeviceDataCap(1 << 20)    # the "tpu" reports no HBM limit
-             .fit(df))
+    model = TpuLearner().setModelConfig(cfg).setBatchSize(16).setEpochs(1) \
+        .fit(df)
     assert np.isfinite(model._final_loss)
     scores = np.stack(list(model.transform(df).col("scores")))
     assert scores.shape == (32, 3) and np.isfinite(scores).all()
