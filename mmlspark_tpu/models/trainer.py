@@ -17,6 +17,8 @@ resume"): per-epoch checkpointing with automatic resume.
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import os
 from typing import Optional
 
@@ -45,8 +47,10 @@ log = get_logger("trainer")
 # runtime telemetry (off-by-default no-ops; MMLSPARK_TPU_TELEMETRY=1)
 _m_step_time = telemetry.registry.histogram(
     "mmlspark_trainer_step_seconds",
-    "wall time per optimizer dispatch (one step on the feed path, a "
-    "stepsPerDispatch window on the scan path)")
+    "wall time per optimizer dispatch: the host time of one step's "
+    "dispatch call on the feed and stream paths (the fit/dispatch span's "
+    "clock reads), a device-synced stepsPerDispatch window on the scan "
+    "path")
 _m_rows_per_sec = telemetry.registry.gauge(
     "mmlspark_trainer_rows_per_sec",
     "training throughput over the last epoch (rows == imgs for image fits)")
@@ -69,6 +73,62 @@ _seen_step_sigs: set = set()
 #: model code) classifies non-transient and raises immediately.
 _STEP_RETRY = RetryPolicy(name="trainer.step", max_attempts=2,
                           base_delay=0.05, max_delay=0.25)
+
+
+class _StepsInFlight:
+    """The losses of dispatched steps that are not known to be finished:
+    what `fit/dispatch` reports as ``in_flight``. Polled with
+    ``is_ready()``, never waited on; steps finish in dispatch order, so
+    the finished ones are a prefix. Holds one scalar a step in flight and
+    exists only in a fit that started with telemetry on."""
+
+    def __init__(self):
+        self.losses = collections.deque()
+
+    def count(self) -> int:
+        while self.losses and self.losses[0].is_ready():
+            self.losses.popleft()
+        return len(self.losses)
+
+
+def _dispatch_step(train_step, params, opt_state, scale_state, xb, yb, wb, *,
+                   step, in_flight, fused=None, elastic_ctx=None):
+    """Enqueue one optimizer step of the stream or the feed loop through
+    `_STEP_RETRY`, under the span `fit/dispatch`: host time only (JAX
+    returns before the device finishes; `setProfile(True)` gives a
+    device-timed step), including any time the runtime holds the call back.
+    The span's two clock reads also feed `mmlspark_trainer_step_seconds`.
+
+    ``fused`` is the placed capture params of a fit-side fused step (``xb``
+    is then the placed raw column tuple and ``yb`` None); ``in_flight`` the
+    fit's `_StepsInFlight`, None when the fit started with telemetry off.
+    Returns ``(params, opt_state, scale_state, loss)``."""
+    state = ((params, opt_state) if scale_state is None
+             else (params, opt_state, scale_state))
+    batch = (xb, yb, wb) if fused is None else (fused, xb, wb)
+
+    def dispatch(_attempt):
+        if elastic_ctx is not None:
+            # host-loss / grow check + the elastic.step fault site; both
+            # raise non-transient, skip the retry and unwind to the
+            # coordinator's re-mesh
+            elastic_ctx.check_step()
+        faults.inject("trainer.step")
+        return train_step(*state, *batch)
+
+    attrs = {} if in_flight is None else {"in_flight": in_flight.count()}
+    with telemetry.trace.span("fit/dispatch", step=step, **attrs) as sp:
+        out = _STEP_RETRY.run(dispatch)
+    _m_step_time.observe(sp.seconds)
+    if in_flight is not None:
+        in_flight.losses.append(out[-1])
+    if fused is not None:
+        from ..core import capture as capturelib
+        capturelib._m_fit_fused.inc()
+    if scale_state is None:
+        params, opt_state, loss = out
+        return params, opt_state, None, loss
+    return out
 
 
 def _note_step_signature(tag: str, *arrays):
@@ -1382,6 +1442,10 @@ class TpuLearner(Estimator):
         hosts' pool after a re-mesh); ``elastic_ctx`` threads the per-step
         host-loss check and the committed-step/resume journal through the
         dispatch loop."""
+        # fit/init: from here to where the epochs begin (a fit that fails
+        # before then records none)
+        init = contextlib.ExitStack()
+        init.enter_context(telemetry.trace.span("fit/init", path="fit"))
         # rendezvous-armed fleets: snapshots go to the writer thread and
         # stalled writers are abandoned (see _save_checkpoint/_ckpt_barrier)
         self._elastic_multiproc = bool(
@@ -1630,7 +1694,6 @@ class TpuLearner(Estimator):
         # concurrent fits from a thread pool (TuneHyperparameters) must not
         # interleave collective programs across the same devices — same
         # deadlock guard as the GBDT fit path (parallel/mesh.py)
-        import contextlib
         # elastic multi-process attempts run on abandonable threads; an
         # orphaned (pinned-in-dead-collective) attempt may still hold the
         # reentrant fit lock, and it can never issue a collective on the
@@ -1645,6 +1708,7 @@ class TpuLearner(Estimator):
             "pipeline/fit_segment", stages=len(plan.pairs), rows=n,
             path="scan" if scan_fn is not None else "feed")
             if plan is not None else contextlib.nullcontext())
+        init.close()
         try:
             with guard, telemetry.trace.span(
                     "fit", model=cfg.get("type"), rows=n,
@@ -1710,6 +1774,10 @@ class TpuLearner(Estimator):
 
     def _fit_stream_core(self, batches_fn, devices=None,
                          elastic_ctx=None) -> TpuModel:
+        # fit/init: from here to where the step loop begins (a fit that
+        # fails before then records none)
+        init = contextlib.ExitStack()
+        init.enter_context(telemetry.trace.span("fit/init", path="stream"))
         self._elastic_multiproc = bool(
             elastic_ctx is not None
             and getattr(elastic_ctx._coord, "_multiproc", False))
@@ -1724,7 +1792,6 @@ class TpuLearner(Estimator):
         if nproc > 1:
             _require_inner_block_local({"tensorParallel": tp})
         mesh = meshlib.create_mesh(model=tp, devices=devices)
-        from ..core import capture as capturelib
         # fit-side pipeline fusion (fitStreamCaptured): stream batches ship
         # as RAW wire-dtype columns and featurize inside the step program
         plan = getattr(self, "_featurize_plan", None)
@@ -1816,7 +1883,6 @@ class TpuLearner(Estimator):
 
         from ..parallel import prefetch as prefetchlib
         axis = mesh.shape["data"]
-        import contextlib
         # elastic multi-process attempts run on abandonable threads; an
         # orphaned (pinned-in-dead-collective) attempt may still hold the
         # reentrant fit lock, and it can never issue a collective on the
@@ -1832,6 +1898,11 @@ class TpuLearner(Estimator):
                                          stages=len(plan.pairs),
                                          path="stream")
                     if plan is not None else contextlib.nullcontext())
+        # steps dispatched in this fit, all epochs: the `step` that joins
+        # one step's spans across the loop and the prefetch thread
+        step_no = 0
+        in_flight = _StepsInFlight() if telemetry.enabled() else None
+        init.close()
         with guard, seg_span:
             for epoch in range(start_epoch, self.getEpochs()):
                 it = first_iter if epoch == start_epoch and first is not None \
@@ -1855,57 +1926,51 @@ class TpuLearner(Estimator):
                 steps_it = prefetchlib.prefetched(
                     lambda s=stream: self._stream_epoch_steps(
                         s, cfg, x0, y0, share, nproc, mesh, plan=plan),
-                    depth=depth, name="fit-stream", span="fit/prefetch")
+                    depth=depth, name="fit-stream", span="fit/prefetch",
+                    first_item=step_no)
                 ckpt_every = (self.getCheckpointEverySteps()
                               if self.getCheckpointDir() else 0)
                 try:
-                    for n, xb, yb, wb in steps_it:
-                        with _m_step_time.time():
-                            def dispatch(_a, p=params, o=opt_state,
-                                         ss=scale_state, xb=xb, yb=yb,
-                                         wb=wb):
-                                if elastic_ctx is not None:
-                                    # host-loss / grow check; both raise
-                                    # non-transient and unwind to the
-                                    # coordinator's re-mesh
-                                    elastic_ctx.check_step()
-                                faults.inject("trainer.step")
-                                if plan is not None:
-                                    # xb carries the placed raw column
-                                    # tuple; yb is None on this path
-                                    if ss is None:
-                                        p2, o2, loss = train_step(
-                                            p, o, plan_dev, xb, wb)
-                                        return p2, o2, None, loss
-                                    return train_step(p, o, ss, plan_dev,
-                                                      xb, wb)
-                                if ss is None:
-                                    p2, o2, loss = train_step(p, o, xb,
-                                                              yb, wb)
-                                    return p2, o2, None, loss
-                                return train_step(p, o, ss, xb, yb, wb)
+                    while True:
+                        with telemetry.trace.span("fit/step",
+                                                  step=step_no) as step_sp:
+                            with telemetry.trace.span("fit/feed_wait",
+                                                      step=step_no) as sp:
+                                item = next(steps_it, None)
+                                if item is None:
+                                    sp.discard()
+                            if item is None:
+                                step_sp.discard()   # no such step
+                                break
+                            n, xb, yb, wb = item
                             params, opt_state, scale_state, loss = \
-                                _STEP_RETRY.run(dispatch)
-                            if plan is not None:
-                                capturelib._m_fit_fused.inc()
-                        steps_run += 1
-                        if n:
-                            n_batches += 1
-                        if elastic_ctx is not None:
-                            elastic_ctx.step_committed(epoch,
-                                                       steps_run - 1)
-                        if ckpt_every and steps_run % ckpt_every == 0 \
-                                and self._ckpt_should_write():
-                            self._save_checkpoint(epoch, params, opt_state,
-                                                  step=steps_run - 1,
-                                                  scale_state=scale_state,
-                                                  elastic_ctx=elastic_ctx)
+                                _dispatch_step(
+                                    train_step, params, opt_state,
+                                    scale_state, xb, yb, wb, step=step_no,
+                                    in_flight=in_flight, fused=plan_dev,
+                                    elastic_ctx=elastic_ctx)
+                            step_no += 1
+                            steps_run += 1
+                            if n:
+                                n_batches += 1
+                            if elastic_ctx is not None:
+                                elastic_ctx.step_committed(epoch,
+                                                           steps_run - 1)
+                            if ckpt_every and steps_run % ckpt_every == 0 \
+                                    and self._ckpt_should_write():
+                                self._save_checkpoint(
+                                    epoch, params, opt_state,
+                                    step=steps_run - 1,
+                                    scale_state=scale_state,
+                                    elastic_ctx=elastic_ctx)
                 finally:
                     steps_it.close()
                 if steps_run == 0:
                     raise ValueError(f"batches_fn() yielded no batches in "
                                      f"epoch {epoch}")
                 last_loss = float(loss)
+                if in_flight is not None:
+                    in_flight.losses.clear()    # the epoch's last step is in
                 from .precision import observe_scale_state
                 skipped_seen = observe_scale_state(scale_state,
                                                    skipped_seen)
@@ -2149,51 +2214,46 @@ class TpuLearner(Estimator):
         t_epoch = time.perf_counter()
         it = prefetchlib.prefetched(produce, depth=self.getPrefetchDepth(),
                                     name="fit-feed", span="fit/prefetch")
+        # steps dispatched in this fit: the `step` that joins one step's
+        # spans across the loop and the prefetch thread (`item`)
+        step_no = 0
+        in_flight = _StepsInFlight() if telemetry.enabled() else None
         try:
             ckpt_every = (self.getCheckpointEverySteps()
                           if self.getCheckpointDir() else 0)
-            for epoch, s, xb, yb, wb in it:
-                t_step = time.perf_counter()
-                with telemetry.trace.span("fit/step", epoch=epoch,
-                                          step=s) as sp:
-                    def dispatch(_a, p=params, o=opt_state,
-                                 ss=scale_state, xb=xb, yb=yb, wb=wb):
-                        if elastic_ctx is not None:
-                            # host-loss check + elastic.step fault site;
-                            # HostLossError is non-transient, so it skips
-                            # the retry and unwinds to the re-mesh
-                            elastic_ctx.check_step()
-                        faults.inject("trainer.step")
-                        if fused is not None:
-                            # xb carries the placed raw column tuple
-                            if ss is None:
-                                p2, o2, loss = train_step(p, o, fused[1],
-                                                          xb, wb)
-                                return p2, o2, None, loss
-                            return train_step(p, o, ss, fused[1], xb, wb)
-                        if ss is None:
-                            p2, o2, loss = train_step(p, o, xb, yb, wb)
-                            return p2, o2, None, loss
-                        return train_step(p, o, ss, xb, yb, wb)
-                    params, opt_state, scale_state, loss = \
-                        _STEP_RETRY.run(dispatch)
-                    if fused is not None:
-                        capturelib._m_fit_fused.inc()
-                    sp.set_sync(loss)
-                _m_step_time.observe(time.perf_counter() - t_step)
-                if elastic_ctx is not None:
-                    elastic_ctx.step_committed(epoch, s)
-                if s < steps - 1:
-                    if ckpt_every and (s + 1) % ckpt_every == 0 \
-                            and self._ckpt_should_write():
-                        self._save_checkpoint(epoch, params, opt_state,
-                                              step=s,
-                                              scale_state=scale_state,
-                                              elastic_ctx=elastic_ctx)
-                    continue
+            while True:
+                with telemetry.trace.span("fit/step",
+                                          step=step_no) as step_sp:
+                    with telemetry.trace.span("fit/feed_wait",
+                                              step=step_no) as sp:
+                        item = next(it, None)
+                        if item is None:
+                            sp.discard()
+                    if item is None:
+                        step_sp.discard()   # no such step
+                        break
+                    epoch, s, xb, yb, wb = item
+                    params, opt_state, scale_state, loss = _dispatch_step(
+                        train_step, params, opt_state, scale_state, xb, yb,
+                        wb, step=step_no, in_flight=in_flight,
+                        fused=None if fused is None else fused[1],
+                        elastic_ctx=elastic_ctx)
+                    step_no += 1
+                    if elastic_ctx is not None:
+                        elastic_ctx.step_committed(epoch, s)
+                    if s < steps - 1:
+                        if ckpt_every and (s + 1) % ckpt_every == 0 \
+                                and self._ckpt_should_write():
+                            self._save_checkpoint(epoch, params, opt_state,
+                                                  step=s,
+                                                  scale_state=scale_state,
+                                                  elastic_ctx=elastic_ctx)
+                        continue
                 # ---- epoch finalize (an early exit below must stop the
                 # producer promptly: the finally closes the prefetcher) ----
                 last_loss = float(loss)
+                if in_flight is not None:
+                    in_flight.losses.clear()    # the epoch's last step is in
                 _m_rows_per_sec.set(
                     steps * bs / max(time.perf_counter() - t_epoch, 1e-9))
                 t_epoch = time.perf_counter()

@@ -289,6 +289,7 @@ def _flash_attention_bwd(causal, scale, block_q, block_k, interpret,
         out_shape=jax.ShapeDtypeStruct(qb.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
+        name="flash_dq",
     )(qb, kb, vb, dob, lse_b, dvec)
 
     # dkv grid: K blocks outer, Q blocks inner (accumulators live per-K)
@@ -305,6 +306,7 @@ def _flash_attention_bwd(causal, scale, block_q, block_k, interpret,
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, D), jnp.float32)],
         interpret=interpret,
+        name="flash_dkv",
     )(qb, kb, vb, dob, lse_b, dvec)
 
     def from_bh(x, T):
@@ -371,6 +373,7 @@ def _flash_attention_fwd_impl(q, k, v, causal, scale, block_q, block_k,
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qb, kb, vb)
     out = out[:, :Tq].reshape(B, H, Tq, D).transpose(0, 2, 1, 3)
     return out, lse[:, :Tq, 0]
@@ -547,6 +550,7 @@ def mxu_node_histogram(bins_t, node, g, h, *, n_nodes: int,
             jax.ShapeDtypeStruct((F_pad * n_nodes, width), jnp.float32),
         ),
         interpret=interpret,
+        name="node_hist_mxu",
     )(bins_t.astype(jnp.int32), node.astype(jnp.int32),
       g.astype(jnp.float32), h.astype(jnp.float32))
     hg = hg.reshape(F_pad, n_nodes, width)[:F, :, :n_bins]
@@ -677,6 +681,7 @@ def _quant_predict_call(kernel, bins_t, scalar_args, n_class: int,
         out_shape=jax.ShapeDtypeStruct((n_class, bins_t.shape[1]),
                                        jnp.float32),
         interpret=interpret,
+        name="gbdt_predict_quant",
     )(*(a.reshape(-1) for a in scalar_args), bins_t)
     return out[:, :n].T
 
@@ -857,6 +862,7 @@ def histogram_fused(bins, grad, hess, n_bins: int = 256,
         out_shape=(jax.ShapeDtypeStruct((F, n_pad), jnp.float32),
                    jax.ShapeDtypeStruct((F, n_pad), jnp.float32)),
         interpret=interpret,
+        name="node_hist_fused",
     )(bins.astype(jnp.int32), grad.astype(jnp.float32).reshape(1, -1),
       hess.astype(jnp.float32).reshape(1, -1))
     return hg[:, :n_bins], hh[:, :n_bins]

@@ -87,11 +87,16 @@ class DevicePrefetcher:
 
     def __init__(self, source: Union[Iterable, Callable[[], Iterable]],
                  depth: int = 2, name: str = "prefetch",
-                 span: Optional[str] = None):
+                 span: Optional[str] = None, first_item: int = 0):
         if depth < 1:
             raise ValueError(f"prefetch depth must be >= 1, got {depth}")
         self.depth = depth
         self.name = name
+        #: the consumer's number for this prefetcher's first item: a span
+        #: carries ``item = first_item + <items produced before it>``, the
+        #: identifier the consumer's own spans of that item share (a fit
+        #: with one prefetcher an epoch numbers its steps across them)
+        self._first_item = first_item
         #: items the producer thread has finished placing (monotonic);
         #: lets tests assert a prefetched run actually ran ahead instead
         #: of degenerating to lockstep
@@ -130,8 +135,12 @@ class DevicePrefetcher:
                     return              # closed while waiting for a slot
                 t0 = time.perf_counter()
                 if self._span:
-                    with telemetry.trace.span(self._span, source=self.name):
+                    with telemetry.trace.span(
+                            self._span, source=self.name,
+                            item=self._first_item + self.items) as sp:
                         item = next(it, _DONE)
+                        if item is _DONE:
+                            sp.discard()    # no such item
                 else:
                     item = next(it, _DONE)
                 if item is _DONE:
@@ -206,7 +215,7 @@ class DevicePrefetcher:
 
 def prefetched(source: Union[Iterable, Callable[[], Iterable]],
                depth: int = 2, name: str = "prefetch",
-               span: Optional[str] = None) -> Iterator:
+               span: Optional[str] = None, first_item: int = 0) -> Iterator:
     """``DevicePrefetcher`` when ``depth >= 1``, the plain (synchronous)
     iterator when ``depth == 0`` — the one switch call sites need. The
     returned iterator always supports ``close()`` so consumer ``finally``
@@ -214,7 +223,8 @@ def prefetched(source: Union[Iterable, Callable[[], Iterable]],
     if depth <= 0:
         it = iter(source() if callable(source) else source)
         return _SyncIter(it)
-    return DevicePrefetcher(source, depth=depth, name=name, span=span)
+    return DevicePrefetcher(source, depth=depth, name=name, span=span,
+                            first_item=first_item)
 
 
 class _SyncIter:

@@ -10,7 +10,7 @@ Usage::
     _steps = telemetry.registry.counter("mmlspark_trainer_steps_total")
     ...
     _steps.inc()
-    with telemetry.trace.span("fit/step", step=i, sync=loss):
+    with telemetry.trace.span("fit/step", step=i):
         ...
 
 Off by default: a disabled metric mutator is one attribute lookup + return,

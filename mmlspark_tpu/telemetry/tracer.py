@@ -20,6 +20,15 @@ makes the span cover real device work at the cost of draining the dispatch
 queue (only ever paid when telemetry is enabled; a disabled span is a no-op
 context manager and never touches jax).
 
+Two clocks, one mechanism: an enabled span also opens a
+``jax.profiler.TraceAnnotation`` of the same name and attributes around its
+body, so while a profiler session runs (``jax.profiler.start_trace`` with
+``host_tracer_level >= 1``) every span lands in the capture's host plane,
+on the clock of the device planes' ``XLA Modules`` line — the program's
+side of a step beside the device's. With no session running the annotation
+costs one atomic load. ``instant()`` and ``complete()`` record after the
+fact; an annotation cannot be back-dated, so they stay ring-only.
+
 Export is JSON-lines — one event object per line — which Perfetto loads
 directly; for legacy chrome://tracing pass ``array=True`` to wrap the same
 events in the JSON-array trace format.
@@ -63,6 +72,8 @@ class _NoopSpan:
     """The disabled path: one shared instance, enter/exit do nothing."""
 
     __slots__ = ()
+    #: what a closed `_Span` reports; a disabled span measured nothing
+    seconds = 0.0
 
     def __enter__(self):
         return self
@@ -73,13 +84,16 @@ class _NoopSpan:
     def set_sync(self, value):
         pass
 
+    def discard(self):
+        pass
+
 
 _NOOP_SPAN = _NoopSpan()
 
 
 class _Span:
     __slots__ = ("_tracer", "name", "_sync", "_args", "_t0", "_ctx",
-                 "_parent_id")
+                 "_parent_id", "_annotation", "seconds")
 
     def __init__(self, tracer: "Tracer", name: str, sync, args: dict):
         self._tracer = tracer
@@ -88,6 +102,9 @@ class _Span:
         self._args = args
         self._ctx = None
         self._parent_id = None
+        #: the span's duration once closed, from the same two clock reads
+        #: as the recorded event (callers feed histograms from it)
+        self.seconds = 0.0
 
     def __enter__(self):
         parent = tracectx.current()
@@ -97,6 +114,11 @@ class _Span:
             self._ctx = parent.child()
             self._parent_id = parent.span_id
             tracectx._push(self._ctx)
+        # the profiler's clock: a no-op unless a profiler session is running
+        import jax.profiler
+        self._annotation = jax.profiler.TraceAnnotation(self.name,
+                                                        **self._args)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
@@ -105,15 +127,28 @@ class _Span:
         inside the span body, e.g. the loss a train step returns)."""
         self._sync = value
 
+    def discard(self):
+        """Record no event for this span: its body found there was nothing
+        to do (the ``next()`` that found a feed exhausted), and an event
+        would carry the identifier of work that never happened."""
+        self._tracer = None
+
     def __exit__(self, *exc):
-        if self._sync is not None:
-            import jax
-            jax.block_until_ready(self._sync)
-        end = time.perf_counter_ns()
+        try:
+            if self._sync is not None:
+                import jax
+                jax.block_until_ready(self._sync)
+        finally:
+            end = time.perf_counter_ns()
+            self._annotation.__exit__(*exc)
+        dur_ns = max(0, end - self._t0)
+        self.seconds = dur_ns / 1e9
         if self._ctx is not None:
             tracectx._pop()
+        if self._tracer is None:
+            return False
         ev = {"name": self.name, "ph": "X", "ts": self._t0 // 1000,
-              "dur": max(0, end - self._t0) // 1000,
+              "dur": dur_ns // 1000,
               "pid": os.getpid(), "tid": threading.get_ident()}
         args = self._args
         if self._ctx is not None:
