@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import telemetry
 from ..core.env import on_tpu
 
 NEG_INF = -1e30
@@ -44,16 +45,123 @@ def _interpret() -> bool:
 
 
 # ------------------------------------------------------------ flash attention
+#
+# The causal tile schedule. A kernel keeps one tile of one sequence resident
+# (Q rows in flash_fwd and flash_dq, K rows in flash_dkv) and walks the other
+# sequence. What is *copied* and what is *computed* are two tile sizes: the
+# BlockSpec block (block_q x block_k, what the grid steps over) stays large,
+# and inside the body a loop walks compute sub-tiles of the walked dimension.
+# Per sub-tile the diagonal decides: wholly above it, never computed (the
+# loop's bound stops short); crossed by it, or holding padded keys, computed
+# under a mask; wholly below it, computed bare: no iota, no compare, no
+# select. All of it is static in the shapes except where the diagonal falls
+# in a grid step, which is a scalar computed from the program ids.
+
+LANES = 128
+
+_m_subtiles = {
+    what: telemetry.registry.counter(
+        f"mmlspark_flash_subtiles_{what}",
+        f"flash attention compute sub-tiles a head, {what}, of the calls "
+        "built (the schedule is static in the shapes: counted at trace "
+        "time)", labels=("kernel",))
+    for what in ("total", "computed", "masked")}
+
+
+def flash_tile_counts(Tq, Tk, block_q, block_k, sub, causal):
+    """(total, computed, masked) compute sub-tiles of one head.
+
+    The copied tiles are block_q x block_k over the padded sequences; a
+    compute sub-tile is block_q x sub (``sub`` divides ``block_k``). A
+    sub-tile is *computed* unless it lies wholly above the diagonal of a
+    top-left-aligned causal mask or wholly in the key padding, and *masked*
+    where the diagonal crosses it or it holds padded keys; the other
+    computed ones run bare. flash_dkv walks Q sub-tiles of sub x block_k:
+    its counts are this function's at (Tq padded, Tk, sub, block_k, block_k).
+    """
+    nq = pl.cdiv(Tq, block_q)
+    n_sub = pl.cdiv(Tk, block_k) * (block_k // sub)
+    computed = masked = 0
+    for i in range(nq):
+        q0, q1 = i * block_q, (i + 1) * block_q - 1     # first, last row
+        for j in range(n_sub):
+            k0, k1 = j * sub, (j + 1) * sub - 1         # first, last key
+            if k0 >= Tk or (causal and k0 > q1):
+                continue
+            computed += 1
+            masked += k1 >= Tk or (causal and k1 > q0)
+    return nq * n_sub, computed, masked
+
+
+def _tile_valid(shape, q_dim, q0, k0, causal, seq_k):
+    """Which scores of a tile count: keys before ``seq_k`` (None where the
+    keys were not padded), at or under the top-left-aligned diagonal. The
+    tile's queries start at ``q0`` and run along dimension ``q_dim``, its
+    keys at ``k0`` along the other."""
+    kpos = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_dim)
+    valid = None if seq_k is None else kpos < seq_k - k0
+    if causal:
+        qpos = jax.lax.broadcasted_iota(jnp.int32, shape, q_dim)
+        under = qpos - kpos >= k0 - q0
+        valid = under if valid is None else jnp.logical_and(valid, under)
+    return valid
+
+
+def _lanes(x, n):
+    """(rows, LANES) whose lanes all hold their row's value -> (rows, n)."""
+    rows, w = x.shape
+    if n % w == 0:
+        return x if n == w else jnp.concatenate([x] * (n // w), axis=1)
+    return x[:, :n] if n < w else jnp.broadcast_to(x[:, :1], (rows, n))
+
+
+def _walk(lo, hi, body):
+    """Run ``body(j)`` for j in [lo, hi); the bounds may be traced scalars."""
+    jax.lax.fori_loop(lo, hi, lambda j, c: (body(j), c)[1], 0)
+
+
+def _sub_start(j, sub, n_sub):
+    return 0 if n_sub == 1 else pl.multiple_of(j * sub, sub)
+
+
+def _k_walk_bounds(q_start, k_start, block_q, block_k, sub, causal, seq_k):
+    """K sub-tiles [0, n_bare) of this grid step run bare, [n_bare, n_run)
+    masked, the rest not at all. Python ints where nothing is skipped or
+    masked (non-causal, keys not padded)."""
+    n_bare = n_run = block_k // sub
+    if seq_k is not None:          # valid keys from this block's first on
+        left = seq_k - k_start
+        n_run = jnp.minimum(n_run, jax.lax.div(left + sub - 1, sub))
+        n_bare = jnp.minimum(n_bare, jax.lax.div(left, sub))
+    if causal:
+        d = q_start - k_start      # how far the diagonal runs into the block
+        n_run = jnp.minimum(n_run, jax.lax.div(
+            jnp.maximum(d + block_q + sub - 1, 0), sub))
+        n_bare = jnp.minimum(n_bare, jax.lax.div(jnp.maximum(d + 1, 0), sub))
+    return n_bare, n_run
+
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                  *, block_q: int, block_k: int, causal: bool, scale: float,
-                  seq_k: int):
+                  *, block_q: int, block_k: int, sub: int, causal: bool,
+                  scale: float, seq_k, masked: bool):
     """Grid = (BH, num_q_blocks, num_k_blocks); KV innermost so the softmax
     state in scratch carries across the k dimension for one q block. Also
-    emits the row logsumexp (the residual the backward kernels need)."""
+    emits the row logsumexp (the residual the backward kernels need).
+
+    The running max ``m`` is kept replicated over 128 lanes and the running
+    sum ``l`` as 128 lane-wise partial sums (one lane where the sub-tile is
+    no multiple of 128): a sub-tile then costs one cross-lane reduction a
+    row (the max) and its rescales are plain elementwise products; the
+    partial sums meet once, in the finalize.
+
+    No row is ever fully masked when its state is read: the walk starts at
+    key 0, which every row sees under a top-left-aligned causal mask, and
+    never enters a sub-tile that holds only padded keys, so ``m`` is finite
+    from the first sub-tile on and the softmax needs no NEG_INF guards."""
     qi = pl.program_id(1)
     ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    n_sub = block_k // sub
+    lw = l_ref.shape[1]
 
     @pl.when(ki == 0)
     def _init():
@@ -63,58 +171,57 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
 
     q_start = qi * block_q
     k_start = ki * block_k
-    # causal: skip blocks strictly above the diagonal
-    run = (q_start + block_q - 1 >= k_start) if causal else True
+    # matmul operands stay in the input dtype (bf16 on chip): the MXU runs
+    # bf16xbf16->f32 at full rate, while f32 inputs force slow multi-pass
+    # emulation; accumulation is f32 either way
+    q = q_ref[0]                                       # (bq, D)
 
-    @pl.when(run)
-    def _compute():
-        # matmul operands stay in the input dtype (bf16 on chip): the MXU
-        # runs bf16xbf16->f32 at full rate, while f32 inputs force slow
-        # multi-pass emulation; accumulation is f32 either way
-        q = q_ref[0]                                   # (bq, D)
-        k = k_ref[0]                                   # (bk, D)
-        v = v_ref[0]
+    def step(j, mask):
+        k0 = _sub_start(j, sub, n_sub)
+        k = k_ref[0, pl.ds(k0, sub), :]                # (sub, D)
+        v = v_ref[0, pl.ds(k0, sub), :]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32,
-                                                  (block_q, block_k), 1)
-        valid = kpos < seq_k                            # mask KV padding
-        if causal:
-            qpos = q_start + jax.lax.broadcasted_iota(jnp.int32,
-                                                      (block_q, block_k), 0)
-            valid = jnp.logical_and(valid, qpos >= kpos)
-        s = jnp.where(valid, s, NEG_INF)
-
-        m_prev = m_ref[:, 0]                            # (bq,)
-        m_cur = jnp.max(s, axis=1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(m_new[:, None] <= NEG_INF / 2, 0.0, p)
+        if mask:
+            s = jnp.where(_tile_valid((block_q, sub), 0, q_start,
+                                      k_start + k0, causal, seq_k),
+                          s, NEG_INF)
+        m_prev = m_ref[:]                              # (bq, LANES)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_new, sub))
         corr = jnp.exp(m_prev - m_new)
-        corr = jnp.where(m_prev <= NEG_INF / 2, 0.0, corr)
-        l_ref[:, 0] = l_ref[:, 0] * corr + jnp.sum(p, axis=1)
-        m_ref[:, 0] = m_new
-        acc_ref[:] = (acc_ref[:] * corr[:, None]
+        l_ref[:] = l_ref[:] * _lanes(corr, lw) + (
+            jnp.sum(p, axis=1, keepdims=True) if lw == 1 else
+            functools.reduce(jnp.add, [p[:, i:i + lw]
+                                       for i in range(0, sub, lw)]))
+        m_ref[:] = m_new
+        acc_ref[:] = (acc_ref[:] * _lanes(corr, acc_ref.shape[1])
                       + jnp.dot(p.astype(v.dtype), v,
                                 preferred_element_type=jnp.float32))
 
-    @pl.when(ki == nk - 1)
+    n_bare, n_run = _k_walk_bounds(q_start, k_start, block_q, block_k, sub,
+                                   causal, seq_k)
+    _walk(0, n_bare, lambda j: step(j, False))
+    if masked:
+        _walk(n_bare, n_run, lambda j: step(j, True))
+
+    @pl.when(ki == pl.num_programs(2) - 1)
     def _finalize():
-        denom = jnp.maximum(l_ref[:, 0], 1e-30)[:, None]
-        o_ref[0] = (acc_ref[:] / denom).astype(o_ref.dtype)
-        lse = m_ref[:, 0] + jnp.log(jnp.maximum(l_ref[:, 0], 1e-30))
-        lse_ref[0] = jnp.where(m_ref[:, 0] <= NEG_INF / 2, NEG_INF,
-                               lse)[:, None]
+        l = jnp.sum(l_ref[:], axis=1, keepdims=True)
+        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
+        lse_ref[0] = m_ref[:, :1] + jnp.log(l)
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
                          dq_ref, acc_ref, *, block_q: int, block_k: int,
-                         causal: bool, scale: float, seq_k: int):
+                         sub: int, causal: bool, scale: float, seq_k,
+                         masked: bool):
     """dq = (P * (dO V^T - D)) K * scale, accumulated over KV blocks.
-    Grid = (BH, num_q_blocks, num_k_blocks), KV innermost."""
+    Grid = (BH, num_q_blocks, num_k_blocks), KV innermost; the K walk is
+    the forward's."""
     qi = pl.program_id(1)
     ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    n_sub = block_k // sub
 
     @pl.when(ki == 0)
     def _init():
@@ -122,48 +229,57 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
 
     q_start = qi * block_q
     k_start = ki * block_k
-    run = (q_start + block_q - 1 >= k_start) if causal else True
+    q = q_ref[0]                     # native dtype: full-rate MXU (see fwd)
+    do = do_ref[0]
+    lse = lse_ref[0]                                     # (bq, 1)
+    dvec = dvec_ref[0]
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0]                 # native dtype: full-rate MXU (see fwd)
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0][:, 0]                           # (bq,)
-        dvec = dvec_ref[0][:, 0]                         # (bq,)
+    def step(j, mask):
+        k0 = _sub_start(j, sub, n_sub)
+        k = k_ref[0, pl.ds(k0, sub), :]
+        v = v_ref[0, pl.ds(k0, sub), :]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32,
-                                                  (block_q, block_k), 1)
-        valid = kpos < seq_k
-        if causal:
-            qpos = q_start + jax.lax.broadcasted_iota(jnp.int32,
-                                                      (block_q, block_k), 0)
-            valid = jnp.logical_and(valid, qpos >= kpos)
-        s = jnp.where(valid, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])
-        p = jnp.where(lse[:, None] <= NEG_INF / 2, 0.0, p)   # padded q rows
+        if mask:
+            s = jnp.where(_tile_valid((block_q, sub), 0, q_start,
+                                      k_start + k0, causal, seq_k),
+                          s, NEG_INF)
+        # no guard on p: every real row's lse is finite (see the forward),
+        # and padded query rows carry q = 0, lse = 0, dO = 0, D = 0
+        p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - dvec[:, None])
+        ds = p * (dp - dvec)
         acc_ref[:] += jnp.dot(ds.astype(k.dtype), k,
                               preferred_element_type=jnp.float32)
 
-    @pl.when(ki == nk - 1)
+    n_bare, n_run = _k_walk_bounds(q_start, k_start, block_q, block_k, sub,
+                                   causal, seq_k)
+    _walk(0, n_bare, lambda j: step(j, False))
+    if masked:
+        _walk(n_bare, n_run, lambda j: step(j, True))
+
+    @pl.when(ki == pl.num_programs(2) - 1)
     def _finalize():
         dq_ref[0] = (acc_ref[:] * scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, block_q: int,
-                          block_k: int, causal: bool, scale: float,
-                          seq_k: int):
+                          block_k: int, sub: int, causal: bool, scale: float,
+                          seq_k, masked: bool):
     """dv = P^T dO; dk = (P * (dO V^T - D))^T Q * scale, accumulated over
-    Q blocks. Grid = (BH, num_k_blocks, num_q_blocks), Q innermost."""
+    Q blocks. Grid = (BH, num_k_blocks, num_q_blocks), Q innermost; the walk
+    is over Q sub-tiles: [lo, first_bare) masked, [first_bare, n_sub) bare,
+    those before ``lo`` (wholly above the diagonal) not at all.
+
+    The scores are formed transposed, keys on rows (K the left operand, lse
+    and D row vectors that broadcast over sublanes): P^T and dS^T then enter
+    their products as plain left operands, where (sub, bk) tiles of P and dS
+    would each pay a transpose."""
     ki = pl.program_id(1)
     qi = pl.program_id(2)
-    nq = pl.num_programs(2)
+    n_sub = block_q // sub
 
     @pl.when(qi == 0)
     def _init():
@@ -172,39 +288,44 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
 
     q_start = qi * block_q
     k_start = ki * block_k
-    run = (q_start + block_q - 1 >= k_start) if causal else True
+    k = k_ref[0]                     # native dtype: full-rate MXU (see fwd)
+    v = v_ref[0]
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0]                 # native dtype: full-rate MXU (see fwd)
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0][:, 0]
-        dvec = dvec_ref[0][:, 0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32,
-                                                  (block_q, block_k), 1)
-        valid = kpos < seq_k
-        if causal:
-            qpos = q_start + jax.lax.broadcasted_iota(jnp.int32,
-                                                      (block_q, block_k), 0)
-            valid = jnp.logical_and(valid, qpos >= kpos)
-        s = jnp.where(valid, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])
-        p = jnp.where(lse[:, None] <= NEG_INF / 2, 0.0, p)
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # (bk, D)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - dvec[:, None])
-        dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # (bk, D)
+    def step(j, mask):
+        q0 = _sub_start(j, sub, n_sub)
+        q = q_ref[0, pl.ds(q0, sub), :]                  # (sub, D)
+        do = do_ref[0, pl.ds(q0, sub), :]
+        row = pl.ds(0 if n_sub == 1 else j, 1)
+        lse = lse_ref[0, 0, row, :]                      # (1, sub)
+        dvec = dvec_ref[0, 0, row, :]
+        st = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32) * scale
+        if mask:
+            st = jnp.where(_tile_valid((block_k, sub), 1, q_start + q0,
+                                       k_start, causal, seq_k), st, NEG_INF)
+        pt = jnp.exp(st - lse)       # (bk, sub); no guard: as in flash_dq
+        dv_acc[:] += jnp.dot(pt.astype(do.dtype), do,
+                             preferred_element_type=jnp.float32)
+        dpt = jax.lax.dot_general(v, do, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        dst = pt * (dpt - dvec)
+        dk_acc[:] += jnp.dot(dst.astype(q.dtype), q,
+                             preferred_element_type=jnp.float32)
 
-    @pl.when(qi == nq - 1)
+    lo = first_bare = 0
+    if causal:
+        d = k_start - q_start        # how far the diagonal runs into the block
+        lo = jnp.minimum(n_sub, jax.lax.div(jnp.maximum(d, 0), sub))
+        first_bare = jnp.minimum(n_sub, jax.lax.div(
+            jnp.maximum(d + block_k + sub - 2, 0), sub))
+    if seq_k is not None:            # the last K block holds the padded keys
+        first_bare = jnp.where(ki == pl.num_programs(1) - 1, n_sub,
+                               first_bare)
+    if masked:
+        _walk(lo, first_bare, lambda j: step(j, True))
+    _walk(first_bare, n_sub, lambda j: step(j, False))
+
+    @pl.when(qi == pl.num_programs(2) - 1)
     def _finalize():
         dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -225,14 +346,28 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
     over the saved row logsumexp) — O(T) memory in both directions, the full
     FlashAttention recurrence.
 
-    Default blocks are head-dim and mask aware (``block_q/block_k=None``,
-    see ``_default_blocks``) and clamp to the sequence length for short
-    inputs. The D=128 contraction fills the MXU's 128-deep systolic array
-    where D=64 half-fills it, which is why transformer configs in this
-    repo default to head_dim 128 (packing two heads into one contraction
-    would sum cross-head scores, so the fix is model-level). The block
-    choices date from sweeps on an earlier runtime (ROADMAP S9 carries
-    the numbers and their provenance); on today's runtime: not measured.
+    The schedule: each kernel keeps one tile of one sequence resident
+    (queries in flash_fwd and flash_dq, keys in flash_dkv) and walks the
+    other, which is copied in large tiles (``block_q`` / ``block_k``, by
+    default a whole sequence of up to 4,096 rows, so K and V are fetched
+    once a head) and computed in 512-row sub-tiles. Under ``causal`` a
+    sub-tile wholly above the diagonal is neither computed nor its tile
+    fetched, one the diagonal crosses (or that holds padded keys) runs under
+    a mask, and every other runs bare; a non-causal call on unpadded lengths
+    holds no mask code at all. ``flash_tile_counts`` counts the three kinds
+    for a shape, and the ``mmlspark_flash_subtiles_*`` counters add them up
+    for every call built.
+
+    Measured on a v5e with ``tools/sweep_flash_blocks.py`` (my chip run,
+    PR 27; milliseconds a call and share of benchmark/flops/attention.py's
+    least time, forward | dq + dkv): (8, 2048, 16, 128) causal 1.35 ms 51.6%
+    | 3.85 ms 45.3%; (8, 4096, 4, 128) causal 1.11 ms 63.1% | 3.29 ms 53.1%,
+    non-causal 1.72 ms 81.3% | 5.30 ms 65.8%; (8, 4096, 8, 64) causal
+    2.22 ms 31.5% | 6.66 ms 26.2%. The D=128 contraction fills the MXU's
+    128-deep systolic array where D=64 half-fills it, which is why
+    transformer configs in this repo default to head_dim 128 (packing two
+    heads into one contraction would sum cross-head scores, so the fix is
+    model-level).
     """
     out, _ = _flash_attention_fwd_impl(q, k, v, causal, scale, block_q,
                                        block_k, interpret)
@@ -245,6 +380,122 @@ def _flash_attention_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
     return out, (q, k, v, out, lse)
 
 
+def _to_bh(x, pad):
+    """(B, T, H, D) -> (B*H, T + pad, D)."""
+    B, T, H, D = x.shape
+    x = x.transpose(0, 2, 1, 3).reshape(B * H, T, D)
+    return jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+
+
+def _from_bh(x, B, T):
+    """(B*H, T_padded, D) -> (B, T, H, D)."""
+    BH, _, D = x.shape
+    return x[:, :T].reshape(B, BH // B, T, D).transpose(0, 2, 1, 3)
+
+
+def _walked_block(causal, resident, walked, n_walked, first):
+    """Which walked block grid step (i, j) reads: block j, except that under
+    ``causal`` a step whose sub-tiles all lie above the diagonal names the
+    nearest block the resident block i does need, which is the one already
+    in VMEM: the pipeline then issues no copy for a step that computes
+    nothing. ``first``: the needed blocks start at the diagonal (flash_dkv
+    over Q) instead of ending there (flash_fwd, flash_dq over K)."""
+    if not causal:
+        return lambda i, j: j
+    if first:
+        return lambda i, j: jnp.maximum(j, jnp.minimum(
+            (i * resident) // walked, n_walked - 1))
+    return lambda i, j: jnp.minimum(j, (i * resident + resident - 1) // walked)
+
+
+_CALL_STATICS = ("blocks", "causal", "scale", "masked", "interpret")
+
+
+def _schedule(kernel, D, causal, Tq, Tk, block_q, block_k):
+    """(block_q, block_k, sub) of one call and whether it holds a masked
+    sub-tile; counts the call's sub-tiles. The three ``_*_call`` below are
+    jitted on these, so the calls of a model's layers are traced and
+    lowered once a program, not once a layer."""
+    bq, bk, sub = _default_blocks(D, causal, Tq, Tk, block_q, block_k, kernel)
+    counts = (flash_tile_counts(Tq + (-Tq) % bq, Tk, sub, bk, bk, causal)
+              if kernel == "flash_dkv" else
+              flash_tile_counts(Tq, Tk, bq, bk, sub, causal))
+    for what, n in zip(("total", "computed", "masked"), counts):
+        _m_subtiles[what].labels(kernel=kernel).inc(n)
+    return (bq, bk, sub), counts[2] > 0
+
+
+def _bwd_operands(q, k, v, do, lse, dvec, bq, bk):
+    """The backward kernels' operands padded to their blocks. Padded query
+    rows carry q = 0, dO = 0, D = 0 and lse = 0, so p is finite there and
+    dS, P^T dO vanish."""
+    pq, pk = (-q.shape[1]) % bq, (-k.shape[1]) % bk
+    return ((_to_bh(q, pq), _to_bh(k, pk), _to_bh(v, pk),
+             _to_bh(do.astype(q.dtype), pq)),
+            [jnp.pad(r, ((0, 0), (0, pq))) for r in (lse, dvec)],
+            k.shape[1] if pk else None)
+
+
+@functools.partial(jax.jit, static_argnames=_CALL_STATICS)
+def _dq_call(q, k, v, do, lse, dvec, *, blocks, causal, scale, masked,
+             interpret):
+    bq, bk, sub = blocks
+    B, Tq, H, D = q.shape
+    (qb, kb, vb, dob), rows, seq_k = _bwd_operands(q, k, v, do, lse, dvec,
+                                                   bq, bk)
+    nq, nk = qb.shape[1] // bq, kb.shape[1] // bk
+    k_block = _walked_block(causal, bq, bk, nk, first=False)
+    qspec = pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0))
+    kspec = pl.BlockSpec((1, bk, D), lambda b, i, j: (b, k_block(i, j), 0))
+    qrow = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0))
+    dq = pl.pallas_call(
+        functools.partial(_flash_bwd_dq_kernel, block_q=bq, block_k=bk,
+                          sub=sub, causal=causal, scale=scale, seq_k=seq_k,
+                          masked=masked),
+        grid=(B * H, nq, nk),
+        in_specs=[qspec, kspec, kspec, qspec, qrow, qrow],
+        out_specs=qspec,
+        out_shape=jax.ShapeDtypeStruct(qb.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        interpret=interpret,
+        name="flash_dq",
+    )(qb, kb, vb, dob, *(r[..., None] for r in rows))
+    return _from_bh(dq, B, Tq)
+
+
+@functools.partial(jax.jit, static_argnames=_CALL_STATICS)
+def _dkv_call(q, k, v, do, lse, dvec, *, blocks, causal, scale, masked,
+              interpret):
+    bq, bk, sub = blocks
+    B, Tq, H, D = q.shape
+    Tk = k.shape[1]
+    (qb, kb, vb, dob), rows, seq_k = _bwd_operands(q, k, v, do, lse, dvec,
+                                                   bq, bk)
+    nq, nk = qb.shape[1] // bq, kb.shape[1] // bk
+    # K blocks outer (the accumulators live per K block), Q blocks inner
+    q_block = _walked_block(causal, bk, bq, nq, first=True)
+    qspec = pl.BlockSpec((1, bq, D), lambda b, i, j: (b, q_block(i, j), 0))
+    kspec = pl.BlockSpec((1, bk, D), lambda b, i, j: (b, i, 0))
+    # lse and D as row vectors, one row a Q sub-tile
+    qrow = pl.BlockSpec((1, 1, bq // sub, sub),
+                        lambda b, i, j: (b, q_block(i, j), 0, 0))
+    dk, dv = pl.pallas_call(
+        functools.partial(_flash_bwd_dkv_kernel, block_q=bq, block_k=bk,
+                          sub=sub, causal=causal, scale=scale, seq_k=seq_k,
+                          masked=masked),
+        grid=(B * H, nk, nq),
+        in_specs=[qspec, kspec, kspec, qspec, qrow, qrow],
+        out_specs=(kspec, kspec),
+        out_shape=(jax.ShapeDtypeStruct(kb.shape, k.dtype),
+                   jax.ShapeDtypeStruct(vb.shape, v.dtype)),
+        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
+                        pltpu.VMEM((bk, D), jnp.float32)],
+        interpret=interpret,
+        name="flash_dkv",
+    )(qb, kb, vb, dob, *(r.reshape(B * H, nq, bq // sub, sub) for r in rows))
+    return _from_bh(dk, B, Tk), _from_bh(dv, B, Tk)
+
+
 def _flash_attention_bwd(causal, scale, block_q, block_k, interpret,
                          residuals, g):
     q, k, v, out, lse = residuals
@@ -252,83 +503,116 @@ def _flash_attention_bwd(causal, scale, block_q, block_k, interpret,
     Tk = k.shape[1]
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
     interpret = _interpret() if interpret is None else interpret
-    block_q, block_k = _default_blocks(D, causal, block_q, block_k)
-    # the (bq, bk) temporaries (S, P, dP, dS) quadruple the block footprint
-    # vs the forward — halve the blocks to stay inside scoped VMEM
-    block_q = min(block_q, 512, max(8, Tq))
-    block_k = min(block_k, 512, max(8, Tk))
-
-    def to_bh(x):
-        return x.transpose(0, 2, 1, 3).reshape(B * H, x.shape[1], D)
-
-    pq = (-Tq) % block_q
-    pk = (-Tk) % block_k
-    qb = jnp.pad(to_bh(q), ((0, 0), (0, pq), (0, 0)))
-    kb = jnp.pad(to_bh(k), ((0, 0), (0, pk), (0, 0)))
-    vb = jnp.pad(to_bh(v), ((0, 0), (0, pk), (0, 0)))
-    dob = jnp.pad(to_bh(g).astype(q.dtype), ((0, 0), (0, pq), (0, 0)))
     # D_i = rowsum(dO * O) — cheap elementwise residual
-    dvec = jnp.sum(to_bh(g).astype(jnp.float32)
-                   * to_bh(out).astype(jnp.float32), axis=-1)
-    dvec = jnp.pad(dvec, ((0, 0), (0, pq)))[..., None]   # (BH, Tq_pad, 1)
-    lse_b = jnp.pad(lse, ((0, 0), (0, pq)),
-                    constant_values=NEG_INF)[..., None]
-    nq = qb.shape[1] // block_q
-    nk = kb.shape[1] // block_k
+    dvec = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                   axis=-1).transpose(0, 2, 1).reshape(B * H, Tq)
 
-    common = dict(block_q=block_q, block_k=block_k, causal=causal,
-                  scale=scale, seq_k=Tk)
-    qspec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
-    kspec = pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0))
-    qrow = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, **common),
-        grid=(B * H, nq, nk),
-        in_specs=[qspec, kspec, kspec, qspec, qrow, qrow],
-        out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct(qb.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        interpret=interpret,
-        name="flash_dq",
-    )(qb, kb, vb, dob, lse_b, dvec)
+    def grad(kernel, call):
+        blocks, masked = _schedule(kernel, D, causal, Tq, Tk, block_q,
+                                   block_k)
+        return call(q, k, v, g, lse, dvec, blocks=blocks, causal=causal,
+                    scale=scale, masked=masked, interpret=interpret)
 
-    # dkv grid: K blocks outer, Q blocks inner (accumulators live per-K)
-    qspec_i = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, j, 0))
-    kspec_i = pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, i, 0))
-    qrow_i = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, j, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, **common),
-        grid=(B * H, nk, nq),
-        in_specs=[qspec_i, kspec_i, kspec_i, qspec_i, qrow_i, qrow_i],
-        out_specs=(kspec_i, kspec_i),
-        out_shape=(jax.ShapeDtypeStruct(kb.shape, k.dtype),
-                   jax.ShapeDtypeStruct(vb.shape, v.dtype)),
-        scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                        pltpu.VMEM((block_k, D), jnp.float32)],
-        interpret=interpret,
-        name="flash_dkv",
-    )(qb, kb, vb, dob, lse_b, dvec)
-
-    def from_bh(x, T):
-        return x[:, :T].reshape(B, H, T, D).transpose(0, 2, 1, 3)
-
-    return (from_bh(dq, Tq).astype(q.dtype),
-            from_bh(dk, Tk).astype(k.dtype),
-            from_bh(dv, Tk).astype(v.dtype))
+    return (grad("flash_dq", _dq_call), *grad("flash_dkv", _dkv_call))
 
 
 flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
 
 
-def _default_blocks(D, causal, block_q, block_k):
-    """Head-dim- and mask-aware default tiles: at D >= 128 the causal
-    path wants a half q block (512x1024) while the non-causal path wants
-    a deep k block (1024x2048); smaller D keeps 1024x1024."""
-    if D >= 128:
-        dq, dk = (512, 1024) if causal else (1024, 2048)
+_RESIDENT = 512        # rows of the tile a kernel keeps resident
+_SUB = 512             # rows of a compute sub-tile of the walked tile
+_WALKED_ROWS = 4096    # rows of a walked (copied) tile at D <= 128
+
+
+def _sub_tile(block, want):
+    """The widest of ``want``, ``want``/2, ... 128 that divides the copied
+    block; a block none divides (a sequence shorter than one sub-tile, an
+    odd explicit block) is one sub-tile."""
+    while want >= LANES:
+        if block % want == 0:
+            return want
+        want //= 2
+    return block
+
+
+def _default_blocks(D, causal, Tq, Tk, block_q=None, block_k=None,
+                    kernel="flash_fwd"):
+    """(block_q, block_k, sub) of one of the three kernels: the copied
+    tiles, clamped to the sequences, and the compute sub-tile of the walked
+    one (block_k in flash_fwd and flash_dq, block_q in flash_dkv).
+
+    Read on this runtime for the two-level kernels (my chip runs, PR 27,
+    ``tools/sweep_flash_blocks.py``, at (8, 2048, 16, 128) causal unless
+    said): the resident tile is 512 rows (1,024 taller wastes more above the
+    diagonal, forward 1.42 against 1.35 ms; 256 pays the per-row softmax
+    state twice as often, 1.65), 1,024 where there is no diagonal and
+    D >= 128 (4-7% at (8, 4096, 4, 128) non-causal); the walked tile is the
+    whole sequence in as few equal tiles of at most 4,096 rows as hold it
+    (one grid step a resident tile: forward 1.35 ms against 1.50 at 1,024
+    keys a tile, dq 1.71 against 2.26); the sub-tile is 512 rows (a narrower
+    one computes no fewer scores than the resident tile's height allows and
+    pays the per-sub-tile costs more often: forward 1.35 / 1.96 ms at 512 /
+    256, dq 1.71 / 2.11, dkv 2.14 / 2.79; a wider one computes more above
+    the diagonal: forward 1.56 at 1,024). Explicit ``block_q`` / ``block_k``
+    are the copied tiles as given.
+    """
+    walks_q = kernel == "flash_dkv"
+    (t_res, res), (t_walk, walk) = (((Tk, block_k), (Tq, block_q)) if walks_q
+                                    else ((Tq, block_q), (Tk, block_k)))
+    res = res or (_RESIDENT if causal or D < LANES else 2 * _RESIDENT)
+    if kernel != "flash_fwd":
+        # the backward's (resident, sub) temporaries (S, P, dP, dS) are
+        # four where the forward's are two
+        res = min(res, 2 * _RESIDENT)
+    res = min(res, max(8, t_res))
+    if walk:
+        walk = min(walk, max(8, t_walk))
+    elif t_walk <= _SUB:
+        walk = max(8, t_walk)
     else:
-        dq, dk = 1024, 1024
-    return block_q or dq, block_k or dk
+        # as few equal tiles as hold the sequence, each whole sub-tiles and
+        # at most _WALKED_ROWS rows of 128 lanes (1 MB an operand in bf16)
+        cap = max(_SUB, _WALKED_ROWS * LANES // max(D, LANES) // _SUB * _SUB)
+        n = pl.cdiv(t_walk, cap)
+        walk = pl.cdiv(pl.cdiv(t_walk, n), _SUB) * _SUB
+    sub = _sub_tile(walk, _SUB)
+    return (walk, res, sub) if walks_q else (res, walk, sub)
+
+
+@functools.partial(jax.jit, static_argnames=_CALL_STATICS)
+def _fwd_call(q, k, v, *, blocks, causal, scale, masked, interpret):
+    block_q, block_k, sub = blocks
+    B, Tq, H, D = q.shape
+    Tk = k.shape[1]
+    pq, pk = (-Tq) % block_q, (-Tk) % block_k
+    qb, kb, vb = _to_bh(q, pq), _to_bh(k, pk), _to_bh(v, pk)
+    nq, nk = qb.shape[1] // block_q, kb.shape[1] // block_k
+    kernel = functools.partial(_flash_kernel, block_q=block_q,
+                               block_k=block_k, sub=sub, causal=causal,
+                               scale=scale, seq_k=Tk if pk else None,
+                               masked=masked)
+    k_block = _walked_block(causal, block_q, block_k, nk, first=False)
+    qspec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
+    kspec = pl.BlockSpec((1, block_k, D),
+                         lambda b, i, j: (b, k_block(i, j), 0))
+    out, lse = pl.pallas_call(
+        kernel,
+        grid=(B * H, nq, nk),
+        in_specs=[qspec, kspec, kspec],
+        out_specs=(qspec,
+                   pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))),
+        out_shape=(jax.ShapeDtypeStruct(qb.shape, q.dtype),
+                   jax.ShapeDtypeStruct(qb.shape[:2] + (1,), jnp.float32)),
+        scratch_shapes=[
+            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, LANES), jnp.float32),
+            pltpu.VMEM((block_q, LANES if sub % LANES == 0 else 1),
+                       jnp.float32),
+        ],
+        interpret=interpret,
+        name="flash_fwd",
+    )(qb, kb, vb)
+    return _from_bh(out, B, Tq), lse[:, :Tq, 0]
 
 
 def _flash_attention_fwd_impl(q, k, v, causal, scale, block_q, block_k,
@@ -337,46 +621,10 @@ def _flash_attention_fwd_impl(q, k, v, causal, scale, block_q, block_k,
     Tk = k.shape[1]
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
     interpret = _interpret() if interpret is None else interpret
-    block_q, block_k = _default_blocks(D, causal, block_q, block_k)
-    block_q = min(block_q, max(8, Tq))
-    block_k = min(block_k, max(8, Tk))
-
-    def to_bh(x):     # (B, T, H, D) -> (B*H, T, D)
-        return x.transpose(0, 2, 1, 3).reshape(B * x.shape[2], x.shape[1], D)
-
-    pq = (-Tq) % block_q
-    pk = (-Tk) % block_k
-    qb = jnp.pad(to_bh(q), ((0, 0), (0, pq), (0, 0)))
-    kb = jnp.pad(to_bh(k), ((0, 0), (0, pk), (0, 0)))
-    vb = jnp.pad(to_bh(v), ((0, 0), (0, pk), (0, 0)))
-    nq = qb.shape[1] // block_q
-    nk = kb.shape[1] // block_k
-
-    kernel = functools.partial(_flash_kernel, block_q=block_q,
-                               block_k=block_k, causal=causal, scale=scale,
-                               seq_k=Tk)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(B * H, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=(pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-                   pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))),
-        out_shape=(jax.ShapeDtypeStruct(qb.shape, q.dtype),
-                   jax.ShapeDtypeStruct(qb.shape[:2] + (1,), jnp.float32)),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-        ],
-        interpret=interpret,
-        name="flash_fwd",
-    )(qb, kb, vb)
-    out = out[:, :Tq].reshape(B, H, Tq, D).transpose(0, 2, 1, 3)
-    return out, lse[:, :Tq, 0]
+    blocks, masked = _schedule("flash_fwd", D, causal, Tq, Tk, block_q,
+                               block_k)
+    return _fwd_call(q, k, v, blocks=blocks, causal=causal, scale=scale,
+                     masked=masked, interpret=interpret)
 
 
 # ------------------------------------------------------------ GBDT histogram

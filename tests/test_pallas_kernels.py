@@ -1,6 +1,8 @@
 """Pallas kernel correctness (interpret mode on the CPU mesh — the same
 kernels compile natively on TPU; the bench exercises that path)."""
 
+import sys
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -40,6 +42,194 @@ def test_flash_cross_attention_lengths(rng):
     out = flash_attention(q, k, v, block_q=8, block_k=8)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
+
+
+# The causal tile schedule: (Tq, Tk, block_q, block_k, sub). Lengths that pad
+# the one sequence, the other, both or neither; blocks the diagonal cuts
+# unevenly (block_q != block_k), sub-tiles smaller than the copied block, and
+# sequences shorter than one sub-tile.
+_SCHEDULES = [
+    pytest.param(32, 32, 8, 16, 8, id="even-sub<block"),
+    pytest.param(32, 32, 16, 8, 8, id="even-bq>bk"),
+    pytest.param(48, 48, 16, 24, 8, id="even-bq!=bk-diagonal-uneven"),
+    pytest.param(32, 32, 8, 32, 16, id="even-whole-k-resident"),
+    pytest.param(20, 32, 8, 16, 8, id="tq-padded"),
+    pytest.param(32, 20, 8, 16, 8, id="tk-padded-boundary-in-sub-tile"),
+    pytest.param(32, 24, 8, 16, 8, id="tk-padded-whole-sub-tile"),
+    pytest.param(20, 28, 8, 16, 8, id="both-padded-tq<tk"),
+    pytest.param(44, 20, 16, 8, 8, id="both-padded-tq>tk"),
+    pytest.param(12, 28, 8, 8, 8, id="tq<tk-one-level"),
+    pytest.param(5, 5, 8, 8, 8, id="shorter-than-a-sub-tile"),
+    pytest.param(5, 19, 16, 16, 16, id="tq-shorter-than-a-sub-tile"),
+]
+
+
+def _with_sub(monkeypatch, sub):
+    """The sub-tile is derived from the block (no argument carries it):
+    give the derivation test-sized widths."""
+    from mmlspark_tpu.ops import pallas_kernels as pk
+    monkeypatch.setattr(
+        pk, "_sub_tile",
+        lambda block, want: sub if block % sub == 0 else block)
+
+
+def _flash_vs_plain(Tq, Tk, bq, bk, causal, dtype, rng):
+    """Forward and the three gradients of both, in float32 numpy."""
+    import jax
+    D = 8
+    q = jnp.asarray(rng.normal(size=(2, Tq, 2, D)).astype(np.float32))
+    k = jnp.asarray(rng.normal(size=(2, Tk, 2, D)).astype(np.float32))
+    v = jnp.asarray(rng.normal(size=(2, Tk, 2, D)).astype(np.float32))
+    w = jnp.asarray(rng.normal(size=(2, Tq, 2, D)).astype(np.float32))
+
+    def run(attn, args):
+        def loss(q, k, v):
+            out = attn(q, k, v)
+            return jnp.sum(out.astype(jnp.float32) * w), out
+        (_, out), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True)(*args)
+        return [np.asarray(a, dtype=np.float32) for a in (out, *grads)]
+
+    got = run(lambda q, k, v: flash_attention(q, k, v, causal, None, bq, bk),
+              [a.astype(dtype) for a in (q, k, v)])
+    want = run(lambda q, k, v: plain_attention(q, k, v, causal=causal),
+               (q, k, v))
+    return got, want
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("Tq,Tk,bq,bk,sub", _SCHEDULES)
+def test_flash_schedule_f32(rng, monkeypatch, Tq, Tk, bq, bk, sub, causal):
+    _with_sub(monkeypatch, sub)
+    got, want = _flash_vs_plain(Tq, Tk, bq, bk, causal, jnp.float32, rng)
+    np.testing.assert_allclose(got[0], want[0], atol=1e-5, rtol=1e-5)
+    for g, r in zip(got[1:], want[1:]):
+        np.testing.assert_allclose(g, r, atol=2e-3, rtol=2e-3)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("Tq,Tk,bq,bk,sub", _SCHEDULES)
+def test_flash_schedule_bf16(rng, monkeypatch, Tq, Tk, bq, bk, sub, causal):
+    _with_sub(monkeypatch, sub)
+    got, want = _flash_vs_plain(Tq, Tk, bq, bk, causal, jnp.bfloat16, rng)
+    np.testing.assert_allclose(got[0], want[0], atol=3e-2, rtol=3e-2)
+    for g, r in zip(got[1:], want[1:]):
+        scale = max(1e-3, float(np.abs(r).max()))
+        np.testing.assert_allclose(g / scale, r / scale, atol=5e-2)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_real_sub_tiles(rng, causal):
+    """The derivation as it ships (no test-sized sub-tile): Tk = 1,100 in one
+    copied tile of 1,536 keys, whose third 512-wide sub-tile holds the
+    boundary; Tq = 200 shorter than a sub-tile."""
+    from mmlspark_tpu.ops.pallas_kernels import _default_blocks
+    assert _default_blocks(8, causal, 200, 1100) == (200, 1536, 512)
+    assert _default_blocks(8, causal, 200, 1100,
+                           kernel="flash_dkv") == (200, 512, 200)
+    q = jnp.asarray(rng.normal(size=(1, 200, 1, 8)).astype(np.float32))
+    k = jnp.asarray(rng.normal(size=(1, 1100, 1, 8)).astype(np.float32))
+    v = jnp.asarray(rng.normal(size=(1, 1100, 1, 8)).astype(np.float32))
+    ref = plain_attention(q, k, v, causal=causal)
+    out = flash_attention(q, k, v, causal)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("args,want", [
+    # T = 2,048 causal a head: today's one-level tiles, forward and backward
+    ((2048, 2048, 512, 1024, 1024, True), (8, 6, 4)),
+    ((2048, 2048, 512, 512, 512, True), (16, 10, 4)),
+    # two-level: 512 x 256 and 256 x 256 compute sub-tiles
+    ((2048, 2048, 512, 1024, 256, True), (32, 20, 8)),
+    ((2048, 2048, 512, 2048, 256, True), (32, 20, 8)),
+    ((2048, 2048, 256, 1024, 256, True), (64, 36, 8)),
+    # non-causal, unpadded: everything computed, nothing masked
+    ((2048, 2048, 512, 1024, 256, False), (32, 32, 0)),
+    ((4096, 4096, 1024, 2048, 512, False), (32, 32, 0)),
+    # Tk = 20 in 16-blocks of 8-sub-tiles: [16, 24) holds the boundary and
+    # is masked, [24, 32) only padding and is skipped, for each of 4 q blocks
+    ((32, 20, 8, 16, 8, False), (16, 12, 4)),
+    # Tk = 24: the padding is one whole sub-tile, nothing masked
+    ((32, 24, 8, 16, 8, False), (16, 12, 0)),
+    # top-left alignment with Tq < Tk: rows 0..11 see keys 0..11 only
+    ((12, 28, 8, 8, 8, True), (8, 3, 2)),
+    # a sequence shorter than one sub-tile: one masked sub-tile
+    ((5, 5, 8, 8, 8, True), (1, 1, 1)),
+])
+def test_flash_tile_counts(args, want):
+    from mmlspark_tpu.ops.pallas_kernels import flash_tile_counts
+    assert flash_tile_counts(*args) == want
+
+
+def test_flash_tile_counts_match_mask():
+    """The counts against the mask itself, tile by tile."""
+    from mmlspark_tpu.ops.pallas_kernels import flash_tile_counts
+    for Tq, Tk, bq, bk, sub, causal in [
+            (48, 48, 16, 24, 8, True), (20, 28, 8, 16, 8, True),
+            (44, 20, 16, 8, 8, True), (44, 20, 16, 8, 8, False)]:
+        Tqp, Tkp = -(-Tq // bq) * bq, -(-Tk // bk) * bk
+        keep = np.arange(Tkp)[None, :] < Tk
+        if causal:
+            keep = keep & (np.arange(Tqp)[:, None] >= np.arange(Tkp)[None, :])
+        keep = np.broadcast_to(keep, (Tqp, Tkp))
+        tiles = keep.reshape(Tqp // bq, bq, Tkp // sub, sub).transpose(
+            0, 2, 1, 3).reshape(-1, bq * sub)
+        # a tile wholly in the key padding is skipped though it holds no
+        # kept score *and* no dropped real one; under a causal mask a tile
+        # that keeps nothing is wholly above the diagonal or in the padding
+        computed = tiles.any(axis=1)
+        assert flash_tile_counts(Tq, Tk, bq, bk, sub, causal) == (
+            len(tiles), int(computed.sum()),
+            int((computed & ~tiles.all(axis=1)).sum()))
+
+
+def test_flash_noncausal_unpadded_has_no_mask_code():
+    """A non-causal call whose lengths are block multiples emits neither
+    body's mask: no iota anywhere in the three kernels' jaxprs."""
+    import jax
+    q = jnp.zeros((1, 256, 1, 8), jnp.float32)
+
+    def loss(q, k, v, causal):
+        return jnp.sum(flash_attention(q, k, v, causal, None, 128, 256))
+
+    def text(causal):
+        return str(jax.make_jaxpr(jax.grad(
+            lambda q, k, v: loss(q, k, v, causal), argnums=(0, 1, 2)))(
+                q, q, q))
+    assert text(False).count("pallas_call") == 3
+    assert "iota" not in text(False)
+    assert "iota" in text(True)          # the check can see a mask
+
+
+def test_flash_subtile_counters(monkeypatch):
+    """One increment per call built, labelled by kernel."""
+    import jax
+    from mmlspark_tpu import telemetry
+    from mmlspark_tpu.ops import pallas_kernels as pk
+    monkeypatch.setattr(sys.modules["mmlspark_tpu.telemetry.registry"]._state,
+                        "enabled", True)
+    names = ("total", "computed", "masked")
+
+    def read():
+        snap = telemetry.registry.snapshot()
+        return {(s["labels"]["kernel"], n): s["value"] for n in names
+                for s in snap[f"mmlspark_flash_subtiles_{n}"]["series"]}
+    before = read()
+    T, D = 2048, 128
+    q = jnp.zeros((1, T, 1, D), jnp.float32)
+    jax.make_jaxpr(jax.grad(lambda q: jnp.sum(
+        pk.flash_attention(q, q, q, True))))(q)
+    after = read()
+    for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
+        bq, bk, sub = pk._default_blocks(D, True, T, T, kernel=kernel)
+        want = (pk.flash_tile_counts(T, T, sub, bk, bk, True)
+                if kernel == "flash_dkv" else
+                pk.flash_tile_counts(T, T, bq, bk, sub, True))
+        assert want[0] > want[1] > want[2] > 0, (kernel, want)
+        got = tuple(after[kernel, n] - before.get((kernel, n), 0.0)
+                    for n in names)
+        assert got == want, (kernel, got, want)
 
 
 def test_histogram_matches_numpy(rng):

@@ -1,40 +1,149 @@
+"""Time the three flash attention kernels on the chip, a kernel at a time.
+
+    python3 tools/sweep_flash_blocks.py                  # the blocks as shipped
+    python3 tools/sweep_flash_blocks.py --shape cell \
+        --fwd 512x1024x256,512x2048x512 --dq 512x512x256 --dkv 512x512x256
+
+Each line is one kernel of one traced program: milliseconds a call from the
+device trace's `XLA Ops` line (read with the benchmark's own reduction and
+told apart by the benchmark's own patterns) and the share of
+`benchmark/flops/attention.py`'s least time, so a reading here and
+`flash_fwd_roofline` / `flash_bwd_roofline` of the cgpt cell are the same
+quantity (the backward's share there is dq and dkv together, as `bwd` here).
+`--fwd` / `--dq` / `--dkv` take `block_q x block_k x sub` candidates and put
+them in `_default_blocks`' place for that kernel, the others as shipped.
+No cell runs this; it fails where JAX finds no TPU.
+"""
+
+import argparse
+import glob
+import json
 import os
 import sys
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-import time
+import tempfile
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
 import jax
 import jax.numpy as jnp
 import numpy as np
-from mmlspark_tpu.ops.pallas_kernels import flash_attention
 
-B, T, H, D = 8, 4096, 4, 128   # same H*D=512 as the round-4 (8,4096,8,64)
-rng = np.random.default_rng(0)
-q = jnp.asarray(rng.normal(size=(B, T, H, D)).astype(np.float32)).astype(jnp.bfloat16)
-k = jnp.asarray(rng.normal(size=(B, T, H, D)).astype(np.float32)).astype(jnp.bfloat16)
-v = jnp.asarray(rng.normal(size=(B, T, H, D)).astype(np.float32)).astype(jnp.bfloat16)
+from benchmark import trace_reduce
+from benchmark.flops import attention
+from benchmark.layer_metrics import flash_bwd_roofline, flash_fwd_roofline
+from mmlspark_tpu.ops import pallas_kernels as pk
 
-def tfs(dt, causal):
-    fl = 4 * B * T * T * H * D * (0.5 if causal else 1.0)
-    return fl / dt / 1e12
+SHAPES = {                      # B, T, H, D, causal
+    "cell": (8, 2048, 16, 128, True),       # cgpt1p3b_train_stream's
+    "long": (8, 4096, 4, 128, True),        # chip_smoke stage D's family
+    "long_nc": (8, 4096, 4, 128, False),
+    "d64": (8, 4096, 8, 64, True),
+}
+KERNELS = {"flash_fwd": flash_fwd_roofline.KERNEL,
+           "flash_dq": flash_bwd_roofline.KERNEL_DQ,
+           "flash_dkv": flash_bwd_roofline.KERNEL_DKV}
+CALLS = 6
 
-for causal in (True, False):
-    for bq, bk in ((512, 512), (512, 1024), (1024, 512), (1024, 1024),
-                   (2048, 512), (1024, 2048)):
-        try:
-            @jax.jit
-            def loop(qx):
-                def body(i, carry):
-                    o = flash_attention(carry, k, v, causal, None, bq, bk)
-                    return o * 1e-3 + carry * (1 - 1e-3)  # data dependence
-                return jax.lax.fori_loop(0, 20, body, qx)
-            r = loop(q)
-            float(jnp.sum(r.astype(jnp.float32)))
-            t0 = time.perf_counter()
-            r = loop(q)
-            float(jnp.sum(r.astype(jnp.float32)))
-            dt = (time.perf_counter() - t0) / 20
-            print(f"causal={causal} {bq}x{bk}: {dt*1e3:7.2f} ms "
-                  f"{tfs(dt, causal):6.1f} TF/s", flush=True)
-        except Exception as e:
-            print(f"causal={causal} {bq}x{bk}: {type(e).__name__} "
-                  f"{str(e)[:80]}", flush=True)
+
+def kernel_ms(fn, args):
+    """{kernel: milliseconds a call} of one traced run of `fn`."""
+    jax.block_until_ready(fn(*args))                  # compile, warm
+    with tempfile.TemporaryDirectory() as d:
+        jax.profiler.start_trace(d)
+        for _ in range(CALLS):
+            jax.block_until_ready(fn(*args))
+        jax.profiler.stop_trace()
+        path, = glob.glob(os.path.join(d, "plugins/profile/*/*.xplane.pb"))
+        trace = trace_reduce.summarise(trace_reduce.load_events(path))
+    out = {}
+    for name, pattern in KERNELS.items():
+        seconds, calls = trace_reduce.kernel_time(trace, pattern)
+        if calls:
+            out[name] = 1e3 * seconds / calls
+    return out
+
+
+def measure(shape, override=None):
+    """{kernel: blocks used}, {kernel: ms a call}: the forward from a
+    forward-only program, dq and dkv from a gradient program; under
+    `override` ({kernel: (block_q, block_k, sub)}) only that kernel's."""
+    B, T, H, D, causal = SHAPES[shape]
+    rng = np.random.default_rng(0)
+    q, k, v = (jnp.asarray(rng.normal(size=(B, T, H, D)), jnp.bfloat16)
+               for _ in range(3))
+    shipped = pk._default_blocks
+
+    def blocks(D, causal, Tq, Tk, block_q=None, block_k=None,
+               kernel="flash_fwd"):
+        if override and kernel in override:
+            return override[kernel]
+        return shipped(D, causal, Tq, Tk, block_q, block_k, kernel)
+
+    pk._default_blocks = blocks
+    try:
+        used = {n: blocks(D, causal, T, T, kernel=n) for n in KERNELS}
+        ms = {}
+        if not override or "flash_fwd" in override:
+            fwd = jax.jit(lambda q, k, v: pk.flash_attention(q, k, v, causal))
+            ms.update(kernel_ms(fwd, (q, k, v)))
+        if not override or "flash_fwd" not in override:
+            grad = jax.jit(jax.grad(
+                lambda q, k, v: jnp.sum(pk.flash_attention(q, k, v, causal)
+                                        .astype(jnp.float32)),
+                argnums=(0, 1, 2)))
+            ms.update({n: t for n, t in kernel_ms(grad, (q, k, v)).items()
+                       if n != "flash_fwd"})
+    finally:
+        pk._default_blocks = shipped
+    return used, ms
+
+
+def report(shape, used, ms, peaks):
+    B, T, H, D, causal = SHAPES[shape]
+    line = {"shape": shape,
+            "blocks": {n: "x".join(map(str, used[n])) for n in ms}}
+    line.update({n + "_ms": t for n, t in ms.items()})
+    if "flash_fwd" in ms:
+        least, _ = attention.least_seconds(
+            *attention.flash_fwd(B, H, T, D, causal), peaks)
+        line["fwd_share_pct"] = 100 * 1e3 * least / ms["flash_fwd"]
+    if "flash_dq" in ms and "flash_dkv" in ms:
+        least, _ = attention.least_seconds(
+            *attention.flash_bwd(B, H, T, D, causal), peaks)
+        line["bwd_ms"] = ms["flash_dq"] + ms["flash_dkv"]
+        line["bwd_share_pct"] = 100 * 1e3 * least / line["bwd_ms"]
+    print(json.dumps(line), flush=True)
+
+
+def parse(text):
+    return [tuple(int(x) for x in c.split("x")) for c in text.split(",") if c]
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--shape", default=",".join(SHAPES))
+    for kernel in KERNELS:
+        ap.add_argument("--" + kernel[len("flash_"):], default="", type=parse)
+    args = ap.parse_args()
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        sys.exit(f"needs a TPU, found {dev.platform}")
+    with open(os.path.join(ROOT, "benchmark", "peaks.json")) as f:
+        peaks = json.load(f)[dev.device_kind]
+    print(json.dumps({"device": dev.device_kind, "calls": CALLS}), flush=True)
+    for shape in args.shape.split(","):
+        report(shape, *measure(shape), peaks)
+        for kernel in KERNELS:
+            for cand in getattr(args, kernel[len("flash_"):]):
+                try:
+                    report(shape, *measure(shape, {kernel: cand}), peaks)
+                except Exception as e:      # a candidate Mosaic refuses
+                    print(json.dumps({"shape": shape, "kernel": kernel,
+                                      "blocks": cand, "error":
+                                      f"{type(e).__name__}: {str(e)[:200]}"}),
+                          flush=True)
+
+
+if __name__ == "__main__":
+    main()
