@@ -472,7 +472,7 @@ class TransformerEncoder(nn.Module):
 # ---------------------------------------------------------------- registry
 
 # families whose input is int token ids (callers must cast features to int32)
-TOKEN_MODELS = ("bilstm", "transformer")
+TOKEN_MODELS = ("bilstm", "transformer", "kimi_linear")
 
 MODEL_BUILDERS: dict[str, Callable[..., nn.Module]] = {
     "mlp": lambda cfg: MLPNet(
@@ -528,7 +528,24 @@ MODEL_BUILDERS: dict[str, Callable[..., nn.Module]] = {
         remat=cfg.get("remat", False),
         dtype=jnp.dtype(cfg.get("dtype", jnp.bfloat16)),
         attn_fn=attn_fn, mesh=mesh),
+    # the hybrid family: delta-rule linear attention, NoPE latent attention,
+    # dropless share-aware experts (models/kimi_linear.py; keys as in the
+    # model's public config.json)
+    "kimi_linear": lambda cfg: _kimi_linear(cfg),   # defined below
 }
+
+
+def _kimi_linear(cfg):
+    from .kimi_linear import build
+    return build(cfg)
+
+
+def has_experts(config: dict) -> bool:
+    """Whether the configuration's model routes tokens over experts: the
+    families that read ``num_experts`` (other configs may carry the key),
+    which are also the ones whose ``__call__`` takes a ``row_mask``."""
+    return (config.get("type") in ("transformer", "kimi_linear")
+            and config.get("num_experts", 0) > 0)
 
 
 def build_model(config: dict, attn_fn: Optional[Callable] = None,

@@ -13,12 +13,15 @@ Routing/auxiliary math runs in float32; expert matmuls in bfloat16.
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from .. import telemetry
 
 
 class MoEMLP(nn.Module):
@@ -117,3 +120,282 @@ def read_moe_aux_loss(intermediates) -> jnp.ndarray:
         if any("moe_aux_loss" in str(getattr(p, "key", p)) for p in path):
             total = total + jnp.sum(leaf)
     return total
+
+
+# ------------------------------------------------ dropless, share-aware
+
+#: rows of one grouped product: a tile holds tokens of one expert
+GROUP_TILE = 256
+#: the tile walk's floor, in uniform shares: a layer walks at least the tiles
+#: that twice its share of evenly routed tokens would fill, so a step's time
+#: is the same whatever the routing until the load passes that. At a fresh
+#: init the held experts' load swings 0.5-1.9 shares by the seed (PERF.md
+#: section 6, PR 28); a balanced load pays for the tiles it leaves empty
+GROUP_FLOOR_SHARES = 2
+
+
+def _tiles(token, weight, counts, min_tiles, n_tokens):
+    """The walk over the assignments sorted by held expert, in tiles of
+    GROUP_TILE of one expert: (number of tiles, tile t -> (expert, first
+    row, which rows are real, their tokens, their weights or 0)). Every
+    expert's group is cut into ceil(count / tile) tiles, expert after
+    expert; the lists are padded by one tile, so a tile that begins at a
+    real assignment never runs past their end. The walk has at least
+    `min_tiles` tiles: those after the routing's own have no real row. A row
+    that is not real carries the token `n_tokens`, one past the last."""
+    tile = GROUP_TILE
+    tiles = (counts + tile - 1) // tile
+    ends, first = jnp.cumsum(tiles), jnp.cumsum(counts) - counts
+    token, weight = jnp.pad(token, (0, tile)), jnp.pad(weight, (0, tile))
+
+    def fetch(t):
+        e = jnp.minimum(jnp.sum(t >= ends), counts.shape[0] - 1)
+        off = (t - (ends[e] - tiles[e])) * tile
+        start = first[e] + off
+        valid = off + jnp.arange(tile) < counts[e]
+        tok = jnp.where(valid, lax.dynamic_slice_in_dim(token, start, tile),
+                        n_tokens)
+        w = jnp.where(valid, lax.dynamic_slice_in_dim(weight, start, tile),
+                      0.0)
+        return e.astype(jnp.int32), start, valid, tok, w
+
+    return jnp.maximum(ends[-1], min_tiles), fetch
+
+
+def _take(x, tok):
+    """Rows `tok` of x; a row past the end reads as zeros."""
+    return x.at[tok].get(mode="fill", fill_value=0)
+
+
+def _add(y, tok, rows):
+    """y with `rows` added at `tok`; a row past the end is left out."""
+    return y.at[tok].add(rows, mode="drop")
+
+
+def _expert(w, e):
+    return lax.dynamic_index_in_dim(w, e, 0, keepdims=False)
+
+
+@jax.custom_vjp
+def grouped_expert_mlp(x, w_gate, w_up, w_down, token, weight, counts,
+                       min_tiles):
+    """SwiGLU experts over the tokens routed to them, nothing dropped.
+
+    x: (N, d) tokens. w_gate, w_up: (E, d, f); w_down: (E, f, d): the E
+    experts held here. `token` (R,) int32 and `weight` (R,) float32 list the
+    assignments (token, combine weight) sorted by held expert, `counts` (E,)
+    how many belong to each; entries after sum(counts) are ignored. Returns
+    (y, rows): y (N, d) float32 = sum over a token's assignments of weight *
+    expert(x), and the number of assignments computed (== sum(counts)).
+
+    The work is a `while` over tiles of GROUP_TILE assignments of one expert
+    whose trip count is the number of tiles the routing needs, and at least
+    `min_tiles`: shapes are static (R is the worst case, every token on every
+    held expert), nothing is dropped however uneven the routing, and up to
+    `min_tiles` the time does not follow the load (a tile with no real row
+    costs what a full one does). The backward pass is the same walk (a
+    dynamic trip count has no transpose of its own); it recomputes a tile's
+    hidden activations."""
+    return _grouped_fwd(x, w_gate, w_up, w_down, token, weight, counts,
+                        min_tiles)[0]
+
+
+def _grouped_fwd(x, w_gate, w_up, w_down, token, weight, counts, min_tiles):
+    n_tiles, fetch = _tiles(token, weight, counts, min_tiles, x.shape[0])
+
+    def body(c):
+        t, y, rows = c
+        e, _, valid, tok, w = fetch(t)
+        xs = _take(x, tok)
+        a = xs @ _expert(w_gate, e)
+        b = xs @ _expert(w_up, e)
+        o = jnp.dot((jax.nn.silu(a) * b), _expert(w_down, e),
+                    preferred_element_type=jnp.float32)
+        y = _add(y, tok, o * w[:, None])
+        return t + 1, y, rows + jnp.sum(valid, dtype=jnp.int32)
+
+    _, y, rows = lax.while_loop(
+        lambda c: c[0] < n_tiles, body,
+        (jnp.int32(0), jnp.zeros(x.shape, jnp.float32), jnp.int32(0)))
+    return (y, rows), (x, w_gate, w_up, w_down, token, weight, counts,
+                       min_tiles)
+
+
+def _grouped_bwd(res, cts):
+    x, w_gate, w_up, w_down, token, weight, counts, min_tiles = res
+    dy = cts[0].astype(x.dtype)
+    n_tiles, fetch = _tiles(token, weight, counts, min_tiles, x.shape[0])
+    f32 = jnp.float32
+
+    def body(c):
+        t, dx, dg, du, dd, dwt = c
+        e, start, valid, tok, w = fetch(t)
+        xs = _take(x, tok)
+        wg, wu, wd = _expert(w_gate, e), _expert(w_up, e), _expert(w_down, e)
+        a = jnp.dot(xs, wg, preferred_element_type=f32)
+        b = jnp.dot(xs, wu, preferred_element_type=f32)
+        sig = jax.nn.sigmoid(a)
+        h = (a * sig * b).astype(x.dtype)
+        do = _take(dy, tok)
+        # the combine weight's gradient: <dy, expert(x)>, real rows only
+        o = jnp.dot(h, wd, preferred_element_type=f32)
+        dw = jnp.where(valid, jnp.sum(o * do.astype(f32), axis=-1), 0.0)
+        dwt = lax.dynamic_update_slice_in_dim(dwt, dw, start, 0)
+        do = (do.astype(f32) * w[:, None]).astype(x.dtype)
+        dh = jnp.dot(do, wd.T, preferred_element_type=f32)
+        da = (dh * b * sig * (1.0 + a * (1.0 - sig))).astype(x.dtype)
+        db = (dh * a * sig).astype(x.dtype)
+        dd = dd.at[e].add(jnp.dot(h.T, do, preferred_element_type=f32))
+        dg = dg.at[e].add(jnp.dot(xs.T, da, preferred_element_type=f32))
+        du = du.at[e].add(jnp.dot(xs.T, db, preferred_element_type=f32))
+        dxs = (jnp.dot(da, wg.T, preferred_element_type=f32)
+               + jnp.dot(db, wu.T, preferred_element_type=f32))
+        return t + 1, _add(dx, tok, dxs), dg, du, dd, dwt
+
+    R = token.shape[0]
+    init = (jnp.int32(0), jnp.zeros(x.shape, f32),
+            jnp.zeros(w_gate.shape, f32), jnp.zeros(w_up.shape, f32),
+            jnp.zeros(w_down.shape, f32), jnp.zeros((R + GROUP_TILE,), f32))
+    _, dx, dg, du, dd, dwt = lax.while_loop(lambda c: c[0] < n_tiles, body,
+                                            init)
+    return (dx.astype(x.dtype), dg.astype(w_gate.dtype),
+            du.astype(w_up.dtype), dd.astype(w_down.dtype), None,
+            dwt[:R].astype(weight.dtype), None, None)
+
+
+grouped_expert_mlp.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+_m_experts_held = telemetry.registry.counter(
+    "mmlspark_moe_experts_held",
+    "experts held here by the dropless expert layers built (static in the "
+    "configuration: counted at trace time)", labels=("layer",))
+_m_router_width = telemetry.registry.counter(
+    "mmlspark_moe_router_width",
+    "router outputs (experts routed over, here or elsewhere) of the dropless "
+    "expert layers built (counted at trace time)", labels=("layer",))
+
+#: what a dropless expert layer reports a step, in this order
+MOE_STEP_STATS = ("moe_tokens_routed", "moe_expert_tokens_max",
+                  "moe_tokens_dropped")
+
+
+_m_step = {
+    "moe_tokens_routed": telemetry.registry.counter(
+        "mmlspark_moe_tokens_routed_total",
+        "assignments (token, expert) routed to the experts held here, over "
+        "the steps read and the expert layers"),
+    "moe_tokens_dropped": telemetry.registry.counter(
+        "mmlspark_moe_tokens_dropped_total",
+        "assignments routed to the experts held here and not computed, over "
+        "the steps read: a dropless layer reads 0"),
+    "moe_expert_tokens_max": telemetry.registry.gauge(
+        "mmlspark_moe_expert_tokens_max",
+        "the fullest held expert's assignments, of the last step read"),
+}
+
+
+def observe_step_stats(values: dict):
+    """A finished step's `MOE_STEP_STATS` (host ints) into the registry."""
+    for name, metric in _m_step.items():
+        if name.endswith("_max"):
+            metric.set(values[name])
+        else:
+            metric.inc(values[name])
+
+
+class SwiGLU(nn.Module):
+    """down(silu(gate(x)) * up(x)), no biases."""
+    d_hidden: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        d = x.shape[-1]
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype)
+        h = nn.silu(dense(self.d_hidden, name="gate")(x)) \
+            * dense(self.d_hidden, name="up")(x)
+        return dense(d, name="down")(h)
+
+
+class DroplessMoE(nn.Module):
+    """One expert-parallel rank's part of a token-choice expert layer that
+    drops nothing: (B, T, d) -> ((B, T, d), stats).
+
+    The router scores every token against all `router_width` experts of the
+    deployment in float32 (sigmoid scores; the top `top_k` by score +
+    `selection_bias`, ties to the lower index; combine weights the chosen
+    scores, renormalised to sum 1 where `renormalize`, times
+    `routed_scale`). The layer holds the `num_experts` experts from
+    `first_expert` on and computes their part of the result, for however
+    many tokens chose them (`grouped_expert_mlp`); what the experts held
+    elsewhere would add is left out, and no exchange stands in for it. Each
+    of `num_shared` shared experts sees every token. No capacity, no
+    auxiliary loss (the family balances through `selection_bias`, a
+    parameter that starts at zero and takes no gradient: its update rule is
+    the trainer's to bring).
+
+    `stats` is int32[3], `MOE_STEP_STATS`: assignments routed to the held
+    experts, the fullest held expert's, and routed minus computed (0)."""
+    num_experts: int              # held here
+    router_width: int             # routed over
+    d_hidden: int
+    top_k: int = 8
+    first_expert: int = 0
+    num_shared: int = 1
+    renormalize: bool = True
+    routed_scale: float = 1.0
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x, row_mask=None):
+        B, T, d = x.shape
+        N, E, W, k = B * T, self.num_experts, self.router_width, self.top_k
+        if not 0 <= self.first_expert <= W - E:
+            raise ValueError(f"experts {self.first_expert}.."
+                             f"{self.first_expert + E - 1} are not among the "
+                             f"router's {W}")
+        _m_experts_held.labels(layer="/".join(self.path)).inc(E)
+        _m_router_width.labels(layer="/".join(self.path)).inc(W)
+        xf = x.reshape(N, d)
+
+        router = self.param("router", nn.initializers.lecun_normal(), (d, W),
+                            jnp.float32)
+        bias = lax.stop_gradient(self.param(
+            "selection_bias", nn.initializers.zeros, (W,), jnp.float32))
+        scores = jax.nn.sigmoid(jnp.dot(
+            xf.astype(jnp.float32), router, precision=lax.Precision.HIGHEST))
+        _, chosen = lax.top_k(scores + bias, k)                  # (N, k)
+        weight = jnp.take_along_axis(scores, chosen, axis=-1)
+        if self.renormalize:
+            weight = weight / (weight.sum(-1, keepdims=True) + 1e-20)
+        weight = weight * self.routed_scale
+
+        # the assignments to the experts held here, sorted by expert
+        local = chosen - self.first_expert
+        held = (local >= 0) & (local < E)
+        if row_mask is not None:       # padded rows are routed nowhere
+            held &= jnp.repeat(row_mask > 0, T)[:, None]
+        local = jnp.where(held, local, E).reshape(-1)
+        order = jnp.argsort(local, stable=True)
+        counts = jnp.sum(local[:, None] == jnp.arange(E), axis=0,
+                         dtype=jnp.int32)
+        init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
+                                            batch_axis=(0,))
+        w_gate, w_up, w_down = (
+            self.param(n, init, shape, jnp.float32).astype(self.dtype)
+            for n, shape in (("expert_gate", (E, d, self.d_hidden)),
+                             ("expert_up", (E, d, self.d_hidden)),
+                             ("expert_down", (E, self.d_hidden, d))))
+        min_tiles = -(-GROUP_FLOOR_SHARES * N * k * E // (W * GROUP_TILE))
+        y, computed = grouped_expert_mlp(
+            xf.astype(self.dtype), w_gate, w_up, w_down,
+            (order // k).astype(jnp.int32), weight.reshape(-1)[order], counts,
+            min_tiles)
+        y = y.astype(self.dtype)
+        for i in range(self.num_shared):
+            y = y + SwiGLU(self.d_hidden, self.dtype,
+                           name=f"shared{i}")(xf)
+        routed = jnp.sum(counts)
+        stats = jnp.stack([routed, jnp.max(counts), routed - computed])
+        return y.reshape(B, T, d).astype(x.dtype), stats

@@ -20,6 +20,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import os
+import time
 from typing import Optional
 
 import jax
@@ -39,7 +40,7 @@ from ..parallel import sequence
 from .. import telemetry
 from ..resilience import faults
 from ..resilience.policy import RetryPolicy
-from .modules import TOKEN_MODELS, build_model
+from .modules import TOKEN_MODELS, build_model, has_experts
 from .tpu_model import TpuModel, _prep_input
 
 log = get_logger("trainer")
@@ -83,16 +84,41 @@ class _StepsInFlight:
     exists only in a fit that started with telemetry on."""
 
     def __init__(self):
+        #: (loss, step, the step program's per-step counts or None): the
+        #: counts of the models that return any (`step_stat_names`) are read
+        #: once their loss is ready, so never waited for either
         self.losses = collections.deque()
 
-    def count(self) -> int:
-        while self.losses and self.losses[0].is_ready():
-            self.losses.popleft()
+    def add(self, step: int, loss, stats=None):
+        self.losses.append((loss, step, stats))
+
+    def count(self, finished: bool = False) -> int:
+        while self.losses and (finished or self.losses[0][0].is_ready()):
+            _, step, stats = self.losses.popleft()
+            if stats is not None:
+                _observe_step_stats(step, stats)
         return len(self.losses)
+
+    def clear(self):
+        """The fit has read its last loss: every step is in, whatever a
+        poll would say."""
+        self.count(finished=True)
+
+
+def _observe_step_stats(step: int, stats: dict):
+    """A finished step's counts (outputs of the step program, on the device
+    until here) into the registry and, numbered by `step`, the ring."""
+    from .moe import observe_step_stats
+    values = {k: int(v) for k, v in jax.device_get(stats).items()}
+    observe_step_stats(values)
+    now = time.perf_counter_ns()
+    telemetry.trace.complete("fit/step_stats", now, end_ns=now, step=step,
+                             **values)
 
 
 def _dispatch_step(train_step, params, opt_state, scale_state, xb, yb, wb, *,
-                   step, in_flight, fused=None, elastic_ctx=None):
+                   step, in_flight, fused=None, elastic_ctx=None,
+                   step_stats=False):
     """Enqueue one optimizer step of the stream or the feed loop through
     `_STEP_RETRY`, under the span `fit/dispatch`: host time only (JAX
     returns before the device finishes; `setProfile(True)` gives a
@@ -102,6 +128,9 @@ def _dispatch_step(train_step, params, opt_state, scale_state, xb, yb, wb, *,
     ``fused`` is the placed capture params of a fit-side fused step (``xb``
     is then the placed raw column tuple and ``yb`` None); ``in_flight`` the
     fit's `_StepsInFlight`, None when the fit started with telemetry off.
+    ``step_stats``: the step program was built to return the model's
+    per-step counts after the loss (`_make_train_step`); they ride with the
+    loss in ``in_flight`` and are read when it is ready.
     Returns ``(params, opt_state, scale_state, loss)``."""
     state = ((params, opt_state) if scale_state is None
              else (params, opt_state, scale_state))
@@ -120,8 +149,11 @@ def _dispatch_step(train_step, params, opt_state, scale_state, xb, yb, wb, *,
     with telemetry.trace.span("fit/dispatch", step=step, **attrs) as sp:
         out = _STEP_RETRY.run(dispatch)
     _m_step_time.observe(sp.seconds)
+    stats = None
+    if step_stats:
+        *out, stats = out
     if in_flight is not None:
-        in_flight.losses.append(out[-1])
+        in_flight.add(step, out[-1], stats)
     if fused is not None:
         from ..core import capture as capturelib
         capturelib._m_fit_fused.inc()
@@ -335,47 +367,63 @@ def _place_params(params, mesh, tx, *, tp: int = 1, ep: int = 1):
     return params, opt
 
 
-def _make_loss_compute(module, loss_fn, is_moe: bool, moe_aux: float):
+def _make_loss_compute(module, loss_fn, is_moe: bool, moe_aux: float,
+                       step_stats: bool = False):
     """The weighted scalar loss of one batch — the ONE forward every
     precision mode and step path shares. The model casts itself to its
     compute dtype (flax ``dtype=``), so precision selection rides the
-    model config; the loss reduction stays f32."""
+    model config; the loss reduction stays f32. ``step_stats``: the model
+    returns per-step counts beside its predictions (`step_stat_names`) and
+    ``compute`` returns ``(loss, counts)``."""
 
     def compute(p, xb, yb, wb):
         # weighted mean so mesh-padding rows (weight 0) carry no gradient.
         # MoE routing must see the row weights too: padded rows may not
         # claim expert capacity or skew the balancing stats
         kw = {"row_mask": wb} if is_moe else {}
+        if step_stats:
+            kw["step_stats"] = True
         if moe_aux > 0.0:
             preds, inter = module.apply(p, xb, mutable=["intermediates"],
                                         **kw)
             from .moe import read_moe_aux_loss
-            aux = read_moe_aux_loss(inter["intermediates"])
+            # a family without an auxiliary loss sows nothing
+            aux = read_moe_aux_loss(inter.get("intermediates", {}))
         else:
             preds = module.apply(p, xb, **kw)
             aux = 0.0
+        stats = None
+        if step_stats:
+            preds, stats = preds
         losses = loss_fn(preds, yb)
         main = jnp.sum(losses * wb) / jnp.maximum(jnp.sum(wb), 1.0)
-        return main + moe_aux * aux
+        loss = main + moe_aux * aux
+        return (loss, stats) if step_stats else loss
 
     return compute
 
 
 def _make_step_body(module, tx, loss_fn, is_moe: bool, moe_aux: float,
-                    grad_clip: float = 0.0):
+                    grad_clip: float = 0.0, step_stats: bool = False):
     """The un-jitted optimizer step: loss -> grads -> update. Shared by the
     one-step-per-dispatch path (fitStream, multi-host) and the scanned
-    multi-step path (fit's default)."""
-    compute = _make_loss_compute(module, loss_fn, is_moe, moe_aux)
+    multi-step path (fit's default). With ``step_stats`` the model's
+    per-step counts follow the loss: ``(params, opt_state, loss, counts)``."""
+    compute = _make_loss_compute(module, loss_fn, is_moe, moe_aux,
+                                 step_stats)
 
     def step_body(params, opt_state, xb, yb, wb):
         loss, grads = jax.value_and_grad(
-            lambda p: compute(p, xb, yb, wb))(params)
+            lambda p: compute(p, xb, yb, wb), has_aux=step_stats)(params)
         if grad_clip > 0.0:
             from .precision import clip_by_global_norm
             grads = clip_by_global_norm(grads, grad_clip)
         updates, opt2 = tx.update(grads, opt_state, params)
-        return optax.apply_updates(params, updates), opt2, loss
+        new_params = optax.apply_updates(params, updates)
+        if step_stats:
+            loss, stats = loss
+            return new_params, opt2, loss, stats
+        return new_params, opt2, loss
 
     return step_body
 
@@ -414,8 +462,13 @@ def _make_pp_step_body(cfg: dict, mesh, tx, loss_fn, n_micro: int):
 
 def _make_train_step(module, tx, loss_fn, is_moe: bool, moe_aux: float,
                      step_body=None, mixed: bool = False,
-                     grad_clip: float = 0.0, featurize=None):
+                     grad_clip: float = 0.0, featurize=None,
+                     step_stats: bool = False):
     """One jitted optimizer step (fitStream / multi-host feed path).
+
+    ``step_stats`` (the plain step only: not ``mixed``, ``featurize`` or a
+    caller's ``step_body``): the program also returns the model's per-step
+    counts, last (`_make_step_body`); `_dispatch_step` is told the same.
 
     ``featurize`` (fit-side pipeline fusion, core/capture.py) is a pure
     traced ``(fparams, raw_arrays) -> (xb, yb)`` body run INSIDE the
@@ -488,7 +541,7 @@ def _make_train_step(module, tx, loss_fn, is_moe: bool, moe_aux: float,
     return sanitize.wrap_donated(
         jax.jit(step_body or
                 _make_step_body(module, tx, loss_fn, is_moe, moe_aux,
-                                grad_clip),
+                                grad_clip, step_stats=step_stats),
                 donate_argnums=donate),
         donate, label="trainer.step")
 
@@ -1605,10 +1658,7 @@ class TpuLearner(Estimator):
                                         "tensorParallel": tp})
         params, opt_state = _place_params(params, mesh, tx, tp=tp, ep=ep)
 
-        # only the transformer family reads num_experts (modules.py builder);
-        # other configs carrying the key must not get a row_mask kwarg
-        is_moe = (cfg.get("type") == "transformer"
-                  and cfg.get("num_experts", 0) > 0)
+        is_moe = has_experts(cfg)
         moe_aux = self.getMoeAuxWeight() if is_moe else 0.0
 
         # multi-host: this process's df is its LOCAL shard of the dataset
@@ -1843,11 +1893,14 @@ class TpuLearner(Estimator):
         tx = make_optimizer(self.getOptimizer(), self.getLearningRate(),
                             self.getMomentum(), self.getWeightDecay())
         loss_fn = make_loss(self.getLoss(), per_example=True)
-        is_moe = (cfg.get("type") == "transformer"
-                  and cfg.get("num_experts", 0) > 0)
+        is_moe = has_experts(cfg)
         if self.getProfile():
             telemetry.profiler.enable()
         mixed, grad_clip, scale_state = self._precision_setup()
+        # the step's own counts (tokens routed, ...) are asked of the
+        # program only in a fit that started with telemetry on
+        step_stats = (telemetry.enabled() and plan is None and not mixed
+                      and bool(getattr(module, "step_stat_names", ())))
         if plan is not None:
             # same program as the feed path's fused step — the instance
             # cache (zero recompiles across resume) is shared with it
@@ -1863,7 +1916,7 @@ class TpuLearner(Estimator):
             train_step = telemetry.profiler.wrap(_make_train_step(
                 module, tx, loss_fn, is_moe,
                 self.getMoeAuxWeight() if is_moe else 0.0, mixed=mixed,
-                grad_clip=grad_clip), "trainer.step")
+                grad_clip=grad_clip, step_stats=step_stats), "trainer.step")
         params, opt_state = _place_params(params, mesh, tx, tp=tp)
 
         params, opt_state, start_epoch, start_step, resume_pos, \
@@ -1948,7 +2001,8 @@ class TpuLearner(Estimator):
                                     train_step, params, opt_state,
                                     scale_state, xb, yb, wb, step=step_no,
                                     in_flight=in_flight, fused=plan_dev,
-                                    elastic_ctx=elastic_ctx)
+                                    elastic_ctx=elastic_ctx,
+                                    step_stats=step_stats)
                             step_no += 1
                             steps_run += 1
                             if n:
@@ -1970,7 +2024,7 @@ class TpuLearner(Estimator):
                                      f"epoch {epoch}")
                 last_loss = float(loss)
                 if in_flight is not None:
-                    in_flight.losses.clear()    # the epoch's last step is in
+                    in_flight.clear()    # the epoch's last step is in
                 from .precision import observe_scale_state
                 skipped_seen = observe_scale_state(scale_state,
                                                    skipped_seen)
@@ -2253,7 +2307,7 @@ class TpuLearner(Estimator):
                 # producer promptly: the finally closes the prefetcher) ----
                 last_loss = float(loss)
                 if in_flight is not None:
-                    in_flight.losses.clear()    # the epoch's last step is in
+                    in_flight.clear()    # the epoch's last step is in
                 _m_rows_per_sec.set(
                     steps * bs / max(time.perf_counter() - t_epoch, 1e-9))
                 t_epoch = time.perf_counter()

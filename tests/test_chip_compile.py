@@ -40,6 +40,8 @@ def one_chip():
 @pytest.mark.parametrize("B,Tq,Tk,H,D,causal,dtype", [
     pytest.param(8, 2048, 2048, 16, 128, True, jnp.bfloat16,
                  id="cgpt-cell"),
+    pytest.param(8, 2048, 2048, 8, 256, True, jnp.bfloat16,
+                 id="kimilinear-cell-latent-padded-to-256"),
     pytest.param(8, 4096, 4096, 4, 128, True, jnp.bfloat16,
                  id="chip-smoke-D"),
     pytest.param(8, 4096, 4096, 4, 128, False, jnp.bfloat16,
