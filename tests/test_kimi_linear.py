@@ -29,6 +29,7 @@ from mmlspark_tpu.models import kimi_linear as kl             # noqa: E402
 from mmlspark_tpu.models.modules import (example_input,       # noqa: E402
                                          has_experts)
 from mmlspark_tpu.models.moe import DroplessMoE, grouped_expert_mlp  # noqa: E402
+from mmlspark_tpu.ops import delta_rule                       # noqa: E402
 from mmlspark_tpu.ops.delta_rule import chunked_delta_rule    # noqa: E402
 from mmlspark_tpu.parallel.sequence import blockwise_attention  # noqa: E402
 
@@ -112,11 +113,13 @@ def scan_inputs(T, decay, seed=0, B=2, H=2, K=8, V=8):
 
 @pytest.mark.parametrize("decay", ["near_one", "moderate", "near_zero",
                                    "mixed"])
-@pytest.mark.parametrize("T,chunk", [(37, 8), (16, 16), (5, 64), (70, 32)])
+@pytest.mark.parametrize("T,chunk", [(37, 8), (16, 16), (5, 64), (70, 32),
+                                     (130, 64), (96, 48), (64, 64)])
 def test_chunked_scan_matches_recurrence(T, chunk, decay):
     """Values and all five gradients; T = 37 is no multiple of the chunk
-    (the pad must leave the state alone), T = 5 is shorter than one, and a
-    chunk of 32 is solved in two diagonal blocks."""
+    (the pad must leave the state alone), T = 5 is shorter than one, and
+    chunks of 32, 48 and 64 take two, three and four diagonal blocks, the
+    decay products left of them as products over the channels."""
     args = scan_inputs(T, decay)
     ct = jax.random.normal(jax.random.PRNGKey(9), args[2].shape, F32)
 
@@ -133,6 +136,32 @@ def test_chunked_scan_matches_recurrence(T, chunk, decay):
         close(a, b, 1e-4)
 
 
+def test_chunked_scan_under_the_hardest_decay():
+    """Log-decays down to -40 a token a channel at chunk 64: a channel falls
+    by e^-2500 inside a chunk, k / exp(G) would overflow float32 after three
+    tokens, and a row block's two factors underflow only where their product
+    does. Outputs and gradients finite and the recurrence's."""
+    q, k, v, g, beta = scan_inputs(128, "near_zero")
+    g = g * (40.0 / 12.0)
+    assert float(g.min()) < -39.0
+    ct = jax.random.normal(jax.random.PRNGKey(9), v.shape, F32)
+
+    def loss(fn, *a):
+        return jnp.sum(fn(*a) * ct)
+
+    chunked = functools.partial(chunked_delta_rule, chunk=64, scale=0.35)
+    plain = functools.partial(recurrence, scale=0.35)
+    args = (q, k, v, g, beta)
+    got = chunked(*args)
+    assert np.all(np.isfinite(np.asarray(got)))
+    close(got, plain(*args), 2e-5)
+    grads = jax.grad(functools.partial(loss, chunked), argnums=range(5))(*args)
+    want = jax.grad(functools.partial(loss, plain), argnums=range(5))(*args)
+    for a, b in zip(grads, want):
+        assert np.all(np.isfinite(np.asarray(a)))
+        close(a, b, 1e-4)
+
+
 def test_chunked_scan_with_one_key_repeated():
     """The same key at every token, steps near 1, decay near 1: the system
     of a chunk is the all-ones lower triangle, whose inverse is bidiagonal
@@ -144,6 +173,57 @@ def test_chunked_scan_with_one_key_repeated():
     beta = jnp.full_like(beta, 0.999)
     got = chunked_delta_rule(q, k, v, g, beta, chunk=64, scale=1.0)
     close(got, recurrence(q, k, v, g, beta, 1.0), 1e-3)
+
+
+def intermediate_shapes(jaxpr):
+    """Shapes of the arrays the jaxpr's equations make, those of the jaxprs
+    they call among them."""
+    shapes = set()
+    for eqn in jaxpr.eqns:
+        shapes |= {tuple(v.aval.shape) for v in eqn.outvars}
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            shapes |= intermediate_shapes(getattr(sub, "jaxpr", sub))
+    return shapes
+
+
+@pytest.mark.parametrize("C,tensor", [
+    (64, (1, 1, 4, 16, 16, 128)),   # the four diagonal blocks' (t, s, channel)
+    (48, (1, 1, 3, 16, 16, 128)),
+    (16, (1, 1, 16, 16, 128)),      # one block: the (C, C, K) tensor, as it was
+    (8, (1, 1, 8, 8, 128))])
+def test_chunk_step_builds_the_decay_tensor_in_diagonal_blocks_only(
+        C, tensor):
+    K = 128
+    row = jax.ShapeDtypeStruct((1, 1, C, K), F32)
+    xs = (row, row, row, row, jax.ShapeDtypeStruct((1, 1, C), F32))
+    state = jax.ShapeDtypeStruct((1, 1, K, K), F32)
+    step = functools.partial(delta_rule._chunk_step, scale=1.0)
+    shapes = intermediate_shapes(jax.make_jaxpr(step)(state, xs).jaxpr)
+    assert tensor in shapes
+    # nothing larger than that tensor or the state
+    assert max(map(np.prod, shapes)) == max(np.prod(tensor), K * K)
+
+
+@pytest.mark.parametrize("T,chunk,exps", [
+    (130, 64, 2 * 3 * 3 * 4 * 256 * 8),     # B H nc n 16 16 K
+    (96, 48, 2 * 3 * 2 * 3 * 256 * 8),
+    (37, 8, 2 * 3 * 5 * 8 * 8 * 8),         # B H nc C C K
+    (5, 64, 2 * 3 * 1 * 5 * 5 * 8)])
+def test_decay_exps_counter_follows_the_blocks(T, chunk, exps):
+    was = telemetry.enabled()
+    telemetry.enable()
+    try:
+        layer = f"counted/{T}/{chunk}"
+        args = scan_inputs(T, "moderate", H=3)
+        jax.eval_shape(functools.partial(chunked_delta_rule, chunk=chunk,
+                                         layer=layer), *args)
+        snap = telemetry.snapshot()
+        read = lambda name: {s["labels"]["layer"]: s["value"]
+                             for s in snap[name]["series"]}[layer]
+        assert read("mmlspark_kda_decay_exps_total") == exps
+        assert read("mmlspark_kda_chunks_total") == 2 * 3 * -(-T // chunk)
+    finally:
+        (telemetry.enable if was else telemetry.disable)()
 
 
 # ------------------------------------------------- the layers, one by one
@@ -596,6 +676,11 @@ def test_step_counts_reach_the_ring_only_with_telemetry_on(on):
         chunks = {s["labels"]["layer"]: s["value"]
                   for s in snap["mmlspark_kda_chunks_total"]["series"]}
         assert {"block0/mixer", "block4/mixer"} <= set(chunks)
+        # the test models' chunk of 8 is one diagonal block of 8 channels
+        exps = {s["labels"]["layer"]: s["value"]
+                for s in snap["mmlspark_kda_decay_exps_total"]["series"]}
+        assert all(exps[layer] == n * 8 * 8 * 8
+                   for layer, n in chunks.items() if layer.startswith("block"))
         held = snap["mmlspark_moe_experts_held"]["series"]
         width = snap["mmlspark_moe_router_width"]["series"]
         assert {s["labels"]["layer"] for s in held} >= {"block1/mlp"}
