@@ -1,6 +1,5 @@
 """Secondary benchmark: LightGBM-class 1M-row GBDT fit wall-clock (the
-second north-star metric in BASELINE.json; bench.py stays the driver's primary
-single-line metric). Prints one JSON line with cold (includes XLA compile)
+second north-star metric in BASELINE.json). Prints one JSON line with cold (includes XLA compile)
 and warm fit times on the attached chip."""
 
 import json

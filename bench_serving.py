@@ -22,8 +22,8 @@ same model and schedule:
 
 and reports **goodput** (200-replies within the deadline per second) and
 p50/p99/p999 latency under saturation. The last line is one
-``mmlspark-bench/v1`` document, so the perf gate records
-`serving_open_loop_*` as first-round metrics and gates them thereafter.
+``mmlspark-bench/v1`` document carrying the `serving_open_loop_*`
+metrics.
 
 ``--chaos`` runs the resilience scenario instead: the PROCESS fleet
 (`serve_fleet` + FleetSupervisor) under a 10% injected `fleet.poll` error
@@ -545,7 +545,7 @@ def chaos_serve_main(rate: float = 300.0, duration: float = 8.0,
     compiles), reconcile the killed worker back into the same lineage,
     and SHRINK by graceful drain once the load ends. Emits
     ``serving_chaos_{recovery_seconds,goodput_rps}`` in one
-    mmlspark-bench/v1 doc for the perf gate."""
+    mmlspark-bench/v1 doc."""
     import tempfile
     import urllib.request
     import jax
@@ -794,13 +794,13 @@ if __name__ == "__main__":
                          "the SLO-driven autoscaled fleet; reports "
                          "goodput, recovery seconds, grow/shrink "
                          "verdicts and emits an mmlspark-bench/v1 doc "
-                         "(serving_chaos_*) for the perf gate")
+                         "(serving_chaos_*)")
     ap.add_argument("--open-loop", action="store_true",
                     help="open-loop arrival benchmark: polling loop vs "
                          "continuous-batching engine over the same "
                          "Poisson/bursty schedule; reports goodput + "
                          "p50/p99/p999 and emits an mmlspark-bench/v1 "
-                         "doc for the perf gate")
+                         "doc")
     ap.add_argument("--rate", type=float, default=500.0,
                     help="open-loop mean arrival rate (req/s)")
     ap.add_argument("--duration", type=float, default=20.0,

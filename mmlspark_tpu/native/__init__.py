@@ -6,13 +6,18 @@ ours is in-repo C++ (csrc/) compiled on demand with the baked-in toolchain
 and loaded here via ctypes. Every entry point has a pure-Python fallback at
 its call site, so the package works (slower) without a compiler.
 
-Set MMLSPARK_TPU_NO_NATIVE=1 to force the fallbacks.
+Set MMLSPARK_TPU_NO_NATIVE=1 to force the fallbacks. That and a missing
+toolchain are the two causes for which the library is quietly absent; a
+build or a load that fails where a toolchain exists is logged as an error
+and named by ``unavailable_reason()``.
 """
 
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import os
+import shutil
 import subprocess
 import threading
 from typing import Optional
@@ -34,6 +39,13 @@ _SO = os.path.join(_BUILD, "libmmltpu.so")
 _lock = threading.Lock()
 _lib = None
 _tried = False
+# why _lib is None once _tried. The first two are the quiet causes: the
+# package is meant to work without the library there. Any other text is a
+# fault (a build or a load that failed where a toolchain exists).
+_DISABLED = "disabled by MMLSPARK_TPU_NO_NATIVE"
+_NO_TOOLCHAIN = "no make or C++ compiler on the path"
+_QUIET = (_DISABLED, _NO_TOOLCHAIN)
+_reason: Optional[str] = None
 
 
 def _needs_build() -> bool:
@@ -65,6 +77,8 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     lib.mmltpu_loader_next.argtypes = [
         ctypes.c_void_p, u8p, u8p, ctypes.POINTER(ctypes.c_int)]
     lib.mmltpu_loader_next.restype = ctypes.c_int
+    lib.mmltpu_loader_ready.argtypes = [ctypes.c_void_p]
+    lib.mmltpu_loader_ready.restype = ctypes.c_int
     lib.mmltpu_loader_destroy.argtypes = [ctypes.c_void_p]
     lib.mmltpu_loader_destroy.restype = None
     lib.mmltpu_csv_parse.argtypes = [
@@ -85,40 +99,76 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     return lib
 
 
-def get_lib() -> Optional[ctypes.CDLL]:
-    """Build (if stale) and load libmmltpu.so; None when unavailable."""
-    global _lib, _tried
-    if _lib is not None or _tried:
-        return _lib
-    with _lock:
-        if _lib is not None or _tried:
-            return _lib
-        _tried = True
-        if os.environ.get("MMLSPARK_TPU_NO_NATIVE"):
-            log.info("native runtime disabled by MMLSPARK_TPU_NO_NATIVE")
-            return None
+def _build_and_load() -> tuple[Optional[ctypes.CDLL], Optional[str]]:
+    """(library, None), or (None, why not).
+
+    One writer: the staleness check, the make and the dlopen run under an
+    exclusive flock on the source directory, so of the processes that
+    start together on a fresh checkout one builds and the rest wait, find
+    the library current and load it; none reads a half-linked file."""
+    cxx = (os.environ.get("CXX") or "g++").split()[0]   # as the Makefile
+    toolchain = bool(shutil.which("make") and shutil.which(cxx))
+    try:
+        fd = os.open(_CSRC, os.O_RDONLY)
         try:
+            fcntl.flock(fd, fcntl.LOCK_EX)
             if _needs_build():
-                os.makedirs(_BUILD, exist_ok=True)
+                if not toolchain:
+                    return None, _NO_TOOLCHAIN
                 r = subprocess.run(
                     ["make", "-C", _CSRC, f"OUT={_BUILD}"],
                     capture_output=True, text=True)
                 if r.returncode != 0:
-                    log.warning("native build failed, using fallbacks:\n%s",
-                                r.stderr[-2000:])
-                    return None
-            _lib = _bind(ctypes.CDLL(_SO))
-        except (OSError, AttributeError) as e:
-            # AttributeError = a stale prebuilt .so missing a newer symbol
-            # (e.g. extracted with fresh mtimes so _needs_build says no):
-            # the contract is None-when-unavailable, never a crash
-            log.warning("native runtime unavailable (%s), using fallbacks", e)
-            _lib = None
+                    return None, (f"build failed (make exit "
+                                  f"{r.returncode}):\n{r.stderr[-2000:]}")
+            return _bind(ctypes.CDLL(_SO)), None
+        finally:
+            os.close(fd)        # gives the lock up
+    except (OSError, AttributeError) as e:
+        # AttributeError = a stale prebuilt .so missing a newer symbol
+        # (e.g. extracted with fresh mtimes so _needs_build says no):
+        # the contract is None-when-unavailable, never a crash
+        return None, (f"load failed ({_SO}): {e}" if toolchain
+                      else _NO_TOOLCHAIN)
+
+
+def get_lib() -> Optional[ctypes.CDLL]:
+    """Build (if stale) and load libmmltpu.so; None when unavailable."""
+    global _lib, _reason, _tried
+    if _tried:
+        return _lib
+    with _lock:
+        if _tried:
+            return _lib
+        if os.environ.get("MMLSPARK_TPU_NO_NATIVE"):
+            _reason = _DISABLED
+        else:
+            _lib, _reason = _build_and_load()
+        if _reason in _QUIET:
+            log.info("native runtime unavailable: %s", _reason)
+        elif _reason is not None:
+            log.error("native runtime unavailable, using the Python "
+                      "fallbacks: %s", _reason)
+        _tried = True
         return _lib
 
 
 def available() -> bool:
     return get_lib() is not None
+
+
+def unavailable_reason() -> Optional[str]:
+    """Why ``available()`` is False; None when the library is loaded."""
+    get_lib()
+    return _reason
+
+
+def unavailable_quietly() -> bool:
+    """True where the library is absent for a cause the package is meant
+    to run with (disabled by the environment, or no toolchain): the one
+    condition under which a test of the native path may be skipped. A
+    build or a load that failed where a toolchain exists answers False."""
+    return unavailable_reason() in _QUIET
 
 
 def decode_image(data: bytes) -> Optional[np.ndarray]:
@@ -196,6 +246,11 @@ class BatchLoader:
             if rc == 0:
                 return
             yield self._buf, self._ok.astype(bool), count.value
+
+    def ready(self) -> int:
+        """Decoded batches waiting in the queue: how far the worker
+        threads are ahead of the consumer at this instant."""
+        return self._lib.mmltpu_loader_ready(self._handle)
 
     def close(self):
         if self._handle:

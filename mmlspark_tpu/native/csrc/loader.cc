@@ -146,6 +146,12 @@ extern "C" int mmltpu_loader_next(void *handle, uint8_t *out, uint8_t *ok,
   return 1;
 }
 
+extern "C" int mmltpu_loader_ready(void *handle) {
+  Loader *ld = static_cast<Loader *>(handle);
+  std::lock_guard<std::mutex> lk(ld->mu);
+  return static_cast<int>(ld->ready.size());
+}
+
 extern "C" void mmltpu_loader_destroy(void *handle) {
   Loader *ld = static_cast<Loader *>(handle);
   {

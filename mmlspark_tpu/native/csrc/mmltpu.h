@@ -50,6 +50,9 @@ void *mmltpu_loader_create(const char *const *paths, int n_paths,
 // final partial batch). Returns 1 if a batch was produced, 0 at end.
 int mmltpu_loader_next(void *handle, uint8_t *out, uint8_t *ok,
                        int *out_count);
+// Decoded batches waiting in the queue right now (never more than
+// max(max_prefetch, n_threads)): what the workers got ahead of the consumer.
+int mmltpu_loader_ready(void *handle);
 void mmltpu_loader_destroy(void *handle);
 
 // ---- CSV ----

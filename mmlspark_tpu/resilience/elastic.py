@@ -51,8 +51,7 @@ Single-process mode rehearses the full recovery path with *simulated*
 hosts (contiguous device groups, ``mesh.host_device_groups``): killing a
 group's heartbeat exercises verdict -> re-mesh -> resume exactly as a real
 preemption would (and :meth:`ElasticFitCoordinator.relaunch_host` the
-grow half), which is what the tier-1 chaos tests and
-``bench.py --chaos-train`` drive. Multi-process mode runs the same
+grow half), which is what the tier-1 chaos tests drive. Multi-process mode runs the same
 heartbeats and verdicts, but an in-job re-mesh is impossible once
 ``jax.distributed`` has lost a member — there the coordinator's job is to
 fail FAST and cleanly (HostLossError instead of a hung collective), so the
@@ -1133,7 +1132,7 @@ class ElasticFitCoordinator:
 
     def relaunch_host(self, host_id: str) -> HostHeartbeat:
         """Simulated-preemption RELAUNCH (single-process failure domains:
-        chaos tests, ``bench.py --chaos-train``): replace a killed host's
+        the chaos tests): replace a killed host's
         beacon with a fresh one carrying the ``joining`` flag — exactly
         the heartbeat a real relaunched host process writes on boot. The
         supervisor turns its sustained freshness into a grow verdict."""

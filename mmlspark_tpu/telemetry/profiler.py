@@ -21,8 +21,7 @@ Answers *why* a step is slow, which the span tracer alone cannot:
 
 Off by default, independent of the span tracer's switch:
 ``profiler.enable()`` (which also enables telemetry — the gauges live in
-the shared registry), ``TpuLearner.setProfile(True)``, or
-``bench.py --profile``. A disabled :class:`ProfiledFunction` call is one
+the shared registry) or ``TpuLearner.setProfile(True)``. A disabled :class:`ProfiledFunction` call is one
 attribute check + delegation to the plain jitted function.
 """
 
@@ -324,8 +323,8 @@ def wrap(fn, tag: str, aot: bool = False) -> ProfiledFunction:
 
 
 def report() -> dict:
-    """JSON-able profile summary — what ``bench.py --profile`` prints and
-    ``docs/observability.md`` documents."""
+    """JSON-able profile summary — what ``docs/observability.md``
+    documents."""
     peak = peak_flops()
     fns = {}
     with _lock:

@@ -113,13 +113,15 @@ class TestPhaseLedger:
 class TestPhaseAttributionE2E:
     def test_phase_sum_reconciles_and_trace_is_fetchable(self, tel,
                                                          tiny_params):
-        """The acceptance pin: clean traffic stamps every phase, the
-        phase histogram's total time reconciles with the request-latency
-        histogram, requests clearing the (epsilon-seeded) slow quantile
-        are tail-retained, the lone request's serve/phase spans sum to
-        its serve/request span, its trace_id rides the latency histogram
-        as an exemplar, and GET /debug/trace/<id> serves the span
-        tree."""
+        """The acceptance pin: clean traffic stamps every phase of every
+        request, requests clearing the (epsilon-seeded) slow quantile
+        are tail-retained, each retained request's serve/phase spans
+        partition its serve/request span (all eight, in order, each
+        beginning where the last ended, the first at admission, the last
+        ending inside the request), the lone request's trace_id rides
+        the latency histogram as an exemplar, and GET /debug/trace/<id>
+        serves the span tree. Asserted from the stamps the program
+        wrote, never from a ratio of two wall-clock sums."""
         step = FusedServingStep(
             _CFG, tiny_params,
             policy=BucketPolicy(max_batch=32, min_bucket=8),
@@ -166,13 +168,15 @@ class TestPhaseAttributionE2E:
             fam = snap["mmlspark_serving_phase_seconds"]
             assert {s["labels"]["phase"]
                     for s in fam["series"]} == set(PHASES)
+            # every request left every stage: nine stamps a phase
+            assert {s["labels"]["phase"]: s["count"]
+                    for s in fam["series"]} == dict.fromkeys(PHASES, 9)
             phase_sum = sum(s["sum"] for s in fam["series"])
             req = snap["mmlspark_http_request_seconds"]["series"][0]
             assert req["count"] == 9
             # the ledger covers admission -> reply encoded; the request
             # histogram adds only the reply-write syscall on top
             assert phase_sum <= req["sum"] * 1.001
-            assert phase_sum >= req["sum"] * 0.90
             # dispatch/batch-wait are phase VIEWS of the same ledger:
             # never more than the phases they are cut from
             disp = snap["mmlspark_serving_dispatch_seconds"]["series"][0]
@@ -187,17 +191,24 @@ class TestPhaseAttributionE2E:
             assert wait["count"] >= 2
             assert wait["sum"] <= head_phases + 1e-6
 
-            # --- the retained trace's spans sum to its request span
-            evs = telemetry.trace.retained_events(tid)
-            req_ev = next(e for e in evs if e["name"] == "serve/request")
-            phase_evs = sorted((e for e in evs
-                                if e["name"] == "serve/phase"),
-                               key=lambda e: e["args"]["seq"])
-            assert [e["args"]["phase"] for e in phase_evs] == list(PHASES)
-            span_sum = sum(e["dur"] for e in phase_evs)
-            # ts/dur are microseconds; allow per-phase floor rounding
-            assert span_sum <= req_ev["dur"] + len(PHASES)
-            assert span_sum >= 0.90 * req_ev["dur"]
+            # --- each retained trace's phase spans partition its request
+            # span (ts/dur are floored microseconds: a boundary may read
+            # one short of the next span's start)
+            for t in tids:
+                evs = telemetry.trace.retained_events(t)
+                req_ev = next(e for e in evs
+                              if e["name"] == "serve/request")
+                phase_evs = sorted((e for e in evs
+                                    if e["name"] == "serve/phase"),
+                                   key=lambda e: e["args"]["seq"])
+                assert [e["args"]["phase"]
+                        for e in phase_evs] == list(PHASES)
+                at = req_ev["ts"]           # admission
+                for e in phase_evs:
+                    assert 0 <= e["ts"] - at <= 1, (t, e)
+                    at = e["ts"] + e["dur"]
+                # the reply stamp precedes the request span's end
+                assert at <= req_ev["ts"] + req_ev["dur"] + 1
 
             # --- exemplar: the retained id on the bucket it landed in
             text = telemetry.registry.prometheus_text()

@@ -747,8 +747,9 @@ class TestDeviceBinning:
     def test_native_cxx_parity(self):
         """The C++ binning kernel (native/csrc/gbdt.cc) is bit-identical
         to the numpy loop across ties, NaN, categoricals, and negatives;
-        skipped only where the native toolchain is unavailable."""
-        from mmlspark_tpu.native import bin_data_native
+        skipped only where the library is absent by design (disabled, or
+        no toolchain), not where its build or load failed."""
+        from mmlspark_tpu import native
         rng = np.random.default_rng(3)
         n, d = 20000, 9
         x = rng.normal(size=(n, d)).astype(np.float32) * 3
@@ -758,9 +759,10 @@ class TestDeviceBinning:
         x[:, 4] = np.round(np.abs(x[:, 4]) * 300) - 5   # cats incl. < 0
         cat = np.zeros(d, bool)
         cat[4] = True
-        nat = bin_data_native(x, edges, cat, 256)
-        if nat is None:
-            pytest.skip("native runtime unavailable")
+        nat = native.bin_data_native(x, edges, cat, 256)
+        if nat is None and native.unavailable_quietly():
+            pytest.skip(native.unavailable_reason())
+        assert nat is not None, native.unavailable_reason()
         host = np.empty((n, d), np.uint8)
         for j in range(d):
             if cat[j]:

@@ -1,10 +1,8 @@
 """Perf observatory: time-series sampling over the metrics registry,
 SLO burn-rate evaluation (+ the serving /healthz + shedding surface),
-rolling-MAD straggler detection, and the statistical bench-regression
-gate (``python -m mmlspark_tpu.perf``)."""
+and rolling-MAD straggler detection."""
 
 import json
-import os
 import urllib.request
 
 import numpy as np
@@ -399,144 +397,6 @@ class TestStragglerDetection:
             assert "elastic/straggler" in names
         finally:
             sup.stop()
-
-
-# ------------------------------------------------------------- perf gate
-
-def _write_history(d, values, metric="train_imgs_per_sec",
-                   unit="imgs/sec", start=1):
-    for i, v in enumerate(values, start=start):
-        (d / f"BENCH_r{i:02d}.json").write_text(json.dumps({
-            "n": i, "parsed": {"metric": metric, "value": v,
-                               "unit": unit, "vs_baseline": None}}))
-
-
-class TestPerfGate:
-    def test_history_discovery_walks_up(self, tmp_path, monkeypatch):
-        from mmlspark_tpu.perf.history import find_history_dir
-        _write_history(tmp_path, [100.0])
-        sub = tmp_path / "a" / "b"
-        sub.mkdir(parents=True)
-        assert find_history_dir(str(sub)) == str(tmp_path)
-        # no history anywhere above, and none committed in this checkout
-        # (the rounds taken on the retired runtime were deleted): None,
-        # which callers treat as "no history", never an error
-        assert find_history_dir("/") is None
-
-    def test_load_record_shapes(self, tmp_path):
-        from mmlspark_tpu.perf.history import load_record
-        a = tmp_path / "round.json"
-        a.write_text(json.dumps({"n": 3, "parsed": {
-            "metric": "m", "value": 5.0, "unit": "s"}}))
-        rec = load_record(str(a))
-        assert rec["round"] == 3
-        assert rec["metrics"]["m"] == {"value": 5.0, "unit": "s"}
-        b = tmp_path / "all.json"
-        b.write_text(json.dumps({"schema": "mmlspark-bench/v1",
-                                 "metrics": [
-                                     {"metric": "x", "value": 1.0,
-                                      "unit": "u"},
-                                     {"metric": "skipped",
-                                      "value": None}]}))
-        rec = load_record(str(b))
-        assert set(rec["metrics"]) == {"x"}
-        # multi-line capture: last parseable JSON line wins
-        c = tmp_path / "capture.json"
-        c.write_text("WARNING: noise\n"
-                     '{"metric": "y", "value": 2.0, "unit": "u"}\n')
-        assert load_record(str(c))["metrics"]["y"]["value"] == 2.0
-        with pytest.raises(ValueError):
-            load_record(str(tmp_path / "missing.json"))
-
-    def test_regression_fails_noise_passes(self, tmp_path):
-        from mmlspark_tpu.perf.cli import main as perf_main
-        _write_history(tmp_path, [98.0, 101.0, 100.0, 102.0])
-        run = tmp_path / "run.json"
-        # 20% down: regression, exit 1
-        run.write_text(json.dumps({"metric": "train_imgs_per_sec",
-                                   "value": 80.5, "unit": "imgs/sec"}))
-        assert perf_main(["--check", str(run),
-                          "--history", str(tmp_path)]) == 1
-        # 2% wobble: inside the band, exit 0
-        run.write_text(json.dumps({"metric": "train_imgs_per_sec",
-                                   "value": 98.5, "unit": "imgs/sec"}))
-        assert perf_main(["--check", str(run),
-                          "--history", str(tmp_path)]) == 0
-        # 20% UP on a throughput metric is an improvement, not a failure
-        run.write_text(json.dumps({"metric": "train_imgs_per_sec",
-                                   "value": 121.0, "unit": "imgs/sec"}))
-        assert perf_main(["--check", str(run),
-                          "--history", str(tmp_path)]) == 0
-
-    def test_regression_names_metric_and_delta(self, tmp_path, capsys):
-        from mmlspark_tpu.perf.cli import main as perf_main
-        _write_history(tmp_path, [100.0, 100.0, 100.0])
-        run = tmp_path / "run.json"
-        run.write_text(json.dumps({"metric": "train_imgs_per_sec",
-                                   "value": 80.0, "unit": "imgs/sec"}))
-        rc = perf_main(["--check", str(run), "--history", str(tmp_path)])
-        out = capsys.readouterr().out
-        assert rc == 1
-        assert "REGRESSION" in out
-        assert "train_imgs_per_sec" in out
-        assert "-20.0%" in out
-
-    def test_lower_is_better_direction(self, tmp_path):
-        from mmlspark_tpu.perf.cli import main as perf_main
-        _write_history(tmp_path, [10.0, 10.2, 9.9],
-                       metric="gbdt_fit_seconds", unit="s")
-        run = tmp_path / "run.json"
-        run.write_text(json.dumps({"metric": "gbdt_fit_seconds",
-                                   "value": 12.5, "unit": "s"}))
-        assert perf_main(["--check", str(run),
-                          "--history", str(tmp_path)]) == 1
-        run.write_text(json.dumps({"metric": "gbdt_fit_seconds",
-                                   "value": 8.0, "unit": "s"}))
-        assert perf_main(["--check", str(run),
-                          "--history", str(tmp_path)]) == 0
-
-    def test_noisy_history_widens_band(self, tmp_path):
-        """MAD-aware thresholds: a swing that would fail a flat history
-        passes when the history itself swings that much."""
-        from mmlspark_tpu.perf.cli import main as perf_main
-        _write_history(tmp_path, [100.0, 140.0, 90.0, 130.0, 95.0])
-        run = tmp_path / "run.json"
-        run.write_text(json.dumps({"metric": "train_imgs_per_sec",
-                                   "value": 85.0, "unit": "imgs/sec"}))
-        assert perf_main(["--check", str(run),
-                          "--history", str(tmp_path)]) == 0
-
-    def test_round_checks_against_prior_rounds_only(self, tmp_path):
-        from mmlspark_tpu.perf.cli import main as perf_main
-        # r1-r3 ~100; r4 regressed to 70 and r5 "recovered" it
-        _write_history(tmp_path, [100.0, 101.0, 99.0, 70.0, 100.0])
-        r4 = tmp_path / "BENCH_r04.json"
-        assert perf_main(["--check", str(r4),
-                          "--history", str(tmp_path)]) == 1
-
-    def test_bench_baseline_resolution(self, tmp_path, monkeypatch):
-        """The vs_baseline fix: bench.py resolves its baseline through
-        perf.history (explicit file, explicit dir, discovery) instead of
-        a glob next to the script."""
-        import importlib.util
-        import mmlspark_tpu
-        repo = os.path.dirname(os.path.dirname(
-            os.path.abspath(mmlspark_tpu.__file__)))
-        spec = importlib.util.spec_from_file_location(
-            "bench_under_test", os.path.join(repo, "bench.py"))
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
-        _write_history(tmp_path, [100.0, 200.0], metric="m")
-        # directory override
-        monkeypatch.setattr(bench, "_BASELINE", str(tmp_path))
-        assert bench._baseline_value("m") == 200.0
-        assert bench._with_baseline(
-            {"metric": "m", "value": 150.0})["vs_baseline"] == 0.75
-        # file override
-        monkeypatch.setattr(bench, "_BASELINE",
-                            str(tmp_path / "BENCH_r01.json"))
-        assert bench._baseline_value("m") == 100.0
-        assert bench._baseline_value("unknown") is None
 
 
 # ------------------------------------------- serving surface (end to end)

@@ -990,9 +990,9 @@ def test_heartbeat_write_retry_and_errors_counter(tmp_path, telemetry_on):
             time.sleep(0.02)
         assert os.path.exists(hb.path)
         # simulate the outage: the directory becomes unwritable (a file
-        # squats on its name)
-        import shutil
-        shutil.rmtree(d)
+        # squats on its name). One rename takes it away: a walk that
+        # unlinks entry by entry races the beacon's own tmp files
+        os.rename(d, d + ".gone")
         with open(d, "w") as f:
             f.write("squatter")
         deadline = time.time() + 5

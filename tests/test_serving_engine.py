@@ -514,7 +514,7 @@ class TestWarmRestart:
             "mmlspark_serving_exec_cache_misses_total") == 0
 
 
-# ----------------------------------------------------- bench + perf gate
+# ------------------------------------------------------- open-loop bench
 
 class TestOpenLoopBench:
     def test_arrival_schedules_deterministic(self):
@@ -531,42 +531,3 @@ class TestOpenLoopBench:
         assert (phase <= 0.25 + 1e-9).all()
         with pytest.raises(ValueError, match="poisson|bursty"):
             bench_serving.arrival_times("adversarial", 1.0, 1.0)
-
-    def test_open_loop_metrics_enter_the_perf_gate(self, tmp_path):
-        """The emitted mmlspark-bench/v1 doc parses into the gate:
-        first-round metrics (absent from the committed BENCH_r* history)
-        record ('no-history') rather than gate, a later regression IS
-        caught, and direction is inferred right for both kinds."""
-        from mmlspark_tpu.perf import gate, history
-        doc = {"schema": "mmlspark-bench/v1",
-               "bench": "serving_open_loop", "backend": "cpu",
-               "metrics": [
-                   {"metric": "serving_open_loop_goodput_rps",
-                    "value": 291.9, "unit": "req/s"},
-                   {"metric": "serving_open_loop_p999_ms",
-                    "value": 18.3, "unit": "ms"}]}
-        path = tmp_path / "BENCH_r90.json"
-        path.write_text(json.dumps(doc))
-        run = history.load_record(str(path))
-        assert set(run["metrics"]) == {"serving_open_loop_goodput_rps",
-                                       "serving_open_loop_p999_ms"}
-        # direction inference: goodput regresses down, latency up
-        assert not gate.lower_is_better("serving_open_loop_goodput_rps",
-                                        "req/s")
-        assert gate.lower_is_better("serving_open_loop_p999_ms", "ms")
-        # earlier rounds that never recorded these metrics
-        (tmp_path / "BENCH_r01.json").write_text(json.dumps({
-            "n": 1, "parsed": {"metric": "train_imgs_per_sec",
-                               "value": 100.0, "unit": "imgs/sec"}}))
-        hist_dir = history.find_history_dir(str(tmp_path))
-        assert hist_dir == str(tmp_path)
-        rounds = history.load_history(hist_dir, exclude=str(path))
-        report = gate.check_run(run, rounds)
-        assert report.ok                  # first round: recorded, not gated
-        assert all(e["status"] == "no-history" for e in report.entries)
-        # once recorded, a goodput collapse fails the gate
-        report2 = gate.check_run(
-            {"metrics": {"serving_open_loop_goodput_rps":
-                         {"value": 150.0, "unit": "req/s"}}},
-            rounds + [run])
-        assert not report2.ok

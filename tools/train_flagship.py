@@ -1,8 +1,8 @@
 """Flagship ACCURACY run: train ResNet-20 from scratch on the richest
 real 32x32 corpus available offline and report held-out accuracy.
 
-`bench.py` proves the flagship path's SPEED on synthetic pixels; this
-script proves it LEARNS — the reference's closest analog is notebook
+The benchmark (`benchmark/run.py`) measures SPEED on seeded noise; this
+script proves the flagship path LEARNS — the reference's closest analog is notebook
 401's CIFAR ConvNet demonstration. The corpus is all 10 classes of
 sklearn's UCI handwritten-digit scans (the only real image data a
 zero-egress image ships), split train/test at the ORIGINAL-scan level
