@@ -334,7 +334,12 @@ class _EncoderBlock(nn.Module):
         H, D = self.heads, self.d_model // self.heads
         h = nn.LayerNorm(dtype=self.dtype)(x)
         qkv = nn.Dense(3 * self.d_model, use_bias=False, dtype=self.dtype)(h)
-        q, k, v = jnp.split(qkv.reshape(B, T, 3 * H, D), 3, axis=2)
+        # thirds of the projection's last dimension, then viewed by heads:
+        # the compiler tiles an array it has to materialise over its last
+        # two dimensions, so a third cut out of a (B, T, 3H, D) view would
+        # be re-tiled on its way to kernels that read (B, T, H*D) in place
+        q, k, v = (a.reshape(B, T, H, D)
+                   for a in jnp.split(qkv, 3, axis=-1))
         a = self.attention(q, k, v).reshape(B, T, self.d_model)
         x = x + nn.Dense(self.d_model, use_bias=False, dtype=self.dtype)(a)
         h = nn.LayerNorm(dtype=self.dtype)(x)

@@ -66,6 +66,12 @@ _m_subtiles = {
         "built (the schedule is static in the shapes: counted at trace "
         "time)", labels=("kernel",))
     for what in ("total", "computed", "masked")}
+_m_calls = telemetry.registry.counter(
+    "mmlspark_flash_calls_total",
+    "flash attention calls built, by how they address a head: in_place (a "
+    "lane block of the (B, T, H*D) array) or transposed (a copy to (B*H, T, "
+    "D)); follows the head width, counted at trace time",
+    labels=("kernel", "layout"))
 
 
 def flash_tile_counts(Tq, Tk, block_q, block_k, sub, causal):
@@ -358,12 +364,27 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
     for a shape, and the ``mmlspark_flash_subtiles_*`` counters add them up
     for every call built.
 
-    Measured on a v5e with ``tools/sweep_flash_blocks.py`` (my chip run,
-    PR 27; milliseconds a call and share of benchmark/flops/attention.py's
-    least time, forward | dq + dkv): (8, 2048, 16, 128) causal 1.35 ms 51.6%
-    | 3.85 ms 45.3%; (8, 4096, 4, 128) causal 1.11 ms 63.1% | 3.29 ms 53.1%,
-    non-causal 1.72 ms 81.3% | 5.30 ms 65.8%; (8, 4096, 8, 64) causal
-    2.22 ms 31.5% | 6.66 ms 26.2%. The D=128 contraction fills the MXU's
+    Where a head is read and written follows its width (``_in_place``): a
+    head one 128-lane tile wide is a lane block of the (B, T, H*D) view of
+    each operand and result, so nothing is copied around the calls as long
+    as the caller's (B, T, H, D) arrays are themselves views of (B, T, H*D)
+    ones (a projection's output, or a slice of its *last* dimension: the
+    compiler tiles a (B, T, H, D) array it has to materialise over (H, D),
+    and re-tiles it on the way); any other width is transposed to
+    (B*H, T, D) and back. ``mmlspark_flash_calls_total`` counts the calls
+    built by kernel and layout.
+
+    Measured on a v5e with ``tools/sweep_flash_blocks.py`` (milliseconds a
+    call and share of benchmark/flops/attention.py's least time, forward |
+    dq + dkv). My chip run, PR 31, heads in place: (8, 2048, 16, 128) causal
+    1.38 ms 50.4% | 3.93 ms 44.4%, with nothing else in the forward program
+    (1.384 ms) and 0.46 ms of row statistics in the gradient's (5.77); the
+    same kernels on (B*H, T, D) copies read 1.36 | 3.85 ms with 0.80 and
+    1.82 ms of copies around them. Copied: (8, 2048, 8, 256) causal 1.15 ms
+    60.8% | 3.58 ms 48.7% (in place 1.16 | 3.75); (8, 4096, 8, 64) causal
+    2.22 ms 31.5% | 6.66 ms 26.2%. My chip run, PR 27, copied then:
+    (8, 4096, 4, 128) causal 1.11 ms 63.1% | 3.29 ms 53.1%, non-causal
+    1.72 ms 81.3% | 5.30 ms 65.8%. The D=128 contraction fills the MXU's
     128-deep systolic array where D=64 half-fills it, which is why
     transformer configs in this repo default to head_dim 128 (packing two
     heads into one contraction would sum cross-head scores, so the fix is
@@ -391,6 +412,45 @@ def _from_bh(x, B, T):
     """(B*H, T_padded, D) -> (B, T, H, D)."""
     BH, _, D = x.shape
     return x[:, :T].reshape(B, BH // B, T, D).transpose(0, 2, 1, 3)
+
+
+def _in_place(D):
+    """Whether the calls read and write a head where the caller left it.
+    The rule is one lane tile a head, D == 128: such a head is a lane block
+    of the (B, T, H*D) view of a (B, T, H, D) array, whole (16, 128) tiles
+    in bfloat16 and (8, 128) in float32. Any other width is copied to
+    (B*H, T, D) first (``_to_bh``) and its results copied back
+    (``_from_bh``). Narrower heads have to be: Mosaic takes a block whose
+    last dimension is a multiple of 128 lanes or the whole dimension. Wider
+    multiples would lower too, and ``tools/sweep_flash_blocks.py --shape
+    latent`` reads a 256-lane head faster in place when its operands are
+    views of (B, T, H*D) arrays. They stay on the copied path because of
+    who calls at that width, which the width stands in for and the kernels
+    cannot see: the package's one such caller (the latent layers, heads
+    zero-padded to 256) hands over arrays it has padded by heads, and those
+    cost a copy more to re-tile than to transpose (PERF.md section 6,
+    PR 31). Widen the rule when a caller at 256 passes lane views."""
+    return D == LANES
+
+
+def _head_layout(H, D):
+    """(pack, unpack, index) of one call: ``pack(x, pad)`` makes the array
+    the kernel reads of a (B, T, H, D) operand, rows padded; ``unpack(y, B,
+    T)`` the (B, T, H, D) result of what it wrote; ``index(bh, rows)`` the
+    block of either that holds row block ``rows`` of head ``bh`` of the
+    (B*H, ...) grid. The kernel sees a (1, rows, D) block either way."""
+    if not _in_place(D):
+        return _to_bh, _from_bh, lambda bh, rows: (bh, rows, 0)
+
+    def pack(x, pad):
+        B, T = x.shape[:2]
+        x = x.reshape(B, T, H * D)
+        return jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
+
+    def unpack(y, B, T):
+        return y[:, :T].reshape(B, T, H, D)
+
+    return pack, unpack, lambda bh, rows: (bh // H, rows, bh % H)
 
 
 def _walked_block(causal, resident, walked, n_walked, first):
@@ -422,16 +482,18 @@ def _schedule(kernel, D, causal, Tq, Tk, block_q, block_k):
               flash_tile_counts(Tq, Tk, bq, bk, sub, causal))
     for what, n in zip(("total", "computed", "masked"), counts):
         _m_subtiles[what].labels(kernel=kernel).inc(n)
+    _m_calls.labels(kernel=kernel, layout="in_place" if _in_place(D)
+                    else "transposed").inc()
     return (bq, bk, sub), counts[2] > 0
 
 
-def _bwd_operands(q, k, v, do, lse, dvec, bq, bk):
+def _bwd_operands(pack, q, k, v, do, lse, dvec, bq, bk):
     """The backward kernels' operands padded to their blocks. Padded query
     rows carry q = 0, dO = 0, D = 0 and lse = 0, so p is finite there and
     dS, P^T dO vanish."""
     pq, pk = (-q.shape[1]) % bq, (-k.shape[1]) % bk
-    return ((_to_bh(q, pq), _to_bh(k, pk), _to_bh(v, pk),
-             _to_bh(do.astype(q.dtype), pq)),
+    return ((pack(q, pq), pack(k, pk), pack(v, pk),
+             pack(do.astype(q.dtype), pq)),
             [jnp.pad(r, ((0, 0), (0, pq))) for r in (lse, dvec)],
             k.shape[1] if pk else None)
 
@@ -441,12 +503,13 @@ def _dq_call(q, k, v, do, lse, dvec, *, blocks, causal, scale, masked,
              interpret):
     bq, bk, sub = blocks
     B, Tq, H, D = q.shape
-    (qb, kb, vb, dob), rows, seq_k = _bwd_operands(q, k, v, do, lse, dvec,
-                                                   bq, bk)
+    pack, unpack, head = _head_layout(H, D)
+    (qb, kb, vb, dob), rows, seq_k = _bwd_operands(pack, q, k, v, do, lse,
+                                                   dvec, bq, bk)
     nq, nk = qb.shape[1] // bq, kb.shape[1] // bk
     k_block = _walked_block(causal, bq, bk, nk, first=False)
-    qspec = pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0))
-    kspec = pl.BlockSpec((1, bk, D), lambda b, i, j: (b, k_block(i, j), 0))
+    qspec = pl.BlockSpec((1, bq, D), lambda b, i, j: head(b, i))
+    kspec = pl.BlockSpec((1, bk, D), lambda b, i, j: head(b, k_block(i, j)))
     qrow = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0))
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, block_q=bq, block_k=bk,
@@ -460,7 +523,7 @@ def _dq_call(q, k, v, do, lse, dvec, *, blocks, causal, scale, masked,
         interpret=interpret,
         name="flash_dq",
     )(qb, kb, vb, dob, *(r[..., None] for r in rows))
-    return _from_bh(dq, B, Tq)
+    return unpack(dq, B, Tq)
 
 
 @functools.partial(jax.jit, static_argnames=_CALL_STATICS)
@@ -469,13 +532,14 @@ def _dkv_call(q, k, v, do, lse, dvec, *, blocks, causal, scale, masked,
     bq, bk, sub = blocks
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
-    (qb, kb, vb, dob), rows, seq_k = _bwd_operands(q, k, v, do, lse, dvec,
-                                                   bq, bk)
+    pack, unpack, head = _head_layout(H, D)
+    (qb, kb, vb, dob), rows, seq_k = _bwd_operands(pack, q, k, v, do, lse,
+                                                   dvec, bq, bk)
     nq, nk = qb.shape[1] // bq, kb.shape[1] // bk
     # K blocks outer (the accumulators live per K block), Q blocks inner
     q_block = _walked_block(causal, bk, bq, nq, first=True)
-    qspec = pl.BlockSpec((1, bq, D), lambda b, i, j: (b, q_block(i, j), 0))
-    kspec = pl.BlockSpec((1, bk, D), lambda b, i, j: (b, i, 0))
+    qspec = pl.BlockSpec((1, bq, D), lambda b, i, j: head(b, q_block(i, j)))
+    kspec = pl.BlockSpec((1, bk, D), lambda b, i, j: head(b, i))
     # lse and D as row vectors, one row a Q sub-tile
     qrow = pl.BlockSpec((1, 1, bq // sub, sub),
                         lambda b, i, j: (b, q_block(i, j), 0, 0))
@@ -493,7 +557,29 @@ def _dkv_call(q, k, v, do, lse, dvec, *, blocks, causal, scale, masked,
         interpret=interpret,
         name="flash_dkv",
     )(qb, kb, vb, dob, *(r.reshape(B * H, nq, bq // sub, sub) for r in rows))
-    return _from_bh(dk, B, Tk), _from_bh(dv, B, Tk)
+    return unpack(dk, B, Tk), unpack(dv, B, Tk)
+
+
+@jax.jit
+def _row_dots(do, out):
+    """D_i = rowsum(dO * O) of every head, (B, T, H, D) x 2 -> (B*H, T)
+    float32: the cheap elementwise residual of the backward. Where the calls
+    read a head in place, so does this: a head's products are a lane block
+    of the (B, T, H*D) views, summed a head at a time, because a reduction
+    over the last dimension of a (B, T, H, D) form, taken before the
+    product or after it, makes the compiler re-tile what it reduces in
+    float32 first (five times the bytes, compiled for a v5e). Jitted as the
+    calls are: the heads' operations are lowered once a program, not once a
+    layer."""
+    B, T, H, D = out.shape
+    f32 = jnp.float32
+    if not _in_place(D):
+        return jnp.sum(do.astype(f32) * out.astype(f32),
+                       axis=-1).transpose(0, 2, 1).reshape(B * H, T)
+    do, out = do.reshape(B, T, H * D), out.reshape(B, T, H * D)
+    heads = [jnp.sum(do[..., h:h + D].astype(f32) * out[..., h:h + D]
+                     .astype(f32), axis=-1) for h in range(0, H * D, D)]
+    return jnp.stack(heads, axis=1).reshape(B * H, T)
 
 
 def _flash_attention_bwd(causal, scale, block_q, block_k, interpret,
@@ -503,9 +589,7 @@ def _flash_attention_bwd(causal, scale, block_q, block_k, interpret,
     Tk = k.shape[1]
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
     interpret = _interpret() if interpret is None else interpret
-    # D_i = rowsum(dO * O) — cheap elementwise residual
-    dvec = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                   axis=-1).transpose(0, 2, 1).reshape(B * H, Tq)
+    dvec = _row_dots(g, out)
 
     def grad(kernel, call):
         blocks, masked = _schedule(kernel, D, causal, Tq, Tk, block_q,
@@ -585,16 +669,17 @@ def _fwd_call(q, k, v, *, blocks, causal, scale, masked, interpret):
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
     pq, pk = (-Tq) % block_q, (-Tk) % block_k
-    qb, kb, vb = _to_bh(q, pq), _to_bh(k, pk), _to_bh(v, pk)
+    pack, unpack, head = _head_layout(H, D)
+    qb, kb, vb = pack(q, pq), pack(k, pk), pack(v, pk)
     nq, nk = qb.shape[1] // block_q, kb.shape[1] // block_k
     kernel = functools.partial(_flash_kernel, block_q=block_q,
                                block_k=block_k, sub=sub, causal=causal,
                                scale=scale, seq_k=Tk if pk else None,
                                masked=masked)
     k_block = _walked_block(causal, block_q, block_k, nk, first=False)
-    qspec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
+    qspec = pl.BlockSpec((1, block_q, D), lambda b, i, j: head(b, i))
     kspec = pl.BlockSpec((1, block_k, D),
-                         lambda b, i, j: (b, k_block(i, j), 0))
+                         lambda b, i, j: head(b, k_block(i, j)))
     out, lse = pl.pallas_call(
         kernel,
         grid=(B * H, nq, nk),
@@ -602,7 +687,8 @@ def _fwd_call(q, k, v, *, blocks, causal, scale, masked, interpret):
         out_specs=(qspec,
                    pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))),
         out_shape=(jax.ShapeDtypeStruct(qb.shape, q.dtype),
-                   jax.ShapeDtypeStruct(qb.shape[:2] + (1,), jnp.float32)),
+                   jax.ShapeDtypeStruct((B * H, qb.shape[1], 1),
+                                        jnp.float32)),
         scratch_shapes=[
             pltpu.VMEM((block_q, D), jnp.float32),
             pltpu.VMEM((block_q, LANES), jnp.float32),
@@ -612,7 +698,7 @@ def _fwd_call(q, k, v, *, blocks, causal, scale, masked, interpret):
         interpret=interpret,
         name="flash_fwd",
     )(qb, kb, vb)
-    return _from_bh(out, B, Tq), lse[:, :Tq, 0]
+    return unpack(out, B, Tq), lse[:, :Tq, 0]
 
 
 def _flash_attention_fwd_impl(q, k, v, causal, scale, block_q, block_k,

@@ -10,6 +10,8 @@ may load the TPU's library, and every xdist worker imports this file. Keep
 such tests in this one file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -37,6 +39,36 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
+# where a head is copied to (B*H, T, D): the transposing copies of the program
+# before the in-place layout (q, k, v, dO in, O, dq, dk, dv out, as the
+# compiler shares and fuses them), by (Tq, Tk, D); the padded lengths' other
+# copies are of padded sizes and not counted
+_COPIES_AS_THEY_WERE = {(4096, 4096, 64): 12, (1000, 200, 64): 6,
+                        (20, 20, 32): 6, (2048, 2048, 256): 6,
+                        (8192, 8192, 256): 3}
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* (copy|transpose|fusion)\(")
+
+
+def _operand_sized_copies(hlo, sizes):
+    """The entry computation's `copy` and `transpose` instructions, and its
+    fusions named for one, whose result has as many elements as q or k."""
+    entry = hlo[hlo.index("ENTRY"):]
+    found = []
+    for line in entry.splitlines():
+        m = _INSTRUCTION.match(line)
+        if not m:
+            continue
+        name, dims, kind = m.groups()
+        elements = 1
+        for d in dims.split(","):
+            elements *= int(d or 1)
+        if elements in sizes and (kind != "fusion" or "copy" in name
+                                  or "transpose" in name):
+            found.append(name)
+    return found
+
+
 @pytest.mark.parametrize("B,Tq,Tk,H,D,causal,dtype", [
     pytest.param(8, 2048, 2048, 16, 128, True, jnp.bfloat16,
                  id="cgpt-cell"),
@@ -62,14 +94,79 @@ def one_chip():
 def test_flash_kernels_compile_for_v5e(one_chip, B, Tq, Tk, H, D, causal,
                                        dtype):
     """Forward and both backward kernels lower to Mosaic and fit VMEM at the
-    blocks `_default_blocks` derives."""
-    q = jax.ShapeDtypeStruct((B, Tq, H, D), dtype, sharding=one_chip)
-    k = jax.ShapeDtypeStruct((B, Tk, H, D), dtype, sharding=one_chip)
+    blocks `_default_blocks` derives, and the optimised program holds the
+    layout copies its head width asks for: no `copy` or `transpose` of a
+    (B, T, H, D)-sized array beside the calls where a head is read in place
+    (D of 128 lanes), the program as it was where it is not.
+
+    q, k, v come in as the models hand them over, (B, T, H*D) arrays viewed
+    as (B, T, H, D), and the gradients leave in that form: a (B, T, H, D)
+    entry parameter is tiled over (H, D) and would be re-tiled on its way to
+    either layout, which is the caller's cost and not the calls'."""
+    q = jax.ShapeDtypeStruct((B, Tq, H * D), dtype, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((B, Tk, H * D), dtype, sharding=one_chip)
 
     def loss(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal, None, None, None,
-                                       False).astype(jnp.float32))
+        q, k, v = (a.reshape(B, -1, H, D) for a in (q, k, v))
+        out = flash_attention(q, k, v, causal, None, None, None, False)
+        return jnp.sum(out.reshape(B, Tq, H * D).astype(jnp.float32))
 
     lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, k)
     assert lowered.as_text().count("tpu_custom_call") == 3
+    copies = _operand_sized_copies(lowered.compile().as_text(),
+                                   {B * Tq * H * D, B * Tk * H * D})
+    assert len(copies) == _COPIES_AS_THEY_WERE.get((Tq, Tk, D), 0), copies
+
+
+@pytest.mark.parametrize("B,T,H,D", [
+    pytest.param(8, 2048, 16, 128, id="cgpt-cell"),
+    pytest.param(2, 4096, 4, 128, id="chip-smoke-D"),
+])
+def test_flash_kernels_compile_from_4d_arrays(one_chip, B, T, H, D):
+    """A caller that hands over materialised (B, T, H, D) arrays at the
+    in-place width (`chip_smoke.py` stage D does): the three kernels lower
+    and the program compiles, with the re-tiling such arrays cost left to
+    the compiler. Only compiled, as every case was before the layout."""
+    q = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, True, None, None, None,
+                                       False).astype(jnp.float32))
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q)
+    assert lowered.as_text().count("tpu_custom_call") == 3
     lowered.compile()
+
+
+@pytest.mark.parametrize("H,D,in_place", [
+    pytest.param(16, 128, True, id="cgpt-widths-in-place"),
+    pytest.param(8, 64, False, id="head-dim-64-copied"),
+])
+def test_encoder_block_compiles_for_v5e(one_chip, monkeypatch, H, D,
+                                        in_place):
+    """One GPT-2 block, forward under `remat` and gradient. At
+    `cgpt1p3b_train_stream`'s widths the three kernels read and write
+    (B, T, H*D) arrays and the compiled program holds no array shaped
+    (..., heads, head_dim) at all, so nothing is re-tiled or transposed
+    between the projections and the calls. (A q cut out of a (B, T, 3H, D)
+    view of the fused projection was re-tiled on its way in and dq on its
+    way out: PERF.md section 6, PR 31.) At a width that is copied the block
+    only has to lower and compile with its lane split."""
+    from mmlspark_tpu.models import build_model
+    from mmlspark_tpu.ops import pallas_kernels
+    monkeypatch.setattr(pallas_kernels, "_interpret", lambda: False)
+    B, T = 8, 2048
+    model = build_model({
+        "type": "transformer", "d_model": H * D, "heads": H, "layers": 1,
+        "mlp_ratio": 4, "vocab_size": 50257, "max_len": T, "causal": True,
+        "remat": True, "num_classes": 2, "attn_impl": "flash"})
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((2, T), jnp.int32)))
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), params)
+    tokens = jax.ShapeDtypeStruct((B, T), jnp.int32, sharding=one_chip)
+    hlo = jax.jit(jax.grad(lambda p, t: jnp.sum(model.apply(p, t)))).lower(
+        params, tokens).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 4
+    if in_place:
+        assert re.findall(rf"\w+\[[\d,]*,(?:{H}|{3 * H}),{D}\]", hlo) == []

@@ -1,6 +1,7 @@
 """Pallas kernel correctness (interpret mode on the CPU mesh — the same
 kernels compile natively on TPU; the bench exercises that path)."""
 
+import functools
 import sys
 
 import jax.numpy as jnp
@@ -73,14 +74,14 @@ def _with_sub(monkeypatch, sub):
         lambda block, want: sub if block % sub == 0 else block)
 
 
-def _flash_vs_plain(Tq, Tk, bq, bk, causal, dtype, rng):
+def _flash_vs_plain(Tq, Tk, bq, bk, causal, dtype, rng, H=2, D=8,
+                    reference=plain_attention):
     """Forward and the three gradients of both, in float32 numpy."""
     import jax
-    D = 8
-    q = jnp.asarray(rng.normal(size=(2, Tq, 2, D)).astype(np.float32))
-    k = jnp.asarray(rng.normal(size=(2, Tk, 2, D)).astype(np.float32))
-    v = jnp.asarray(rng.normal(size=(2, Tk, 2, D)).astype(np.float32))
-    w = jnp.asarray(rng.normal(size=(2, Tq, 2, D)).astype(np.float32))
+    q = jnp.asarray(rng.normal(size=(2, Tq, H, D)).astype(np.float32))
+    k = jnp.asarray(rng.normal(size=(2, Tk, H, D)).astype(np.float32))
+    v = jnp.asarray(rng.normal(size=(2, Tk, H, D)).astype(np.float32))
+    w = jnp.asarray(rng.normal(size=(2, Tq, H, D)).astype(np.float32))
 
     def run(attn, args):
         def loss(q, k, v):
@@ -92,8 +93,7 @@ def _flash_vs_plain(Tq, Tk, bq, bk, causal, dtype, rng):
 
     got = run(lambda q, k, v: flash_attention(q, k, v, causal, None, bq, bk),
               [a.astype(dtype) for a in (q, k, v)])
-    want = run(lambda q, k, v: plain_attention(q, k, v, causal=causal),
-               (q, k, v))
+    want = run(lambda q, k, v: reference(q, k, v, causal=causal), (q, k, v))
     return got, want
 
 
@@ -230,6 +230,103 @@ def test_flash_subtile_counters(monkeypatch):
         got = tuple(after[kernel, n] - before.get((kernel, n), 0.0)
                     for n in names)
         assert got == want, (kernel, got, want)
+
+
+# A head read where the model left it: one 128-lane tile wide, it is a lane
+# block of the (B, T, H*D) view; any other width (256 here, 8-64 above) is
+# copied to (B*H, T, D). (Tq, Tk, block_q, block_k): lengths the blocks
+# divide and lengths they do not (the padded path in the in-place layout),
+# Tq != Tk.
+_LANE_TILE_LENGTHS = [
+    pytest.param(32, 32, 16, 16, id="blocks-divide"),
+    pytest.param(20, 20, 8, 16, id="both-padded"),
+    pytest.param(12, 28, 8, 8, id="tq<tk-keys-padded"),
+    pytest.param(40, 24, 16, 8, id="tq>tk-queries-padded"),
+]
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("Tq,Tk,bq,bk", _LANE_TILE_LENGTHS)
+@pytest.mark.parametrize("H", [2, 3])
+@pytest.mark.parametrize("D", [pytest.param(128, id="d128-in-place"),
+                               pytest.param(256, id="d256-copied")])
+def test_flash_heads_of_whole_lane_tiles(rng, D, H, Tq, Tk, bq, bk, causal):
+    """Values and the three gradients where a head is a lane block (128:
+    the in-place layout, padded lengths included) and at the next multiple
+    of 128 lanes, which runs the copied layout (256)."""
+    from mmlspark_tpu.parallel.sequence import blockwise_attention
+    got, want = _flash_vs_plain(
+        Tq, Tk, bq, bk, causal, jnp.float32, rng, H, D,
+        functools.partial(blockwise_attention, block_size=8))
+    np.testing.assert_allclose(got[0], want[0], atol=1e-5, rtol=1e-5)
+    for g, r in zip(got[1:], want[1:]):
+        np.testing.assert_allclose(g, r, atol=2e-3, rtol=2e-3)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs it calls (the jitted
+    calls), the kernels' own bodies left out."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
+
+
+@pytest.mark.parametrize("D,fwd_transposes,grad_transposes", [
+    # the copied layout as it was: q, k, v in and O out; the same again in
+    # the gradient's forward, q, k, v, dO in twice, dq, dk, dv out
+    pytest.param(64, 4, 15, id="d64-transposed"),
+    pytest.param(128, 0, 0, id="d128-in-place"),
+    pytest.param(256, 4, 15, id="d256-transposed"),
+])
+def test_flash_layout_copies_in_jaxpr(D, fwd_transposes, grad_transposes):
+    """No transpose of an operand-sized array around the in-place calls,
+    forward or gradient; the other widths hold what they held."""
+    import jax
+    q = jnp.zeros((1, 256, 2, D), jnp.float32)
+
+    def attend(q, k, v):
+        return flash_attention(q, k, v, True)
+
+    grad = jax.grad(lambda q, k, v: jnp.sum(attend(q, k, v)),
+                    argnums=(0, 1, 2))
+    for fn, calls, want in ((attend, 1, fwd_transposes),
+                            (grad, 3, grad_transposes)):
+        eqns = list(_equations(jax.make_jaxpr(fn)(q, q, q).jaxpr))
+        assert sum(e.primitive.name == "pallas_call" for e in eqns) == calls
+        assert sum(e.primitive.name == "transpose"
+                   and e.outvars[0].aval.size == q.size
+                   for e in eqns) == want
+
+
+@pytest.mark.parametrize("D,layout", [(64, "transposed"), (128, "in_place"),
+                                      (256, "transposed")])
+def test_flash_calls_counter(monkeypatch, D, layout):
+    """One increment a call built, labelled by kernel and by how it
+    addresses a head; the choice follows the width alone."""
+    import jax
+    from mmlspark_tpu import telemetry
+    monkeypatch.setattr(sys.modules["mmlspark_tpu.telemetry.registry"]._state,
+                        "enabled", True)
+
+    def read():
+        series = telemetry.registry.snapshot()[
+            "mmlspark_flash_calls_total"]["series"]
+        return {(s["labels"]["kernel"], s["labels"]["layout"]): s["value"]
+                for s in series}
+    before = read()
+    q = jnp.zeros((1, 256, 3, D), jnp.float32)
+    jax.make_jaxpr(jax.grad(lambda q: jnp.sum(
+        flash_attention(q, q, q, True))))(q)
+    grew = {key: n - before.get(key, 0.0) for key, n in read().items()
+            if n != before.get(key, 0.0)}
+    assert grew == {(kernel, layout): 1.0
+                    for kernel in ("flash_fwd", "flash_dq", "flash_dkv")}
 
 
 def test_histogram_matches_numpy(rng):

@@ -10,6 +10,12 @@ told apart by the benchmark's own patterns) and the share of
 `benchmark/flops/attention.py`'s least time, so a reading here and
 `flash_fwd_roofline` / `flash_bwd_roofline` of the cgpt cell are the same
 quantity (the backward's share there is dq and dkv together, as `bwd` here).
+Beside them `program_ms`, the whole traced program's busy time a call
+(`fwd_program_ms` of the forward-only program, `grad_program_ms` of the
+gradient program, which runs all three kernels): less the kernels it is what
+`flash_attention` puts around its calls. The programs take q, k, v as the
+models hand them over, (B, T, H*D) arrays viewed as (B, T, H, D), and return
+results of that form, so no re-tiling of an entry parameter is counted.
 `--fwd` / `--dq` / `--dkv` take `block_q x block_k x sub` candidates and put
 them in `_default_blocks`' place for that kernel, the others as shipped.
 No cell runs this; it fails where JAX finds no TPU.
@@ -36,6 +42,7 @@ from mmlspark_tpu.ops import pallas_kernels as pk
 
 SHAPES = {                      # B, T, H, D, causal
     "cell": (8, 2048, 16, 128, True),       # cgpt1p3b_train_stream's
+    "latent": (8, 2048, 8, 256, True),      # kimilinear's, padded to 256
     "long": (8, 4096, 4, 128, True),        # chip_smoke stage D's family
     "long_nc": (8, 4096, 4, 128, False),
     "d64": (8, 4096, 8, 64, True),
@@ -47,7 +54,8 @@ CALLS = 6
 
 
 def kernel_ms(fn, args):
-    """{kernel: milliseconds a call} of one traced run of `fn`."""
+    """{kernel: milliseconds a call} of one traced run of `fn`, and the whole
+    program's busy milliseconds a call under "program"."""
     jax.block_until_ready(fn(*args))                  # compile, warm
     with tempfile.TemporaryDirectory() as d:
         jax.profiler.start_trace(d)
@@ -56,7 +64,7 @@ def kernel_ms(fn, args):
         jax.profiler.stop_trace()
         path, = glob.glob(os.path.join(d, "plugins/profile/*/*.xplane.pb"))
         trace = trace_reduce.summarise(trace_reduce.load_events(path))
-    out = {}
+    out = {"program": sum(trace["step_busy_ms"]) / trace["steps"]}
     for name, pattern in KERNELS.items():
         seconds, calls = trace_reduce.kernel_time(trace, pattern)
         if calls:
@@ -70,8 +78,12 @@ def measure(shape, override=None):
     `override` ({kernel: (block_q, block_k, sub)}) only that kernel's."""
     B, T, H, D, causal = SHAPES[shape]
     rng = np.random.default_rng(0)
-    q, k, v = (jnp.asarray(rng.normal(size=(B, T, H, D)), jnp.bfloat16)
+    q, k, v = (jnp.asarray(rng.normal(size=(B, T, H * D)), jnp.bfloat16)
                for _ in range(3))
+
+    def attend(q, k, v):
+        q, k, v = (a.reshape(B, -1, H, D) for a in (q, k, v))
+        return pk.flash_attention(q, k, v, causal).reshape(B, T, H * D)
     shipped = pk._default_blocks
 
     def blocks(D, causal, Tq, Tk, block_q=None, block_k=None,
@@ -85,15 +97,16 @@ def measure(shape, override=None):
         used = {n: blocks(D, causal, T, T, kernel=n) for n in KERNELS}
         ms = {}
         if not override or "flash_fwd" in override:
-            fwd = jax.jit(lambda q, k, v: pk.flash_attention(q, k, v, causal))
-            ms.update(kernel_ms(fwd, (q, k, v)))
+            read = kernel_ms(jax.jit(attend), (q, k, v))
+            ms["fwd_program"] = read.pop("program")
+            ms.update(read)
         if not override or "flash_fwd" not in override:
             grad = jax.jit(jax.grad(
-                lambda q, k, v: jnp.sum(pk.flash_attention(q, k, v, causal)
-                                        .astype(jnp.float32)),
+                lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32)),
                 argnums=(0, 1, 2)))
-            ms.update({n: t for n, t in kernel_ms(grad, (q, k, v)).items()
-                       if n != "flash_fwd"})
+            read = kernel_ms(grad, (q, k, v))
+            ms["grad_program"] = read.pop("program")
+            ms.update({n: t for n, t in read.items() if n != "flash_fwd"})
     finally:
         pk._default_blocks = shipped
     return used, ms
@@ -102,7 +115,8 @@ def measure(shape, override=None):
 def report(shape, used, ms, peaks):
     B, T, H, D, causal = SHAPES[shape]
     line = {"shape": shape,
-            "blocks": {n: "x".join(map(str, used[n])) for n in ms}}
+            "blocks": {n: "x".join(map(str, used[n])) for n in ms
+                       if n in used}}
     line.update({n + "_ms": t for n, t in ms.items()})
     if "flash_fwd" in ms:
         least, _ = attention.least_seconds(
