@@ -11,10 +11,16 @@ are those of the model's public `config.json`; the counts of heads, routed
 experts and vocabulary rows are what is held *here* (one rank's share of a
 layer), while `router_width` stays the deployment's expert count.
 
-Not here: the vocabulary projection with a per-token loss (the head is the
-mean over positions, a final RMSNorm and a linear classifier, as the
-`transformer` family's), rotary (``mla_use_nope`` must be true), a query
-bottleneck (``q_lora_rank`` must be null), a learning-rate schedule.
+`MLALayer`, `_Block` and `causal_attention` also serve the
+`joyai_llm_flash` family (models/joyai_llm_flash.py), whose latent layers
+rotate the split part of q and k (`rotate_pairs`) behind a query bottleneck:
+two static fields of `MLALayer` that this family's build leaves unset.
+
+Not here (the sibling family has them): the vocabulary projection with a
+per-token loss (this family's head is the mean over positions, a final
+RMSNorm and a linear classifier, as the `transformer` family's), rotary
+(``mla_use_nope`` must be true) and a query bottleneck (``q_lora_rank`` must
+be null). In neither: a learning-rate schedule.
 """
 
 from __future__ import annotations
@@ -26,9 +32,16 @@ from typing import Any, Optional, Sequence
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from .. import telemetry
 from ..ops.delta_rule import chunked_delta_rule
 from .moe import MOE_STEP_STATS, DroplessMoE, SwiGLU
+
+_m_rotary_layers = telemetry.registry.counter(
+    "mmlspark_mla_rotary_layers_total",
+    "latent attention layers built with rotary on the split part of q and k "
+    "(static in the configuration: counted at trace time)")
 
 
 def _dense(features, dtype, name):
@@ -116,16 +129,43 @@ class KDALayer(nn.Module):
         return dense(d, name="o_proj")(o)
 
 
-class MLALayer(nn.Module):
-    """Multi-head latent attention without rotary over the heads held here.
+def rotate_pairs(x, theta):
+    """Rotary position embedding on interleaved pairs: x is (B, T, ..., D),
+    position t = 0..T-1 along axis 1; the pair (x[2i], x[2i+1]) is turned by
+    the angle t * theta^(-2i / D). Angles, sines and the product are float32;
+    the result has x's dtype. The pair's partner comes from a product with a
+    constant D x D matrix of 0 and +-1 (exact: one term a column), so no
+    operand is ever viewed as (..., D / 2, 2), which the chip would tile 64
+    times over."""
+    T, D = x.shape[1], x.shape[-1]
+    f32 = jnp.float32
+    # lanes 2i and 2i + 1 share the pair's frequency theta^(-2i / D)
+    inv = theta ** (-(jnp.arange(D) // 2 * 2).astype(f32) / D)
+    ang = (jnp.arange(T, dtype=f32)[:, None] * inv).reshape(
+        (T,) + (1,) * (x.ndim - 3) + (D,))
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    # (x P)[2i] = -x[2i + 1], (x P)[2i + 1] = x[2i]
+    i = np.arange(0, D, 2)
+    swap = np.zeros((D, D), np.float32)
+    swap[i + 1, i], swap[i, i + 1] = -1.0, 1.0
+    x32 = x.astype(f32)
+    partner = jnp.matmul(x32, swap, precision=jax.lax.Precision.HIGHEST)
+    return (x32 * cos + partner * sin).astype(x.dtype)
 
-    q = W_q x (heads x (nope + rope)); [c, k_r] = W_kva x (kv_rank + rope), c
+
+class MLALayer(nn.Module):
+    """Multi-head latent attention over the heads held here.
+
+    q = W_q x (heads x (nope + rope)) or, with a query bottleneck `q_rank`,
+    q = W_qb RMSNorm(W_qa x); [c, k_r] = W_kva x (kv_rank + rope), c
     RMS-normalised; [k_n, v] = W_kvb c per head; k = [k_n, k_r] with k_r
-    shared by the heads and not rotated; causal softmax(q k^T / sqrt(nope +
-    rope)) v; W_o from heads x v_dim. The attention kernels here take one
-    width for q, k and v, so the three are zero-padded to a common multiple
-    of 128 lanes (zeros add nothing to a score, and the padded columns of
-    the result are cut off): exact, at the price of the padding's work."""
+    shared by the heads. With `rope_theta` the rope-wide parts of q (per
+    head) and k_r are rotated by position (`rotate_pairs`); without, nothing
+    is (NoPE). Causal softmax(q k^T / sqrt(nope + rope)) v; W_o from heads x
+    v_dim. The attention kernels here take one width for q, k and v, so the
+    three are zero-padded to a common multiple of 128 lanes (zeros add
+    nothing to a score, and the padded columns of the result are cut off):
+    exact, at the price of the padding's work."""
     heads: int
     kv_rank: int
     nope_dim: int
@@ -134,17 +174,32 @@ class MLALayer(nn.Module):
     attention: Any                 # (q, k, v, scale) -> o, all (B, T, H, D)
     eps: float = 1e-5
     dtype: Any = jnp.bfloat16
+    q_rank: Optional[int] = None          # the query bottleneck's width
+    rope_theta: Optional[float] = None    # None: no rotation
 
     @nn.compact
     def __call__(self, x):
         B, T, d = x.shape
         H, qk = self.heads, self.nope_dim + self.rope_dim
         dense = functools.partial(_dense, dtype=self.dtype)
-        q = dense(H * qk, name="q_proj")(x).reshape(B, T, H, qk)
+        norm = functools.partial(nn.RMSNorm, epsilon=self.eps,
+                                 dtype=self.dtype)
+        if self.q_rank is None:
+            q = dense(H * qk, name="q_proj")(x)
+        else:
+            c_q = norm(name="q_a_norm")(dense(self.q_rank, name="q_a_proj")(x))
+            q = dense(H * qk, name="q_b_proj")(c_q)
+        q = q.reshape(B, T, H, qk)
         kva = dense(self.kv_rank + self.rope_dim, name="kv_a_proj")(x)
-        c = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
-                       name="kv_a_norm")(kva[..., :self.kv_rank])
+        c = norm(name="kv_a_norm")(kva[..., :self.kv_rank])
         k_r = kva[..., self.kv_rank:]
+        if self.rope_theta is not None:
+            _m_rotary_layers.inc()
+            q = jnp.concatenate(
+                [q[..., :self.nope_dim],
+                 rotate_pairs(q[..., self.nope_dim:], self.rope_theta)],
+                axis=-1)
+            k_r = rotate_pairs(k_r, self.rope_theta)
         kv = dense(H * (self.nope_dim + self.v_dim), name="kv_b_proj")(c)
         kv = kv.reshape(B, T, H, self.nope_dim + self.v_dim)
         k = jnp.concatenate(
@@ -160,6 +215,24 @@ class MLALayer(nn.Module):
         o = self.attention(padded(q), padded(k), padded(v), qk ** -0.5)
         o = o[..., :self.v_dim].reshape(B, T, H * self.v_dim)
         return dense(d, name="o_proj")(o)
+
+
+def causal_attention(attn_impl: str, block_size: int):
+    """(q, k, v, scale) -> o over (B, T, H, D) operands, causal: the Pallas
+    flash kernel (``flash``; ``auto`` on a TPU) or the single-device
+    blockwise recurrence (``blockwise``; ``auto`` elsewhere)."""
+    def attention(q, k, v, scale):
+        impl = attn_impl
+        if impl == "auto":
+            from ..core.env import on_tpu
+            impl = "flash" if on_tpu() else "blockwise"
+        if impl == "flash":
+            from ..ops.pallas_kernels import flash_attention
+            return flash_attention(q, k, v, causal=True, scale=scale)
+        from ..parallel.sequence import blockwise_attention
+        return blockwise_attention(q, k, v, block_size=block_size,
+                                   causal=True, scale=scale)
+    return attention
 
 
 class _Block(nn.Module):
@@ -182,6 +255,29 @@ class _Block(nn.Module):
         else:
             h, stats = mlp(h), jnp.zeros((len(MOE_STEP_STATS),), jnp.int32)
         return x + h, stats
+
+
+def block_mlp(model, dense: bool):
+    """name -> a block's MLP, from the fields both families' models carry:
+    a dense SwiGLU or the dropless expert layer."""
+    if dense:
+        return functools.partial(SwiGLU, model.intermediate_size, model.dtype)
+    return functools.partial(
+        DroplessMoE, num_experts=model.num_experts,
+        router_width=model.router_width,
+        d_hidden=model.moe_intermediate_size, top_k=model.top_k,
+        first_expert=model.first_expert, num_shared=model.num_shared,
+        renormalize=model.renormalize, routed_scale=model.routed_scale,
+        dtype=model.dtype)
+
+
+def expert_step_stats(stats):
+    """The blocks' `MOE_STEP_STATS` rows as {name: scalar}: sums over the
+    layers; the fullest expert is the maximum."""
+    s = jnp.stack(stats)
+    reduce = {"moe_expert_tokens_max": jnp.max}
+    return {n: reduce.get(n, jnp.sum)(s[:, j])
+            for j, n in enumerate(MOE_STEP_STATS)}
 
 
 class KimiLinearModel(nn.Module):
@@ -226,18 +322,6 @@ class KimiLinearModel(nn.Module):
         return (["embed"] + [f"block{i}" for i in range(len(self.layer_kinds))]
                 + ["logits"])
 
-    def _attention(self, q, k, v, scale):
-        impl = self.attn_impl
-        if impl == "auto":
-            from ..core.env import on_tpu
-            impl = "flash" if on_tpu() else "blockwise"
-        if impl == "flash":
-            from ..ops.pallas_kernels import flash_attention
-            return flash_attention(q, k, v, causal=True, scale=scale)
-        from ..parallel.sequence import blockwise_attention
-        return blockwise_attention(q, k, v, block_size=self.block_size,
-                                   causal=True, scale=scale)
-
     def _mixer(self, kind):
         if kind == "kda":
             return functools.partial(
@@ -246,21 +330,10 @@ class KimiLinearModel(nn.Module):
         if kind == "mla":
             return functools.partial(
                 MLALayer, self.heads, self.kv_rank, self.nope_dim,
-                self.rope_dim, self.v_dim, self._attention, self.eps,
+                self.rope_dim, self.v_dim,
+                causal_attention(self.attn_impl, self.block_size), self.eps,
                 self.dtype)
         raise ValueError(f"layer kind must be 'kda' or 'mla', got {kind!r}")
-
-    def _mlp(self, i):
-        if i < self.dense_layers:
-            return functools.partial(SwiGLU, self.intermediate_size,
-                                     self.dtype)
-        return functools.partial(
-            DroplessMoE, num_experts=self.num_experts,
-            router_width=self.router_width,
-            d_hidden=self.moe_intermediate_size, top_k=self.top_k,
-            first_expert=self.first_expert, num_shared=self.num_shared,
-            renormalize=self.renormalize, routed_scale=self.routed_scale,
-            dtype=self.dtype)
 
     @nn.compact
     def __call__(self, tokens, output_layer: Optional[str] = None,
@@ -275,7 +348,8 @@ class KimiLinearModel(nn.Module):
         Block = nn.remat(_Block) if self.remat else _Block
         stats = []
         for i, kind in enumerate(self.layer_kinds):
-            blk = Block(self._mixer(kind), self._mlp(i), self.eps,
+            blk = Block(self._mixer(kind),
+                        block_mlp(self, i < self.dense_layers), self.eps,
                         self.dtype, name=f"block{i}")
             x, s = blk(x, row_mask)
             stats.append(s)
@@ -293,21 +367,20 @@ class KimiLinearModel(nn.Module):
         logits = x.astype(jnp.float32)
         if not step_stats:
             return logits
-        s = jnp.stack(stats)
-        reduce = {"moe_expert_tokens_max": jnp.max}
-        return logits, {n: reduce.get(n, jnp.sum)(s[:, j])
-                        for j, n in enumerate(MOE_STEP_STATS)}
+        return logits, expert_step_stats(stats)
 
 
 def build(cfg: dict) -> KimiLinearModel:
     """The model from the keys of the public `config.json` (counts are what
     is held here; see the module's docstring)."""
     if not cfg.get("mla_use_nope", True):
-        raise ValueError("kimi_linear: rotary is not here; mla_use_nope "
-                         "must be true")
+        raise ValueError("kimi_linear: this family's latent layers are NoPE; "
+                         "mla_use_nope must be true (MLALayer rotates the "
+                         "split part for the joyai_llm_flash family)")
     if cfg.get("q_lora_rank") is not None:
-        raise ValueError("kimi_linear: no query bottleneck; q_lora_rank "
-                         "must be null")
+        raise ValueError("kimi_linear: this family has no query bottleneck; "
+                         "q_lora_rank must be null (MLALayer takes one for "
+                         "the joyai_llm_flash family)")
     if cfg.get("moe_router_activation_func", "sigmoid") != "sigmoid" \
             or cfg.get("hidden_act", "silu") != "silu":
         raise ValueError("kimi_linear: the router scores by sigmoid and the "

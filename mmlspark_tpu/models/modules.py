@@ -477,7 +477,7 @@ class TransformerEncoder(nn.Module):
 # ---------------------------------------------------------------- registry
 
 # families whose input is int token ids (callers must cast features to int32)
-TOKEN_MODELS = ("bilstm", "transformer", "kimi_linear")
+TOKEN_MODELS = ("bilstm", "transformer", "kimi_linear", "joyai_llm_flash")
 
 MODEL_BUILDERS: dict[str, Callable[..., nn.Module]] = {
     "mlp": lambda cfg: MLPNet(
@@ -537,6 +537,10 @@ MODEL_BUILDERS: dict[str, Callable[..., nn.Module]] = {
     # dropless share-aware experts (models/kimi_linear.py; keys as in the
     # model's public config.json)
     "kimi_linear": lambda cfg: _kimi_linear(cfg),   # defined below
+    # rotary latent attention behind a query bottleneck in every layer, the
+    # same experts, a vocabulary head with a per-token loss and a
+    # multi-token-prediction module (models/joyai_llm_flash.py)
+    "joyai_llm_flash": lambda cfg: _joyai_llm_flash(cfg),
 }
 
 
@@ -545,12 +549,24 @@ def _kimi_linear(cfg):
     return build(cfg)
 
 
+def _joyai_llm_flash(cfg):
+    from .joyai_llm_flash import build
+    return build(cfg)
+
+
+#: the key that counts the routed experts held, by family (other configs may
+#: carry these keys and route nothing)
+_EXPERTS_KEY = {"transformer": "num_experts", "kimi_linear": "num_experts",
+                "joyai_llm_flash": "n_routed_experts"}
+
+
 def has_experts(config: dict) -> bool:
     """Whether the configuration's model routes tokens over experts: the
-    families that read ``num_experts`` (other configs may carry the key),
-    which are also the ones whose ``__call__`` takes a ``row_mask``."""
-    return (config.get("type") in ("transformer", "kimi_linear")
-            and config.get("num_experts", 0) > 0)
+    families that count experts held (`_EXPERTS_KEY`; other configs may carry
+    the key), which are also the ones whose ``__call__`` takes a
+    ``row_mask``."""
+    key = _EXPERTS_KEY.get(config.get("type"))
+    return key is not None and config.get(key, 0) > 0
 
 
 def build_model(config: dict, attn_fn: Optional[Callable] = None,

@@ -106,10 +106,13 @@ class _StepsInFlight:
 
 
 def _observe_step_stats(step: int, stats: dict):
-    """A finished step's counts (outputs of the step program, on the device
-    until here) into the registry and, numbered by `step`, the ring."""
+    """A finished step's counts and loss terms (outputs of the step program,
+    on the device until here): the expert layers' counts into the registry
+    and all of them, numbered by `step`, onto the ring, whole numbers as ints
+    and the rest as floats."""
     from .moe import observe_step_stats
-    values = {k: int(v) for k, v in jax.device_get(stats).items()}
+    values = {k: (int(v) if np.issubdtype(v.dtype, np.integer) else float(v))
+              for k, v in jax.device_get(stats).items()}
     observe_step_stats(values)
     now = time.perf_counter_ns()
     telemetry.trace.complete("fit/step_stats", now, end_ns=now, step=step,
@@ -192,8 +195,23 @@ def make_optimizer(name: str, lr: float, momentum: float = 0.9,
 
 def make_loss(name: str, per_example: bool = False):
     """Loss on (preds, labels); per_example=True returns the (n,) vector so
-    callers can weight out padding rows."""
-    if name == "cross_entropy":
+    callers can weight out padding rows.
+
+    ``next_token`` is the language-model objective of the families with a
+    vocabulary head (`has_lm_head`): `_make_loss_compute` asks the module
+    itself for the rows' losses (``module_kwargs``), which it computes from
+    the row's own ids, every position against the next id, chunked over
+    positions. The labels handed over with a batch are not read."""
+    if name == "next_token":
+        def vec(row_losses, labels):
+            if row_losses.ndim != 1:
+                raise ValueError(
+                    "loss 'next_token' takes the model's own row losses; this "
+                    f"step path handed it predictions of shape "
+                    f"{row_losses.shape}")
+            return row_losses
+        vec.module_kwargs = {"row_losses": True}
+    elif name == "cross_entropy":
         def vec(logits, labels):
             return optax.softmax_cross_entropy_with_integer_labels(
                 logits, labels.astype(jnp.int32))
@@ -210,7 +228,8 @@ def make_loss(name: str, per_example: bool = False):
 
 def _stream_batch(b, cfg: dict, loss_name: str):
     """Normalize one (features, labels) generator item to device-ready
-    numpy: token models take int32 ids, labels follow the loss dtype.
+    numpy: token models take int32 ids, labels follow the loss dtype
+    (``next_token`` reads none: they ride along as float32, one a row).
     uint8 image batches stay uint8 — the device cast is free and shipping
     bytes is 4x less host->HBM traffic, the same wire contract fit() and
     TpuModel._prep_input keep."""
@@ -374,13 +393,22 @@ def _make_loss_compute(module, loss_fn, is_moe: bool, moe_aux: float,
     compute dtype (flax ``dtype=``), so precision selection rides the
     model config; the loss reduction stays f32. ``step_stats``: the model
     returns per-step counts beside its predictions (`step_stat_names`) and
-    ``compute`` returns ``(loss, counts)``."""
+    ``compute`` returns ``(loss, counts)``. A loss with ``module_kwargs``
+    (`make_loss`: ``next_token``) is computed by the module from its own
+    input: the kwargs are passed on and what comes back are the rows'
+    losses."""
+    asked = getattr(loss_fn, "module_kwargs", {})
+    if asked and not getattr(module, "has_lm_head", False):
+        raise ValueError(
+            f"loss 'next_token' needs a model with a vocabulary head "
+            f"(`has_lm_head`); {type(module).__name__} has none")
 
     def compute(p, xb, yb, wb):
         # weighted mean so mesh-padding rows (weight 0) carry no gradient.
         # MoE routing must see the row weights too: padded rows may not
         # claim expert capacity or skew the balancing stats
         kw = {"row_mask": wb} if is_moe else {}
+        kw.update(asked)
         if step_stats:
             kw["step_stats"] = True
         if moe_aux > 0.0:
@@ -696,8 +724,10 @@ class TpuLearner(Estimator):
     weightDecay = FloatParam("weight decay", default=0.0)
     batchSize = IntParam("global batch size", default=256, min=1)
     epochs = IntParam("training epochs", default=5, min=1)
-    loss = StringParam("cross_entropy|mse", default="cross_entropy",
-                       choices=("cross_entropy", "mse"))
+    loss = StringParam("cross_entropy|mse|next_token (the per-token "
+                       "language-model loss of a family with a vocabulary "
+                       "head; reads no label)", default="cross_entropy",
+                       choices=("cross_entropy", "mse", "next_token"))
     seed = IntParam("PRNG seed", default=0)
     shuffle = BooleanParam("shuffle each epoch", default=True)
     checkpointDir = StringParam("per-epoch checkpoint directory ('' = off)",

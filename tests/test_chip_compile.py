@@ -170,3 +170,55 @@ def test_encoder_block_compiles_for_v5e(one_chip, monkeypatch, H, D,
     assert hlo.count('custom_call_target="tpu_custom_call"') == 4
     if in_place:
         assert re.findall(rf"\w+\[[\d,]*,(?:{H}|{3 * H}),{D}\]", hlo) == []
+
+
+def test_rotary_latent_layer_compiles_for_v5e(one_chip, monkeypatch):
+    """`joyai_train_stream`'s latent layer at its own size (8 rows of 4,096
+    positions, 8 heads, query/key 128 + 64 rotated, value 128, the query
+    behind a 1,536-wide bottleneck), forward and gradient: the rotation is
+    plain XLA before the three flash calls padded to 256 lanes, and no
+    operand of the compiled program is viewed by pairs, (..., 32, 2), a
+    shape the chip would tile 64 times over."""
+    from mmlspark_tpu.models import kimi_linear as kl
+    from mmlspark_tpu.ops import pallas_kernels
+    monkeypatch.setattr(pallas_kernels, "_interpret", lambda: False)
+    B, T, d = 8, 4096, 2048
+    layer = kl.MLALayer(8, 512, 128, 64, 128,
+                        kl.causal_attention("flash", 512), 1e-6,
+                        jnp.bfloat16, 1536, 32e6)
+    x = jax.ShapeDtypeStruct((B, T, d), jnp.bfloat16, sharding=one_chip)
+    params = jax.eval_shape(lambda: layer.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, T, d), jnp.bfloat16)))
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), params)
+    hlo = jax.jit(jax.grad(lambda p, x: jnp.sum(
+        layer.apply(p, x).astype(jnp.float32)))).lower(
+            params, x).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 3
+    assert re.findall(r"\w+\[[\d,]*,32,2\]", hlo) == []
+
+
+def test_vocabulary_loss_walk_holds_no_whole_logits_on_v5e(one_chip):
+    """Both heads' loss walk at the cell's size (8 x 4,096 positions onto
+    16,160 vocabulary rows in chunks of 512), value and gradients: the
+    compiled program holds (8, 512, 16160) chunks and no array with the
+    batch's 32,768 positions beside the vocabulary, and its loops are the
+    `while`s `lm_head_ms` reads: they carry the hidden states by chunks."""
+    from mmlspark_tpu.models.joyai_llm_flash import chunked_token_losses
+    B, T, d, V = 8, 4096, 2048, 16160
+    shape = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one_chip)
+
+    def loss(h, scale, kernel, targets):
+        return jnp.sum(chunked_token_losses(
+            h, scale, kernel, targets, jnp.arange(T) < T - 1, eps=1e-6,
+            chunk=512, dtype=jnp.bfloat16))
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        shape((B, T, d), jnp.bfloat16), shape((d,), jnp.float32),
+        shape((d, V), jnp.float32), shape((B, T), jnp.int32)
+    ).compile().as_text()
+    assert f"[{B},512,{V}]" in hlo
+    assert re.findall(rf"\[(?:{B * T}|{B},{T}),{V}\]", hlo) == []
+    loops = [line for line in hlo.splitlines()
+             if re.search(r"= \(.*\) while\(", line)]
+    assert loops and all("[8,8,512,2048]" in line for line in loops)
