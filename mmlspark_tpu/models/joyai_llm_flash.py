@@ -43,7 +43,7 @@ from jax import lax
 
 from .. import telemetry
 from .kimi_linear import (MLALayer, _Block, _dense, block_mlp,
-                          causal_attention, expert_step_stats)
+                          causal_attention, expert_step_stats, remat_block)
 from .moe import MOE_STEP_STATS
 
 #: what the model reports a step beside the expert layers' counts: the
@@ -204,7 +204,7 @@ class JoyAIFlashModel(nn.Module):
         x = tap.tap("embed", embed(tokens))
         if tap.done:
             return tap.result.astype(jnp.float32)
-        Block = nn.remat(_Block) if self.remat else _Block
+        Block = remat_block() if self.remat else _Block
 
         def block(dense):
             return functools.partial(Block, self._mixer(), block_mlp(self, dense),

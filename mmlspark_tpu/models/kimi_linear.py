@@ -11,7 +11,7 @@ are those of the model's public `config.json`; the counts of heads, routed
 experts and vocabulary rows are what is held *here* (one rank's share of a
 layer), while `router_width` stays the deployment's expert count.
 
-`MLALayer`, `_Block` and `causal_attention` also serve the
+`MLALayer`, `_Block`, `remat_block` and `causal_attention` also serve the
 `joyai_llm_flash` family (models/joyai_llm_flash.py), whose latent layers
 rotate the split part of q and k (`rotate_pairs`) behind a query bottleneck:
 two static fields of `MLALayer` that this family's build leaves unset.
@@ -153,6 +153,13 @@ def rotate_pairs(x, theta):
     return (x32 * cos + partner * sin).astype(x.dtype)
 
 
+def _lane_padded(a, width):
+    """`a` with zeros after its last dimension, up to `width`."""
+    if a.shape[-1] == width:
+        return a
+    return jnp.pad(a, ((0, 0),) * (a.ndim - 1) + ((0, width - a.shape[-1]),))
+
+
 class MLALayer(nn.Module):
     """Multi-head latent attention over the heads held here.
 
@@ -162,16 +169,16 @@ class MLALayer(nn.Module):
     shared by the heads. With `rope_theta` the rope-wide parts of q (per
     head) and k_r are rotated by position (`rotate_pairs`); without, nothing
     is (NoPE). Causal softmax(q k^T / sqrt(nope + rope)) v; W_o from heads x
-    v_dim. The attention kernels here take one width for q, k and v, so the
-    three are zero-padded to a common multiple of 128 lanes (zeros add
-    nothing to a score, and the padded columns of the result are cut off):
-    exact, at the price of the padding's work."""
+    v_dim. `attention` takes two widths: q and k are handed over zero-padded
+    to a multiple of 128 lanes (192 -> 256; zeros add nothing to a score,
+    and on the chip a 192-deep product costs the passes of a 256-deep one),
+    v as it is, and the result comes back v_dim wide with nothing to cut."""
     heads: int
     kv_rank: int
     nope_dim: int
     rope_dim: int
     v_dim: int
-    attention: Any                 # (q, k, v, scale) -> o, all (B, T, H, D)
+    attention: Any       # (q, k (B, T, H, Dqk), v (B, T, H, Dv), scale) -> o
     eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     q_rank: Optional[int] = None          # the query bottleneck's width
@@ -207,20 +214,19 @@ class MLALayer(nn.Module):
              jnp.broadcast_to(k_r[:, :, None, :], (B, T, H, self.rope_dim))],
             axis=-1)
         v = kv[..., self.nope_dim:]
-        width = -(-max(qk, self.v_dim) // 128) * 128
-
-        def padded(a):
-            return jnp.pad(a, ((0, 0),) * 3 + ((0, width - a.shape[-1]),))
-
-        o = self.attention(padded(q), padded(k), padded(v), qk ** -0.5)
-        o = o[..., :self.v_dim].reshape(B, T, H * self.v_dim)
-        return dense(d, name="o_proj")(o)
+        width = -(-qk // 128) * 128
+        o = self.attention(_lane_padded(q, width), _lane_padded(k, width), v,
+                           qk ** -0.5)
+        return dense(d, name="o_proj")(o.reshape(B, T, H * self.v_dim))
 
 
 def causal_attention(attn_impl: str, block_size: int):
-    """(q, k, v, scale) -> o over (B, T, H, D) operands, causal: the Pallas
-    flash kernel (``flash``; ``auto`` on a TPU) or the single-device
-    blockwise recurrence (``blockwise``; ``auto`` elsewhere)."""
+    """(q, k, v, scale) -> o, causal, over q and k (B, T, H, Dqk) and v
+    (B, T, H, Dv), o as wide as v: the Pallas flash kernel (``flash``;
+    ``auto`` on a TPU), which takes the two widths as they are, or the
+    single-device blockwise recurrence (``blockwise``; ``auto`` elsewhere),
+    which takes one: v is zero-padded to q's width for it and the padded
+    columns of its result are cut off."""
     def attention(q, k, v, scale):
         impl = attn_impl
         if impl == "auto":
@@ -230,9 +236,24 @@ def causal_attention(attn_impl: str, block_size: int):
             from ..ops.pallas_kernels import flash_attention
             return flash_attention(q, k, v, causal=True, scale=scale)
         from ..parallel.sequence import blockwise_attention
-        return blockwise_attention(q, k, v, block_size=block_size,
-                                   causal=True, scale=scale)
+        o = blockwise_attention(q, k, _lane_padded(v, q.shape[-1]),
+                                block_size=block_size, causal=True,
+                                scale=scale)
+        return o[..., :v.shape[-1]]
     return attention
+
+
+def remat_block():
+    """`_Block` rematerialised in its backward pass, all but the two
+    residuals only a flash forward kernel can make (its result and the rows'
+    log-sum-exp, `pallas_kernels.FLASH_RESIDUALS`): those are kept, 65 MB a
+    latent block at 8 rows of 4,096 positions and 8 heads of 128, which is
+    about what v, O and dO no longer padded to 256 lanes gave back, and the
+    backward pass does not run `flash_fwd` a second time. On the blockwise
+    path nothing carries the names and the whole block is recomputed."""
+    from ..ops.pallas_kernels import FLASH_RESIDUALS
+    return nn.remat(_Block, policy=jax.checkpoint_policies
+                    .save_only_these_names(*FLASH_RESIDUALS))
 
 
 class _Block(nn.Module):
@@ -345,7 +366,7 @@ class KimiLinearModel(nn.Module):
                                       name="embed")(tokens))
         if tap.done:
             return tap.result.astype(jnp.float32)
-        Block = nn.remat(_Block) if self.remat else _Block
+        Block = remat_block() if self.remat else _Block
         stats = []
         for i, kind in enumerate(self.layer_kinds):
             blk = Block(self._mixer(kind),

@@ -30,6 +30,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -70,8 +71,9 @@ _m_calls = telemetry.registry.counter(
     "mmlspark_flash_calls_total",
     "flash attention calls built, by how they address a head: in_place (a "
     "lane block of the (B, T, H*D) array) or transposed (a copy to (B*H, T, "
-    "D)); follows the head width, counted at trace time",
-    labels=("kernel", "layout"))
+    "D)), and by the head's widths (q and k's, or q and k's / v's where "
+    "they differ); both follow the shapes, counted at trace time",
+    labels=("kernel", "layout", "widths"))
 
 
 def flash_tile_counts(Tq, Tk, block_q, block_k, sub, causal):
@@ -341,7 +343,12 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
 def flash_attention(q, k, v, causal: bool = False, scale=None,
                     block_q: int = None, block_k: int = None,
                     interpret=None):
-    """FlashAttention on TPU. q/k/v: (B, T, H, D) -> (B, T, H, D).
+    """FlashAttention on TPU. q, k: (B, T, H, Dqk), v: (B, Tk, H, Dv) ->
+    (B, T, H, Dv). The two widths may differ (a latent head's queries and
+    keys are wider than its values): q, k, dq and dk are read and written
+    at Dqk, v, the result, its cotangent and dv at Dv, and every product
+    runs over the width its operands have. The default ``scale`` is
+    Dqk ** -0.5.
 
     The score matrix stays in VMEM tiles; HBM traffic is O(T*D) instead of
     O(T^2). Sequence dims are padded to block multiples internally (padded
@@ -364,15 +371,17 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
     for a shape, and the ``mmlspark_flash_subtiles_*`` counters add them up
     for every call built.
 
-    Where a head is read and written follows its width (``_in_place``): a
-    head one 128-lane tile wide is a lane block of the (B, T, H*D) view of
-    each operand and result, so nothing is copied around the calls as long
-    as the caller's (B, T, H, D) arrays are themselves views of (B, T, H*D)
-    ones (a projection's output, or a slice of its *last* dimension: the
-    compiler tiles a (B, T, H, D) array it has to materialise over (H, D),
-    and re-tiles it on the way); any other width is transposed to
-    (B*H, T, D) and back. ``mmlspark_flash_calls_total`` counts the calls
-    built by kernel and layout.
+    Where a head is read and written follows its two widths
+    (``_in_place``): a head one 128-lane tile wide in both is a lane block
+    of the (B, T, H*D) view of each operand and result, so nothing is
+    copied around the calls as long as the caller's (B, T, H, D) arrays are
+    themselves views of (B, T, H*D) ones (a projection's output, or a slice
+    of its *last* dimension: the compiler tiles a (B, T, H, D) array it has
+    to materialise over (H, D), and re-tiles it on the way); any other pair
+    of widths is transposed to (B*H, T, D) and back, each operand at its
+    own width. The tile schedule is the wider width's.
+    ``mmlspark_flash_calls_total`` counts the calls built by kernel, layout
+    and widths (``128``, ``256/128``).
 
     Measured on a v5e with ``tools/sweep_flash_blocks.py`` (milliseconds a
     call and share of benchmark/flops/attention.py's least time, forward |
@@ -382,7 +391,17 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
     same kernels on (B*H, T, D) copies read 1.36 | 3.85 ms with 0.80 and
     1.82 ms of copies around them. Copied: (8, 2048, 8, 256) causal 1.15 ms
     60.8% | 3.58 ms 48.7% (in place 1.16 | 3.75); (8, 4096, 8, 64) causal
-    2.22 ms 31.5% | 6.66 ms 26.2%. My chip run, PR 27, copied then:
+    2.22 ms 31.5% | 6.66 ms 26.2%. My chip run, PR 33, two widths, copied
+    (shares of the products at the widths built, each counted at its own):
+    (8, 4096, 8, 256 / 128) causal 3.31 ms 63.3% | 10.89 ms 51.2%, where
+    the same call with v zero-padded to 256 reads 4.21 | 13.60 ms and the
+    programs round the kernels 1.31 | 2.68 ms against 1.46 | 3.95;
+    (8, 2048, 8, 256 / 128) 0.92 ms 56.6% | 2.86 ms 48.7% against 1.15 |
+    3.69; q and k at 192 lanes, unpadded, the same kernels to 0.1 ms (3.41 |
+    10.92 at 4,096). A walked tile of the whole 4,096 rows, which v at 128
+    lanes leaves room for, read 3.07 | 4.54 + 5.54 ms (dq -14%): not
+    shipped, the schedule is still the wider width's. My chip run, PR 27,
+    copied then:
     (8, 4096, 4, 128) causal 1.11 ms 63.1% | 3.29 ms 53.1%, non-causal
     1.72 ms 81.3% | 5.30 ms 65.8%. The D=128 contraction fills the MXU's
     128-deep systolic array where D=64 half-fills it, which is why
@@ -395,9 +414,19 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
     return out
 
 
+#: names (`jax.ad_checkpoint.checkpoint_name`) of the two residuals of a
+#: differentiated call that only the forward kernel can make, its result and
+#: the rows' log-sum-exp; the other three are the caller's own q, k, v. A
+#: `jax.checkpoint` whose policy saves these names does not run the forward
+#: kernel again in its backward pass.
+FLASH_RESIDUALS = ("flash_out", "flash_lse")
+
+
 def _flash_attention_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
     out, lse = _flash_attention_fwd_impl(q, k, v, causal, scale, block_q,
                                          block_k, interpret)
+    out, lse = (checkpoint_name(a, name)
+                for a, name in zip((out, lse), FLASH_RESIDUALS))
     return out, (q, k, v, out, lse)
 
 
@@ -414,33 +443,41 @@ def _from_bh(x, B, T):
     return x[:, :T].reshape(B, BH // B, T, D).transpose(0, 2, 1, 3)
 
 
-def _in_place(D):
+def _in_place(Dqk, Dv):
     """Whether the calls read and write a head where the caller left it.
-    The rule is one lane tile a head, D == 128: such a head is a lane block
-    of the (B, T, H*D) view of a (B, T, H, D) array, whole (16, 128) tiles
-    in bfloat16 and (8, 128) in float32. Any other width is copied to
-    (B*H, T, D) first (``_to_bh``) and its results copied back
-    (``_from_bh``). Narrower heads have to be: Mosaic takes a block whose
-    last dimension is a multiple of 128 lanes or the whole dimension. Wider
-    multiples would lower too, and ``tools/sweep_flash_blocks.py --shape
-    latent`` reads a 256-lane head faster in place when its operands are
-    views of (B, T, H*D) arrays. They stay on the copied path because of
-    who calls at that width, which the width stands in for and the kernels
-    cannot see: the package's one such caller (the latent layers, heads
-    zero-padded to 256) hands over arrays it has padded by heads, and those
-    cost a copy more to re-tile than to transpose (PERF.md section 6,
-    PR 31). Widen the rule when a caller at 256 passes lane views."""
-    return D == LANES
+    The rule is one lane tile a head in both widths, Dqk == Dv == 128: such
+    a head is a lane block of the (B, T, H*D) view of a (B, T, H, D) array,
+    whole (16, 128) tiles in bfloat16 and (8, 128) in float32. Any other
+    pair of widths is copied to (B*H, T, D) first (``_to_bh``) and its
+    results copied back (``_from_bh``). Narrower heads have to be: Mosaic
+    takes a block whose last dimension is a multiple of 128 lanes or the
+    whole dimension. Wider multiples would lower too, and a 256-lane head
+    read faster in place when its operands were views of (B, T, H*D) arrays
+    (my chip run, PR 31). They stay on the copied path because of who calls
+    at those widths, which the widths stand in for and the kernels cannot
+    see: the package's one such caller (the latent layers: q and k
+    zero-padded from 192 to 256 lanes, v at its own 128) hands over arrays
+    it has concatenated and padded by heads, and those cost a copy more to
+    re-tile than to transpose (PERF.md section 6, PR 31). Widen the rule
+    when a caller at 256 passes lane views."""
+    return Dqk == Dv == LANES
 
 
-def _head_layout(H, D):
+def widths_label(Dqk, Dv):
+    """``widths`` of ``mmlspark_flash_calls_total``: "128", "256/128"."""
+    return str(Dqk) if Dqk == Dv else f"{Dqk}/{Dv}"
+
+
+def _head_layout(H, Dqk, Dv):
     """(pack, unpack, index) of one call: ``pack(x, pad)`` makes the array
     the kernel reads of a (B, T, H, D) operand, rows padded; ``unpack(y, B,
     T)`` the (B, T, H, D) result of what it wrote; ``index(bh, rows)`` the
     block of either that holds row block ``rows`` of head ``bh`` of the
-    (B*H, ...) grid. The kernel sees a (1, rows, D) block either way."""
-    if not _in_place(D):
+    (B*H, ...) grid. The kernel sees a (1, rows, D) block either way, D the
+    operand's own width."""
+    if not _in_place(Dqk, Dv):
         return _to_bh, _from_bh, lambda bh, rows: (bh, rows, 0)
+    D = LANES
 
     def pack(x, pad):
         B, T = x.shape[:2]
@@ -471,19 +508,23 @@ def _walked_block(causal, resident, walked, n_walked, first):
 _CALL_STATICS = ("blocks", "causal", "scale", "masked", "interpret")
 
 
-def _schedule(kernel, D, causal, Tq, Tk, block_q, block_k):
+def _schedule(kernel, Dqk, Dv, causal, Tq, Tk, block_q, block_k):
     """(block_q, block_k, sub) of one call and whether it holds a masked
-    sub-tile; counts the call's sub-tiles. The three ``_*_call`` below are
-    jitted on these, so the calls of a model's layers are traced and
-    lowered once a program, not once a layer."""
-    bq, bk, sub = _default_blocks(D, causal, Tq, Tk, block_q, block_k, kernel)
+    sub-tile, from the wider of its two widths; counts the call and its
+    sub-tiles. The three ``_*_call`` below are jitted on these, so the calls
+    of a model's layers are traced and lowered once a program, not once a
+    layer."""
+    bq, bk, sub = _default_blocks(max(Dqk, Dv), causal, Tq, Tk, block_q,
+                                  block_k, kernel)
     counts = (flash_tile_counts(Tq + (-Tq) % bq, Tk, sub, bk, bk, causal)
               if kernel == "flash_dkv" else
               flash_tile_counts(Tq, Tk, bq, bk, sub, causal))
     for what, n in zip(("total", "computed", "masked"), counts):
         _m_subtiles[what].labels(kernel=kernel).inc(n)
-    _m_calls.labels(kernel=kernel, layout="in_place" if _in_place(D)
-                    else "transposed").inc()
+    _m_calls.labels(
+        kernel=kernel,
+        layout="in_place" if _in_place(Dqk, Dv) else "transposed",
+        widths=widths_label(Dqk, Dv)).inc()
     return (bq, bk, sub), counts[2] > 0
 
 
@@ -503,20 +544,24 @@ def _dq_call(q, k, v, do, lse, dvec, *, blocks, causal, scale, masked,
              interpret):
     bq, bk, sub = blocks
     B, Tq, H, D = q.shape
-    pack, unpack, head = _head_layout(H, D)
+    Dv = v.shape[-1]
+    pack, unpack, head = _head_layout(H, D, Dv)
     (qb, kb, vb, dob), rows, seq_k = _bwd_operands(pack, q, k, v, do, lse,
                                                    dvec, bq, bk)
     nq, nk = qb.shape[1] // bq, kb.shape[1] // bk
     k_block = _walked_block(causal, bq, bk, nk, first=False)
-    qspec = pl.BlockSpec((1, bq, D), lambda b, i, j: head(b, i))
-    kspec = pl.BlockSpec((1, bk, D), lambda b, i, j: head(b, k_block(i, j)))
+    qspec, dospec = (pl.BlockSpec((1, bq, w), lambda b, i, j: head(b, i))
+                     for w in (D, Dv))
+    kspec, vspec = (pl.BlockSpec((1, bk, w),
+                                 lambda b, i, j: head(b, k_block(i, j)))
+                    for w in (D, Dv))
     qrow = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0))
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, block_q=bq, block_k=bk,
                           sub=sub, causal=causal, scale=scale, seq_k=seq_k,
                           masked=masked),
         grid=(B * H, nq, nk),
-        in_specs=[qspec, kspec, kspec, qspec, qrow, qrow],
+        in_specs=[qspec, kspec, vspec, dospec, qrow, qrow],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct(qb.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
@@ -531,15 +576,18 @@ def _dkv_call(q, k, v, do, lse, dvec, *, blocks, causal, scale, masked,
               interpret):
     bq, bk, sub = blocks
     B, Tq, H, D = q.shape
-    Tk = k.shape[1]
-    pack, unpack, head = _head_layout(H, D)
+    Tk, Dv = k.shape[1], v.shape[-1]
+    pack, unpack, head = _head_layout(H, D, Dv)
     (qb, kb, vb, dob), rows, seq_k = _bwd_operands(pack, q, k, v, do, lse,
                                                    dvec, bq, bk)
     nq, nk = qb.shape[1] // bq, kb.shape[1] // bk
     # K blocks outer (the accumulators live per K block), Q blocks inner
     q_block = _walked_block(causal, bk, bq, nq, first=True)
-    qspec = pl.BlockSpec((1, bq, D), lambda b, i, j: head(b, q_block(i, j)))
-    kspec = pl.BlockSpec((1, bk, D), lambda b, i, j: head(b, i))
+    qspec, dospec = (pl.BlockSpec((1, bq, w),
+                                  lambda b, i, j: head(b, q_block(i, j)))
+                     for w in (D, Dv))
+    kspec, vspec = (pl.BlockSpec((1, bk, w), lambda b, i, j: head(b, i))
+                    for w in (D, Dv))
     # lse and D as row vectors, one row a Q sub-tile
     qrow = pl.BlockSpec((1, 1, bq // sub, sub),
                         lambda b, i, j: (b, q_block(i, j), 0, 0))
@@ -548,32 +596,32 @@ def _dkv_call(q, k, v, do, lse, dvec, *, blocks, causal, scale, masked,
                           sub=sub, causal=causal, scale=scale, seq_k=seq_k,
                           masked=masked),
         grid=(B * H, nk, nq),
-        in_specs=[qspec, kspec, kspec, qspec, qrow, qrow],
-        out_specs=(kspec, kspec),
+        in_specs=[qspec, kspec, vspec, dospec, qrow, qrow],
+        out_specs=(kspec, vspec),
         out_shape=(jax.ShapeDtypeStruct(kb.shape, k.dtype),
                    jax.ShapeDtypeStruct(vb.shape, v.dtype)),
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                        pltpu.VMEM((bk, D), jnp.float32)],
+                        pltpu.VMEM((bk, Dv), jnp.float32)],
         interpret=interpret,
         name="flash_dkv",
     )(qb, kb, vb, dob, *(r.reshape(B * H, nq, bq // sub, sub) for r in rows))
     return unpack(dk, B, Tk), unpack(dv, B, Tk)
 
 
-@jax.jit
-def _row_dots(do, out):
-    """D_i = rowsum(dO * O) of every head, (B, T, H, D) x 2 -> (B*H, T)
-    float32: the cheap elementwise residual of the backward. Where the calls
-    read a head in place, so does this: a head's products are a lane block
-    of the (B, T, H*D) views, summed a head at a time, because a reduction
-    over the last dimension of a (B, T, H, D) form, taken before the
-    product or after it, makes the compiler re-tile what it reduces in
-    float32 first (five times the bytes, compiled for a v5e). Jitted as the
-    calls are: the heads' operations are lowered once a program, not once a
-    layer."""
+@functools.partial(jax.jit, static_argnames=("in_place",))
+def _row_dots(do, out, *, in_place):
+    """D_i = rowsum(dO * O) of every head over v's width, (B, T, H, Dv) x 2
+    -> (B*H, T) float32: the cheap elementwise residual of the backward.
+    Where the calls read a head in place, so does this: a head's products
+    are a lane block of the (B, T, H*D) views, summed a head at a time,
+    because a reduction over the last dimension of a (B, T, H, D) form,
+    taken before the product or after it, makes the compiler re-tile what it
+    reduces in float32 first (five times the bytes, compiled for a v5e).
+    Jitted as the calls are: the heads' operations are lowered once a
+    program, not once a layer."""
     B, T, H, D = out.shape
     f32 = jnp.float32
-    if not _in_place(D):
+    if not in_place:
         return jnp.sum(do.astype(f32) * out.astype(f32),
                        axis=-1).transpose(0, 2, 1).reshape(B * H, T)
     do, out = do.reshape(B, T, H * D), out.reshape(B, T, H * D)
@@ -589,11 +637,11 @@ def _flash_attention_bwd(causal, scale, block_q, block_k, interpret,
     Tk = k.shape[1]
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
     interpret = _interpret() if interpret is None else interpret
-    dvec = _row_dots(g, out)
+    dvec = _row_dots(g, out, in_place=_in_place(D, v.shape[-1]))
 
     def grad(kernel, call):
-        blocks, masked = _schedule(kernel, D, causal, Tq, Tk, block_q,
-                                   block_k)
+        blocks, masked = _schedule(kernel, D, v.shape[-1], causal, Tq, Tk,
+                                   block_q, block_k)
         return call(q, k, v, g, lse, dvec, blocks=blocks, causal=causal,
                     scale=scale, masked=masked, interpret=interpret)
 
@@ -667,9 +715,9 @@ def _default_blocks(D, causal, Tq, Tk, block_q=None, block_k=None,
 def _fwd_call(q, k, v, *, blocks, causal, scale, masked, interpret):
     block_q, block_k, sub = blocks
     B, Tq, H, D = q.shape
-    Tk = k.shape[1]
+    Tk, Dv = k.shape[1], v.shape[-1]
     pq, pk = (-Tq) % block_q, (-Tk) % block_k
-    pack, unpack, head = _head_layout(H, D)
+    pack, unpack, head = _head_layout(H, D, Dv)
     qb, kb, vb = pack(q, pq), pack(k, pk), pack(v, pk)
     nq, nk = qb.shape[1] // block_q, kb.shape[1] // block_k
     kernel = functools.partial(_flash_kernel, block_q=block_q,
@@ -677,20 +725,23 @@ def _fwd_call(q, k, v, *, blocks, causal, scale, masked, interpret):
                                scale=scale, seq_k=Tk if pk else None,
                                masked=masked)
     k_block = _walked_block(causal, block_q, block_k, nk, first=False)
-    qspec = pl.BlockSpec((1, block_q, D), lambda b, i, j: head(b, i))
-    kspec = pl.BlockSpec((1, block_k, D),
-                         lambda b, i, j: head(b, k_block(i, j)))
+    qspec, ospec = (pl.BlockSpec((1, block_q, w), lambda b, i, j: head(b, i))
+                    for w in (D, Dv))
+    kspec, vspec = (pl.BlockSpec((1, block_k, w),
+                                 lambda b, i, j: head(b, k_block(i, j)))
+                    for w in (D, Dv))
     out, lse = pl.pallas_call(
         kernel,
         grid=(B * H, nq, nk),
-        in_specs=[qspec, kspec, kspec],
-        out_specs=(qspec,
+        in_specs=[qspec, kspec, vspec],
+        out_specs=(ospec,
                    pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))),
-        out_shape=(jax.ShapeDtypeStruct(qb.shape, q.dtype),
+        out_shape=(jax.ShapeDtypeStruct(qb.shape[:2] + (vb.shape[2],),
+                                        q.dtype),
                    jax.ShapeDtypeStruct((B * H, qb.shape[1], 1),
                                         jnp.float32)),
         scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
             pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, LANES if sub % LANES == 0 else 1),
                        jnp.float32),
@@ -707,8 +758,8 @@ def _flash_attention_fwd_impl(q, k, v, causal, scale, block_q, block_k,
     Tk = k.shape[1]
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
     interpret = _interpret() if interpret is None else interpret
-    blocks, masked = _schedule("flash_fwd", D, causal, Tq, Tk, block_q,
-                               block_k)
+    blocks, masked = _schedule("flash_fwd", D, v.shape[-1], causal, Tq, Tk,
+                               block_q, block_k)
     return _fwd_call(q, k, v, blocks=blocks, causal=causal, scale=scale,
                      masked=masked, interpret=interpret)
 

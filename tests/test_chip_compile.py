@@ -118,6 +118,53 @@ def test_flash_kernels_compile_for_v5e(one_chip, B, Tq, Tk, H, D, causal,
     assert len(copies) == _COPIES_AS_THEY_WERE.get((Tq, Tk, D), 0), copies
 
 
+_KERNEL_CALL = re.compile(
+    r"%(flash_\w+?)(?:\.\d+)? = (.*?) custom-call\(.*"
+    r"operand_layout_constraints=\{(.*?)\}, frontend_attributes")
+
+
+def _kernel_widths(hlo):
+    """{kernel: (last dimension of each operand, of each result)} of the
+    compiled program's flash calls."""
+    def lanes(shapes):
+        return tuple(int(dims.split(",")[-1])
+                     for dims in re.findall(r"\w+\[([\d,]+)\]", shapes))
+    return {name: (lanes(operands), lanes(results))
+            for name, results, operands in _KERNEL_CALL.findall(hlo)}
+
+
+# what the three kernels of a latent head read and write, by last dimension:
+# q, k, dq, dk at 256 lanes, v, O, dO, dv at 128; then the row statistics
+_TWO_WIDTHS = {"flash_fwd": ((256, 256, 128), (128, 1)),
+               "flash_dq": ((256, 256, 128, 128, 1, 1), (256,)),
+               "flash_dkv": ((256, 256, 128, 128, 512, 512), (256, 128))}
+
+
+@pytest.mark.parametrize("T", [pytest.param(4096, id="joyai-cell"),
+                               pytest.param(2048, id="kimilinear-cell")])
+def test_two_width_flash_kernels_compile_for_v5e(one_chip, T):
+    """A latent head's calls at the two cells' sizes, (8, T, 8, 256 / 128):
+    the three kernels lower to Mosaic and fit VMEM with v, O, dO and dv at
+    v's width, and of the optimised program's six transposing copies (the
+    six of `_COPIES_AS_THEY_WERE` at 256 lanes) four are of q's size, q and
+    k in and dq and dk out, and two of v's, half as many bytes."""
+    B, H, D, Dv = 8, 8, 256, 128
+    q = jax.ShapeDtypeStruct((B, T, H * D), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((B, T, H * Dv), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        q, k, v = (a.reshape(B, T, H, -1) for a in (q, k, v))
+        out = flash_attention(q, k, v, True, None, None, None, False)
+        return jnp.sum(out.reshape(B, T, H * Dv).astype(jnp.float32))
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, v)
+    assert lowered.as_text().count("tpu_custom_call") == 3
+    hlo = lowered.compile().as_text()
+    assert _kernel_widths(hlo) == _TWO_WIDTHS
+    assert [len(_operand_sized_copies(hlo, {B * T * H * w}))
+            for w in (D, Dv)] == [4, 2]
+
+
 @pytest.mark.parametrize("B,T,H,D", [
     pytest.param(8, 2048, 16, 128, id="cgpt-cell"),
     pytest.param(2, 4096, 4, 128, id="chip-smoke-D"),
@@ -176,9 +223,11 @@ def test_rotary_latent_layer_compiles_for_v5e(one_chip, monkeypatch):
     """`joyai_train_stream`'s latent layer at its own size (8 rows of 4,096
     positions, 8 heads, query/key 128 + 64 rotated, value 128, the query
     behind a 1,536-wide bottleneck), forward and gradient: the rotation is
-    plain XLA before the three flash calls padded to 256 lanes, and no
-    operand of the compiled program is viewed by pairs, (..., 32, 2), a
-    shape the chip would tile 64 times over."""
+    plain XLA before the three flash calls, and no operand of the compiled
+    program is viewed by pairs, (..., 32, 2), a shape the chip would tile 64
+    times over. The calls take two widths: q, k, dq and dk at 256 lanes
+    (192 zero-padded) are the only (B*H, T, 256) arrays the program holds,
+    and v, O, dO and dv cross the kernels at their own 128."""
     from mmlspark_tpu.models import kimi_linear as kl
     from mmlspark_tpu.ops import pallas_kernels
     monkeypatch.setattr(pallas_kernels, "_interpret", lambda: False)
@@ -196,6 +245,50 @@ def test_rotary_latent_layer_compiles_for_v5e(one_chip, monkeypatch):
             params, x).compile().as_text()
     assert hlo.count('custom_call_target="tpu_custom_call"') == 3
     assert re.findall(r"\w+\[[\d,]*,32,2\]", hlo) == []
+    assert _kernel_widths(hlo) == _TWO_WIDTHS
+    copied = re.findall(rf"^\s*(?:ROOT )?%(\S+) = \w+\[{B * 8},{T},256\]", hlo,
+                        re.M)
+    assert len(copied) == 4, copied           # q, k in; dq, dk out
+
+
+def test_remat_blocks_keep_the_flash_residuals_on_v5e(one_chip, monkeypatch):
+    """Two of `joyai_train_stream`'s blocks under `remat` (latent mixer,
+    dense SwiGLU) at 8 rows of 4,096 positions, gradient: the backward pass
+    keeps each flash call's result and log-sum-exp (`remat_block`'s policy)
+    and recomputes the rest of the block, so the compiled program runs
+    `flash_fwd` once a block, not twice, beside one `flash_dq` and one
+    `flash_dkv`."""
+    import functools
+    import flax.linen as nn
+    from mmlspark_tpu.models import kimi_linear as kl
+    from mmlspark_tpu.ops import pallas_kernels
+    monkeypatch.setattr(pallas_kernels, "_interpret", lambda: False)
+    B, T, d = 8, 4096, 2048
+    mixer = functools.partial(
+        kl.MLALayer, 8, 512, 128, 64, 128, kl.causal_attention("flash", 512),
+        1e-6, jnp.bfloat16, 1536, 32e6)
+    mlp = functools.partial(kl.SwiGLU, 7168, jnp.bfloat16)
+
+    class Two(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            for i in range(2):
+                x, _ = kl.remat_block()(mixer, mlp, 1e-6, jnp.bfloat16,
+                                        name=f"block{i}")(x)
+            return x
+
+    model = Two()
+    x = jax.ShapeDtypeStruct((B, T, d), jnp.bfloat16, sharding=one_chip)
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, T, d), jnp.bfloat16)))
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), params)
+    hlo = jax.jit(jax.grad(lambda p, x: jnp.sum(
+        model.apply(p, x).astype(jnp.float32)))).lower(
+            params, x).compile().as_text()
+    calls = re.findall(r"%(flash_\w+?)(?:\.\d+)? = ", hlo)
+    assert sorted(calls) == ["flash_dkv"] * 2 + ["flash_dq"] * 2 + \
+        ["flash_fwd"] * 2
 
 
 def test_vocabulary_loss_walk_holds_no_whole_logits_on_v5e(one_chip):

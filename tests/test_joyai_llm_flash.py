@@ -119,9 +119,7 @@ def test_rotated_scores_depend_on_the_distance_alone():
 
 # ---------------------------------------------------- the latent layer
 
-def attention(q, k, v, scale):
-    return blockwise_attention(q, k, v, block_size=8, causal=True,
-                               scale=scale)
+attention = kl.causal_attention("blockwise", 8)
 
 
 def make_mla(cfg, **over):
@@ -163,7 +161,10 @@ def test_reference_softmax_in_query_blocks_is_the_whole_softmax(monkeypatch):
 
 class _MLAAsItWas(nn.Module):
     """`MLALayer.__call__` as it stood before the bottleneck and the
-    rotation (PR 31's tree), kept here as the pin it is compared with."""
+    rotation (PR 31's tree), kept here as the pin it is compared with: it
+    pads q, k and v to one width and cuts the result, which since PR 33 is
+    what `causal_attention`'s blockwise branch does with the v it is handed
+    unpadded."""
     heads: int
     kv_rank: int
     nope_dim: int
@@ -202,11 +203,15 @@ class _MLAAsItWas(nn.Module):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_latent_layer_without_both_is_the_one_kimi_had(dtype):
-    """Neither field set (kimi's build): the same parameters, the same
-    program (jaxpr for jaxpr) and the same bits as the layer before."""
-    args = (2, 16, 8, 4, 8, attention, 1e-5, dtype)
+    """Neither field set (kimi's build), on the blockwise path: the same
+    parameters, the same program (jaxpr for jaxpr) and the same bits as the
+    layer before, which padded v itself and took a one-width attention."""
+    def one_width(q, k, v, scale):
+        return blockwise_attention(q, k, v, block_size=8, causal=True,
+                                   scale=scale)
     x = jax.random.normal(jax.random.PRNGKey(1), (3, 19, 32), dtype)
-    new, old = kl.MLALayer(*args), _MLAAsItWas(*args)
+    new = kl.MLALayer(2, 16, 8, 4, 8, attention, 1e-5, dtype)
+    old = _MLAAsItWas(2, 16, 8, 4, 8, one_width, 1e-5, dtype)
     p = old.init(jax.random.PRNGKey(2), x)
     assert jax.tree_util.tree_all(jax.tree_util.tree_map(
         lambda a, b: bool(jnp.array_equal(a, b)), p,

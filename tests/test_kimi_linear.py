@@ -31,7 +31,6 @@ from mmlspark_tpu.models.modules import (example_input,       # noqa: E402
 from mmlspark_tpu.models.moe import DroplessMoE, grouped_expert_mlp  # noqa: E402
 from mmlspark_tpu.ops import delta_rule                       # noqa: E402
 from mmlspark_tpu.ops.delta_rule import chunked_delta_rule    # noqa: E402
-from mmlspark_tpu.parallel.sequence import blockwise_attention  # noqa: E402
 
 F32 = jnp.float32
 
@@ -235,9 +234,7 @@ def layer_config(heads=2):
     return cfg
 
 
-def attention(q, k, v, scale):
-    return blockwise_attention(q, k, v, block_size=8, causal=True,
-                               scale=scale)
+attention = kl.causal_attention("blockwise", 8)
 
 
 def make_mixer(kind, cfg):

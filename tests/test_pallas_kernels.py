@@ -74,26 +74,31 @@ def _with_sub(monkeypatch, sub):
         lambda block, want: sub if block % sub == 0 else block)
 
 
+def _out_and_grads(attn, args, w):
+    """attn(q, k, v) and the gradients of sum(out * w) by the three, in
+    float32 numpy."""
+    import jax
+
+    def loss(q, k, v):
+        out = attn(q, k, v)
+        return jnp.sum(out.astype(jnp.float32) * w), out
+    (_, out), grads = jax.value_and_grad(
+        loss, argnums=(0, 1, 2), has_aux=True)(*args)
+    return [np.asarray(a, dtype=np.float32) for a in (out, *grads)]
+
+
 def _flash_vs_plain(Tq, Tk, bq, bk, causal, dtype, rng, H=2, D=8,
                     reference=plain_attention):
     """Forward and the three gradients of both, in float32 numpy."""
-    import jax
     q = jnp.asarray(rng.normal(size=(2, Tq, H, D)).astype(np.float32))
     k = jnp.asarray(rng.normal(size=(2, Tk, H, D)).astype(np.float32))
     v = jnp.asarray(rng.normal(size=(2, Tk, H, D)).astype(np.float32))
     w = jnp.asarray(rng.normal(size=(2, Tq, H, D)).astype(np.float32))
-
-    def run(attn, args):
-        def loss(q, k, v):
-            out = attn(q, k, v)
-            return jnp.sum(out.astype(jnp.float32) * w), out
-        (_, out), grads = jax.value_and_grad(
-            loss, argnums=(0, 1, 2), has_aux=True)(*args)
-        return [np.asarray(a, dtype=np.float32) for a in (out, *grads)]
-
-    got = run(lambda q, k, v: flash_attention(q, k, v, causal, None, bq, bk),
-              [a.astype(dtype) for a in (q, k, v)])
-    want = run(lambda q, k, v: reference(q, k, v, causal=causal), (q, k, v))
+    got = _out_and_grads(
+        lambda q, k, v: flash_attention(q, k, v, causal, None, bq, bk),
+        [a.astype(dtype) for a in (q, k, v)], w)
+    want = _out_and_grads(lambda q, k, v: reference(q, k, v, causal=causal),
+                          (q, k, v), w)
     return got, want
 
 
@@ -263,6 +268,67 @@ def test_flash_heads_of_whole_lane_tiles(rng, D, H, Tq, Tk, bq, bk, causal):
         np.testing.assert_allclose(g, r, atol=2e-3, rtol=2e-3)
 
 
+# A latent head: q and k wider than v. (Tq, Tk, block_q, block_k) as above:
+# a sequence no block divides, cross lengths either way.
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("Tq,Tk,bq,bk", _LANE_TILE_LENGTHS)
+@pytest.mark.parametrize("qk,Dqk,Dv", [
+    pytest.param(256, 256, 128, id="256/128"),
+    pytest.param(192, 256, 128, id="192-padded-to-256/128"),
+])
+def test_flash_two_widths(rng, qk, Dqk, Dv, Tq, Tk, bq, bk, causal, dtype):
+    """v narrower than q and k: values and the three gradients against the
+    plain softmax at the widths the model asks for (q and k `qk` wide, the
+    scale theirs) and against the zero-padded one-width call this replaces
+    (v padded to `Dqk`, the result and dv cut back), which computes the same
+    sums with zero terms more."""
+    B, H = 2, 2
+
+    def draw(T, D):
+        return jnp.asarray(rng.normal(size=(B, T, H, D)).astype(np.float32))
+    q, k, v, w = draw(Tq, qk), draw(Tk, qk), draw(Tk, Dv), draw(Tq, Dv)
+    scale = qk ** -0.5
+
+    def lanes(a, width):
+        return jnp.pad(a, ((0, 0),) * 3 + ((0, width - a.shape[-1]),))
+
+    def two_widths(q, k, v):
+        return flash_attention(lanes(q, Dqk), lanes(k, Dqk), v, causal,
+                               scale, bq, bk)
+
+    def one_width(q, k, v):
+        return flash_attention(lanes(q, Dqk), lanes(k, Dqk), lanes(v, Dqk),
+                               causal, scale, bq, bk)[..., :Dv]
+
+    def plain(q, k, v):
+        return plain_attention(q, k, v, causal=causal, scale=scale)
+
+    cast = [a.astype(dtype) for a in (q, k, v)]
+    got, padded, want = (_out_and_grads(two_widths, cast, w),
+                         _out_and_grads(one_width, cast, w),
+                         _out_and_grads(plain, (q, k, v), w))
+    assert [a.shape for a in got] == [a.shape for a in (w, q, k, v)]
+    if dtype == jnp.float32:
+        # the same products in the same order, the padded call's with zero
+        # terms more: equal to float32's rounding of the sums
+        for g, p in zip(got, padded):
+            np.testing.assert_allclose(g, p, atol=1e-6, rtol=1e-6)
+        np.testing.assert_allclose(got[0], want[0], atol=1e-5, rtol=1e-5)
+        for g, r in zip(got[1:], want[1:]):
+            np.testing.assert_allclose(g, r, atol=2e-3, rtol=2e-3)
+    else:
+        # bfloat16 operands, float32 sums: zeros change no product and no
+        # sum, so the two calls agree to the bit
+        for g, p in zip(got, padded):
+            np.testing.assert_array_equal(g, p)
+        np.testing.assert_allclose(got[0], want[0], atol=3e-2, rtol=3e-2)
+        for g, r in zip(got[1:], want[1:]):
+            top = max(1e-3, float(np.abs(r).max()))
+            np.testing.assert_allclose(g / top, r / top, atol=5e-2)
+
+
 def _equations(jaxpr):
     """Every equation of a jaxpr and of the jaxprs it calls (the jitted
     calls), the kernels' own bodies left out."""
@@ -277,18 +343,24 @@ def _equations(jaxpr):
                     yield from _equations(inner)
 
 
-@pytest.mark.parametrize("D,fwd_transposes,grad_transposes", [
+@pytest.mark.parametrize("D,Dv,fwd_transposes,grad_transposes", [
     # the copied layout as it was: q, k, v in and O out; the same again in
     # the gradient's forward, q, k, v, dO in twice, dq, dk, dv out
-    pytest.param(64, 4, 15, id="d64-transposed"),
-    pytest.param(128, 0, 0, id="d128-in-place"),
-    pytest.param(256, 4, 15, id="d256-transposed"),
+    pytest.param(64, 64, 4, 15, id="d64-transposed"),
+    pytest.param(128, 128, 0, 0, id="d128-in-place"),
+    pytest.param(256, 256, 4, 15, id="d256-transposed"),
+    # two widths: the same copies, those of v, O, dO and dv at v's width
+    pytest.param(256, 128, 4, 15, id="d256/128-transposed"),
 ])
-def test_flash_layout_copies_in_jaxpr(D, fwd_transposes, grad_transposes):
+def test_flash_layout_copies_in_jaxpr(D, Dv, fwd_transposes,
+                                      grad_transposes):
     """No transpose of an operand-sized array around the in-place calls,
-    forward or gradient; the other widths hold what they held."""
+    forward or gradient; the other widths hold what they held. A call at
+    two widths holds no v, O, dO or dv at q's width: each kernel reads v and
+    dO and writes O and dv at v's own, and only q, k, dq and dk at q's."""
     import jax
     q = jnp.zeros((1, 256, 2, D), jnp.float32)
+    v = jnp.zeros((1, 256, 2, Dv), jnp.float32)
 
     def attend(q, k, v):
         return flash_attention(q, k, v, True)
@@ -297,19 +369,29 @@ def test_flash_layout_copies_in_jaxpr(D, fwd_transposes, grad_transposes):
                     argnums=(0, 1, 2))
     for fn, calls, want in ((attend, 1, fwd_transposes),
                             (grad, 3, grad_transposes)):
-        eqns = list(_equations(jax.make_jaxpr(fn)(q, q, q).jaxpr))
+        jaxpr = jax.make_jaxpr(fn)(q, q, v)
+        eqns = list(_equations(jaxpr.jaxpr))
         assert sum(e.primitive.name == "pallas_call" for e in eqns) == calls
         assert sum(e.primitive.name == "transpose"
-                   and e.outvars[0].aval.size == q.size
+                   and e.outvars[0].aval.size in (q.size, v.size)
                    for e in eqns) == want
+        if D == Dv == 128:
+            continue        # in place: the kernels see (B, T, H*D) arrays
+        # what each kernel is handed and what it writes, by last dimension:
+        # q, k (and dO after v in the backward calls), then the results
+        kernels = [e for e in eqns if e.primitive.name == "pallas_call"]
+        assert [tuple(a.aval.shape[-1] for a in e.invars[:n])
+                for e, n in zip(kernels, (3, 4, 4))] == \
+            [(D, D, Dv), (D, D, Dv, Dv), (D, D, Dv, Dv)][:calls]
+        assert [tuple(o.aval.shape[-1] for o in e.outvars)
+                for e in kernels] == [(Dv, 1), (D,), (D, Dv)][:calls]
+        assert [a.shape[-1] for a in jaxpr.out_avals] == \
+            [[Dv], [D, D, Dv]][calls == 3]
 
 
-@pytest.mark.parametrize("D,layout", [(64, "transposed"), (128, "in_place"),
-                                      (256, "transposed")])
-def test_flash_calls_counter(monkeypatch, D, layout):
-    """One increment a call built, labelled by kernel and by how it
-    addresses a head; the choice follows the width alone."""
-    import jax
+def _flash_calls_counted(monkeypatch, trace):
+    """What `mmlspark_flash_calls_total` grew by while `trace()` ran:
+    {(kernel, layout, widths): calls}."""
     from mmlspark_tpu import telemetry
     monkeypatch.setattr(sys.modules["mmlspark_tpu.telemetry.registry"]._state,
                         "enabled", True)
@@ -317,16 +399,72 @@ def test_flash_calls_counter(monkeypatch, D, layout):
     def read():
         series = telemetry.registry.snapshot()[
             "mmlspark_flash_calls_total"]["series"]
-        return {(s["labels"]["kernel"], s["labels"]["layout"]): s["value"]
-                for s in series}
+        return {tuple(s["labels"][n] for n in ("kernel", "layout", "widths")):
+                s["value"] for s in series}
     before = read()
-    q = jnp.zeros((1, 256, 3, D), jnp.float32)
-    jax.make_jaxpr(jax.grad(lambda q: jnp.sum(
-        flash_attention(q, q, q, True))))(q)
-    grew = {key: n - before.get(key, 0.0) for key, n in read().items()
+    trace()
+    return {key: n - before.get(key, 0.0) for key, n in read().items()
             if n != before.get(key, 0.0)}
-    assert grew == {(kernel, layout): 1.0
+
+
+@pytest.mark.parametrize("D,Dv,layout,widths", [
+    (64, 64, "transposed", "64"), (128, 128, "in_place", "128"),
+    (256, 256, "transposed", "256"), (256, 128, "transposed", "256/128"),
+    (192, 128, "transposed", "192/128"), (128, 256, "transposed", "128/256")])
+def test_flash_calls_counter(monkeypatch, D, Dv, layout, widths):
+    """One increment a call built, labelled by kernel, by how it addresses
+    a head and by the head's widths; the choice follows the two widths
+    alone (in place only where both are one lane tile)."""
+    import jax
+    q = jnp.zeros((1, 256, 3, D), jnp.float32)
+    v = jnp.zeros((1, 256, 3, Dv), jnp.float32)
+    grew = _flash_calls_counted(monkeypatch, lambda: jax.make_jaxpr(jax.grad(
+        lambda q, v: jnp.sum(flash_attention(q, q, v, True)),
+        argnums=(0, 1)))(q, v))
+    assert grew == {(kernel, layout, widths): 1.0
                     for kernel in ("flash_fwd", "flash_dq", "flash_dkv")}
+
+
+@pytest.mark.parametrize("cell,layout,widths,blocks", [
+    ("cgpt1p3b_train_stream", "in_place", "128", 6),
+    ("kimilinear_train_stream", "transposed", "256/128", 1),
+    ("joyai_train_stream", "transposed", "256/128", 6),
+])
+def test_flash_calls_counter_at_the_cells_sizes(monkeypatch, cell, layout,
+                                                widths, blocks):
+    """The benchmark's three sequence cells as its own driver builds them
+    (configuration and traffic from the manifest: 8 rows of 2,048 or 4,096
+    ids, `remat`), traced and not run: every flash call of the step is
+    counted under the layout and the widths its heads ask for, a block's
+    forward twice (`remat`), its dq and dkv once."""
+    import json
+    import os
+    import jax
+    from benchmark.drivers.train_stream import build_learner
+    from mmlspark_tpu.models import build_model
+    root = os.path.join(os.path.dirname(__file__), os.pardir)
+
+    def load(*path):
+        with open(os.path.join(root, *path)) as f:
+            return json.load(f)
+    entry, = (w for w in load("BENCHMARK.json")["workloads"]
+              if w["name"] == cell)
+    config = load("benchmark", "configs", entry["config"] + ".json")
+    traffic = load("benchmark", "traffic", entry["traffic"] + ".json")
+    model_cfg = dict(build_learner(config, traffic, 0).getModelConfig())
+    model = build_model({**model_cfg, "attn_impl": "flash"})
+    T = config["input"]["seq_len"]
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, T), jnp.int32)))
+    kw = ({"row_losses": True} if config["learner"]["loss"] == "next_token"
+          else {})
+    grew = _flash_calls_counted(monkeypatch, lambda: jax.eval_shape(
+        jax.grad(lambda p, t: jnp.sum(
+            model.apply(p, t, **kw).astype(jnp.float32))), params,
+        jax.ShapeDtypeStruct((traffic["batch_rows"], T), jnp.int32)))
+    assert grew == {("flash_fwd", layout, widths): 2.0 * blocks,
+                    ("flash_dq", layout, widths): 1.0 * blocks,
+                    ("flash_dkv", layout, widths): 1.0 * blocks}
 
 
 def test_histogram_matches_numpy(rng):

@@ -16,6 +16,11 @@ gradient program, which runs all three kernels): less the kernels it is what
 `flash_attention` puts around its calls. The programs take q, k, v as the
 models hand them over, (B, T, H*D) arrays viewed as (B, T, H, D), and return
 results of that form, so no re-tiling of an entry parameter is counted.
+A shape with two widths (the latent layers': q and k at `Dqk`, v at `Dv`) is
+timed as built and, on the next line (`"call": "padded"`), as the one-width
+call it replaces, v zero-padded to `Dqk` before the call and the result cut
+back after it; its shares are of the work at the widths built and, as
+`*_asked_share_pct`, at the widths the model asks for (192-wide q and k).
 `--fwd` / `--dq` / `--dkv` take `block_q x block_k x sub` candidates and put
 them in `_default_blocks`' place for that kernel, the others as shipped.
 No cell runs this; it fails where JAX finds no TPU.
@@ -40,9 +45,13 @@ from benchmark.flops import attention
 from benchmark.layer_metrics import flash_bwd_roofline, flash_fwd_roofline
 from mmlspark_tpu.ops import pallas_kernels as pk
 
-SHAPES = {                      # B, T, H, D, causal
+ASKED = (192, 128)              # a latent head's q, k and v before padding
+SHAPES = {                      # B, T, H, D or (Dqk, Dv), causal
     "cell": (8, 2048, 16, 128, True),       # cgpt1p3b_train_stream's
-    "latent": (8, 2048, 8, 256, True),      # kimilinear's, padded to 256
+    "latent": (8, 2048, 8, (256, 128), True),       # kimilinear's
+    "latent4k": (8, 4096, 8, (256, 128), True),     # joyai's
+    "latent192": (8, 2048, 8, ASKED, True),   # q, k not padded: not shipped
+    "latent4k192": (8, 4096, 8, ASKED, True),
     "long": (8, 4096, 4, 128, True),        # chip_smoke stage D's family
     "long_nc": (8, 4096, 4, 128, False),
     "d64": (8, 4096, 8, 64, True),
@@ -72,18 +81,28 @@ def kernel_ms(fn, args):
     return out
 
 
-def measure(shape, override=None):
+def widths(D):
+    return D if isinstance(D, tuple) else (D, D)
+
+
+def measure(shape, override=None, padded=False):
     """{kernel: blocks used}, {kernel: ms a call}: the forward from a
     forward-only program, dq and dkv from a gradient program; under
-    `override` ({kernel: (block_q, block_k, sub)}) only that kernel's."""
+    `override` ({kernel: (block_q, block_k, sub)}) only that kernel's.
+    `padded`: v enters the call zero-padded to q's width."""
     B, T, H, D, causal = SHAPES[shape]
+    Dqk, Dv = widths(D)
+    D = max(Dqk, Dv)
     rng = np.random.default_rng(0)
-    q, k, v = (jnp.asarray(rng.normal(size=(B, T, H * D)), jnp.bfloat16)
-               for _ in range(3))
+    q, k, v = (jnp.asarray(rng.normal(size=(B, T, H * w)), jnp.bfloat16)
+               for w in (Dqk, Dqk, Dv))
 
     def attend(q, k, v):
-        q, k, v = (a.reshape(B, -1, H, D) for a in (q, k, v))
-        return pk.flash_attention(q, k, v, causal).reshape(B, T, H * D)
+        q, k, v = (a.reshape(B, T, H, -1) for a in (q, k, v))
+        if padded:
+            v = jnp.pad(v, ((0, 0),) * 3 + ((0, Dqk - Dv),))
+        out = pk.flash_attention(q, k, v, causal)[..., :Dv]
+        return out.reshape(B, T, H * Dv)
     shipped = pk._default_blocks
 
     def blocks(D, causal, Tq, Tk, block_q=None, block_k=None,
@@ -112,21 +131,37 @@ def measure(shape, override=None):
     return used, ms
 
 
-def report(shape, used, ms, peaks):
+def least_ms(B, T, H, Dqk, Dv, causal, backward, peaks):
+    """The least milliseconds of a call at two widths, product by product
+    (`benchmark/flops/attention.py` counts one width: equal at Dqk == Dv).
+    Forward QK^T and PV; backward S, dP, dq, dk, dv. Bytes: q, k, v, o once
+    forward; those, dO, dq, dk, dv once backward."""
+    lanes = 3 * Dqk + 2 * Dv if backward else Dqk + Dv
+    ops = 2.0 * B * H * T * T * lanes * (0.5 if causal else 1.0)
+    nbytes = 2.0 * B * H * T * 2 * (Dqk + Dv) * (2 if backward else 1)
+    return 1e3 * attention.least_seconds(ops, nbytes, peaks)[0]
+
+
+def report(shape, used, ms, peaks, padded=False):
     B, T, H, D, causal = SHAPES[shape]
-    line = {"shape": shape,
+    built = (max(widths(D)),) * 2 if padded else widths(D)
+    line = {"shape": shape, "call": "padded" if padded else "as built",
+            "widths": pk.widths_label(*built),
             "blocks": {n: "x".join(map(str, used[n])) for n in ms
                        if n in used}}
     line.update({n + "_ms": t for n, t in ms.items()})
-    if "flash_fwd" in ms:
-        least, _ = attention.least_seconds(
-            *attention.flash_fwd(B, H, T, D, causal), peaks)
-        line["fwd_share_pct"] = 100 * 1e3 * least / ms["flash_fwd"]
     if "flash_dq" in ms and "flash_dkv" in ms:
-        least, _ = attention.least_seconds(
-            *attention.flash_bwd(B, H, T, D, causal), peaks)
         line["bwd_ms"] = ms["flash_dq"] + ms["flash_dkv"]
-        line["bwd_share_pct"] = 100 * 1e3 * least / line["bwd_ms"]
+    for key, kernel, backward in (("fwd", "flash_fwd", False),
+                                  ("bwd", "bwd", True)):
+        took = line.get(kernel + "_ms")
+        if took is None:
+            continue
+        line[key + "_share_pct"] = 100 * least_ms(
+            B, T, H, *built, causal, backward, peaks) / took
+        if isinstance(D, tuple):
+            line[key + "_asked_share_pct"] = 100 * least_ms(
+                B, T, H, *ASKED, causal, backward, peaks) / took
     print(json.dumps(line), flush=True)
 
 
@@ -148,6 +183,8 @@ def main():
     print(json.dumps({"device": dev.device_kind, "calls": CALLS}), flush=True)
     for shape in args.shape.split(","):
         report(shape, *measure(shape), peaks)
+        if len(set(widths(SHAPES[shape][3]))) == 2:
+            report(shape, *measure(shape, padded=True), peaks, padded=True)
         for kernel in KERNELS:
             for cand in getattr(args, kernel[len("flash_"):]):
                 try:
