@@ -26,9 +26,12 @@ prediction block over all T positions with the ids rolled; the block is
 causal, so a scored position never sees the wrapped one.
 
 Not here: a learning-rate schedule, the selection bias's update rule (it
-stays 0), grouped-query heads, decode with a latent cache, experts across
-chips with their all-to-all, a two-width flash kernel, the head and loss as
-one kernel, packing with segment ids.
+stays 0), grouped-query latent heads (the flash kernels take a key/value
+head count since the `lfm2_moe` family, models/lfm2_moe.py; latent
+attention has as many of either), decode with a latent cache, experts
+across chips with their all-to-all, the head and loss as one kernel,
+packing with segment ids. `chunked_token_losses`, `_Leaf` and `_rms_norm`
+also serve the `lfm2_moe` family, whose head is its embedding's transpose.
 """
 
 from __future__ import annotations

@@ -27,7 +27,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Any, Optional, Sequence
+from typing import Any, Callable, Optional, Sequence
 
 import flax.linen as nn
 import jax
@@ -69,9 +69,11 @@ def _conv_init(key, shape, dtype=jnp.float32):
 
 
 class _ShortConv(nn.Module):
-    """Causal depthwise convolution over time, then SiLU:
-    y_t = silu(sum_i w_i x_{t-(n-1)+i})."""
+    """Causal depthwise convolution over time, then `activation` (SiLU, as
+    KDA's q, k, v take it; None for none, as the `lfm2_moe` family's gated
+    mixer takes it): y_t = act(sum_i w_i x_{t-(n-1)+i})."""
     kernel_size: int
+    activation: Optional[Callable] = nn.silu
 
     @nn.compact
     def __call__(self, x):
@@ -79,7 +81,7 @@ class _ShortConv(nn.Module):
         w = self.param("kernel", _conv_init, (n, x.shape[-1]), jnp.float32)
         xp = jnp.pad(x, ((0, 0), (n - 1, 0), (0, 0)))
         y = sum(xp[:, i:i + T] * w[i].astype(x.dtype) for i in range(n))
-        return nn.silu(y)
+        return y if self.activation is None else self.activation(y)
 
 
 class KDALayer(nn.Module):
@@ -221,12 +223,13 @@ class MLALayer(nn.Module):
 
 
 def causal_attention(attn_impl: str, block_size: int):
-    """(q, k, v, scale) -> o, causal, over q and k (B, T, H, Dqk) and v
-    (B, T, H, Dv), o as wide as v: the Pallas flash kernel (``flash``;
-    ``auto`` on a TPU), which takes the two widths as they are, or the
-    single-device blockwise recurrence (``blockwise``; ``auto`` elsewhere),
-    which takes one: v is zero-padded to q's width for it and the padded
-    columns of its result are cut off."""
+    """(q, k, v, scale) -> o, causal, over q (B, T, H, Dqk), k (B, T, Hkv,
+    Dqk) and v (B, T, Hkv, Dv), o as wide as v with q's heads: the Pallas
+    flash kernel (``flash``; ``auto`` on a TPU), which takes the two widths
+    and the two head counts as they are, or the single-device blockwise
+    recurrence (``blockwise``; ``auto`` elsewhere), which takes one of
+    each: v is zero-padded to q's width for it (the padded columns of its
+    result are cut off) and grouped k and v are repeated to q's heads."""
     def attention(q, k, v, scale):
         impl = attn_impl
         if impl == "auto":
@@ -236,6 +239,9 @@ def causal_attention(attn_impl: str, block_size: int):
             from ..ops.pallas_kernels import flash_attention
             return flash_attention(q, k, v, causal=True, scale=scale)
         from ..parallel.sequence import blockwise_attention
+        group = q.shape[2] // k.shape[2]
+        if group > 1:
+            k, v = (jnp.repeat(a, group, axis=2) for a in (k, v))
         o = blockwise_attention(q, k, _lane_padded(v, q.shape[-1]),
                                 block_size=block_size, causal=True,
                                 scale=scale)

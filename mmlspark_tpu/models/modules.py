@@ -477,7 +477,8 @@ class TransformerEncoder(nn.Module):
 # ---------------------------------------------------------------- registry
 
 # families whose input is int token ids (callers must cast features to int32)
-TOKEN_MODELS = ("bilstm", "transformer", "kimi_linear", "joyai_llm_flash")
+TOKEN_MODELS = ("bilstm", "transformer", "kimi_linear", "joyai_llm_flash",
+                "lfm2_moe")
 
 MODEL_BUILDERS: dict[str, Callable[..., nn.Module]] = {
     "mlp": lambda cfg: MLPNet(
@@ -541,6 +542,10 @@ MODEL_BUILDERS: dict[str, Callable[..., nn.Module]] = {
     # same experts, a vocabulary head with a per-token loss and a
     # multi-token-prediction module (models/joyai_llm_flash.py)
     "joyai_llm_flash": lambda cfg: _joyai_llm_flash(cfg),
+    # gated short convolutions and grouped-query attention with per-head
+    # QK-norm and whole-head rotary, the same experts without a shared one,
+    # a vocabulary head tied to the embedding (models/lfm2_moe.py)
+    "lfm2_moe": lambda cfg: _lfm2_moe(cfg),
 }
 
 
@@ -554,10 +559,16 @@ def _joyai_llm_flash(cfg):
     return build(cfg)
 
 
+def _lfm2_moe(cfg):
+    from .lfm2_moe import build
+    return build(cfg)
+
+
 #: the key that counts the routed experts held, by family (other configs may
 #: carry these keys and route nothing)
 _EXPERTS_KEY = {"transformer": "num_experts", "kimi_linear": "num_experts",
-                "joyai_llm_flash": "n_routed_experts"}
+                "joyai_llm_flash": "n_routed_experts",
+                "lfm2_moe": "num_experts"}
 
 
 def has_experts(config: dict) -> bool:

@@ -326,11 +326,14 @@ class DroplessMoE(nn.Module):
     deployment in float32 (sigmoid scores; the top `top_k` by score +
     `selection_bias`, ties to the lower index; combine weights the chosen
     scores, renormalised to sum 1 where `renormalize`, times
-    `routed_scale`). The layer holds the `num_experts` experts from
+    `routed_scale`; the renormalisation divides by the sum + `renorm_eps`,
+    the family's own: 1e-20 where its source adds that, 1e-6 in `lfm2_moe`).
+    The layer holds the `num_experts` experts from
     `first_expert` on and computes their part of the result, for however
     many tokens chose them (`grouped_expert_mlp`); what the experts held
     elsewhere would add is left out, and no exchange stands in for it. Each
-    of `num_shared` shared experts sees every token. No capacity, no
+    of `num_shared` shared experts sees every token (0 of them in `lfm2_moe`:
+    the routed part is then the whole result). No capacity, no
     auxiliary loss (the family balances through `selection_bias`, a
     parameter that starts at zero and takes no gradient: its update rule is
     the trainer's to bring).
@@ -346,6 +349,7 @@ class DroplessMoE(nn.Module):
     renormalize: bool = True
     routed_scale: float = 1.0
     dtype: Any = jnp.bfloat16
+    renorm_eps: float = 1e-20
 
     @nn.compact
     def __call__(self, x, row_mask=None):
@@ -368,7 +372,8 @@ class DroplessMoE(nn.Module):
         _, chosen = lax.top_k(scores + bias, k)                  # (N, k)
         weight = jnp.take_along_axis(scores, chosen, axis=-1)
         if self.renormalize:
-            weight = weight / (weight.sum(-1, keepdims=True) + 1e-20)
+            weight = weight / (weight.sum(-1, keepdims=True)
+                               + self.renorm_eps)
         weight = weight * self.routed_scale
 
         # the assignments to the experts held here, sorted by expert

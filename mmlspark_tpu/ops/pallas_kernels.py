@@ -71,9 +71,11 @@ _m_calls = telemetry.registry.counter(
     "mmlspark_flash_calls_total",
     "flash attention calls built, by how they address a head: in_place (a "
     "lane block of the (B, T, H*D) array) or transposed (a copy to (B*H, T, "
-    "D)), and by the head's widths (q and k's, or q and k's / v's where "
-    "they differ); both follow the shapes, counted at trace time",
-    labels=("kernel", "layout", "widths"))
+    "D)), by the head's widths (q and k's, or q and k's / v's where "
+    "they differ) and by the query heads that share one key/value head "
+    "(group; 1 where there are as many of either); all follow the shapes, "
+    "counted at trace time",
+    labels=("kernel", "layout", "widths", "group"))
 
 
 def flash_tile_counts(Tq, Tk, block_q, block_k, sub, causal):
@@ -275,21 +277,32 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, block_q: int,
                           block_k: int, sub: int, causal: bool, scale: float,
-                          seq_k, masked: bool):
+                          seq_k, masked: bool, group: int = 1):
     """dv = P^T dO; dk = (P * (dO V^T - D))^T Q * scale, accumulated over
     Q blocks. Grid = (BH, num_k_blocks, num_q_blocks), Q innermost; the walk
     is over Q sub-tiles: [lo, first_bare) masked, [first_bare, n_sub) bare,
-    those before ``lo`` (wholly above the diagonal) not at all.
+    those before ``lo`` (wholly above the diagonal) not at all. Where
+    ``group`` query heads share this key/value head the grid is (B*Hkv,
+    num_k_blocks, group, num_q_blocks): the resident K block walks the Q
+    blocks of one query head after another and the accumulators add up
+    over all of them before dk and dv are written.
 
     The scores are formed transposed, keys on rows (K the left operand, lse
     and D row vectors that broadcast over sublanes): P^T and dS^T then enter
     their products as plain left operands, where (sub, bk) tiles of P and dS
     would each pay a transpose."""
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    q_axis = 2 if group == 1 else 3
+    qi = pl.program_id(q_axis)
     n_sub = block_q // sub
 
-    @pl.when(qi == 0)
+    def at_head(is_q_block, head):
+        """Whether this is that Q block of that query head of the group."""
+        if group == 1:
+            return is_q_block
+        return jnp.logical_and(is_q_block, pl.program_id(2) == head)
+
+    @pl.when(at_head(qi == 0, 0))
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -333,7 +346,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
         _walk(lo, first_bare, lambda j: step(j, True))
     _walk(first_bare, n_sub, lambda j: step(j, False))
 
-    @pl.when(qi == pl.num_programs(2) - 1)
+    @pl.when(at_head(qi == pl.num_programs(q_axis) - 1, group - 1))
     def _finalize():
         dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -343,12 +356,24 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
 def flash_attention(q, k, v, causal: bool = False, scale=None,
                     block_q: int = None, block_k: int = None,
                     interpret=None):
-    """FlashAttention on TPU. q, k: (B, T, H, Dqk), v: (B, Tk, H, Dv) ->
-    (B, T, H, Dv). The two widths may differ (a latent head's queries and
-    keys are wider than its values): q, k, dq and dk are read and written
-    at Dqk, v, the result, its cotangent and dv at Dv, and every product
-    runs over the width its operands have. The default ``scale`` is
-    Dqk ** -0.5.
+    """FlashAttention on TPU. q: (B, T, H, Dqk), k: (B, Tk, Hkv, Dqk), v:
+    (B, Tk, Hkv, Dv) -> (B, T, H, Dv). The two widths may differ (a latent
+    head's queries and keys are wider than its values): q, k, dq and dk are
+    read and written at Dqk, v, the result, its cotangent and dv at Dv, and
+    every product runs over the width its operands have. The default
+    ``scale`` is Dqk ** -0.5.
+
+    The two head counts may differ too (grouped-query attention): k and v
+    share one count Hkv that divides H, and query head h attends to
+    key/value head h // (H / Hkv). K and V are never repeated: they are
+    handed over, copied (where the layout copies at all) and read at Hkv
+    heads, and dk and dv come back at Hkv heads. flash_fwd and flash_dq
+    run over the B*H query heads and index the group's key/value block;
+    flash_dkv runs over the B*Hkv key/value heads and walks the Q blocks of
+    its group's H / Hkv query heads one head after another under the same
+    resident K block, adding all of them up in its accumulators. With
+    Hkv == H the three calls are the programs they were.
+    ``mmlspark_flash_calls_total`` counts the calls built by ``group`` too.
 
     The score matrix stays in VMEM tiles; HBM traffic is O(T*D) instead of
     O(T^2). Sequence dims are padded to block multiples internally (padded
@@ -391,7 +416,11 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
     same kernels on (B*H, T, D) copies read 1.36 | 3.85 ms with 0.80 and
     1.82 ms of copies around them. Copied: (8, 2048, 8, 256) causal 1.15 ms
     60.8% | 3.58 ms 48.7% (in place 1.16 | 3.75); (8, 4096, 8, 64) causal
-    2.22 ms 31.5% | 6.66 ms 26.2%. My chip run, PR 33, two widths, copied
+    2.22 ms 31.5% | 6.66 ms 26.2%; grouped, 8 query heads over 2 key/value
+    heads at that shape (my chip run, PR 34, inside the `lfm2_moe` cell's
+    step; shares of benchmark/flops/lfm2_moe.py's least time, K, V, dK and
+    dV moved at 2 heads): 2.22 ms 31.4% | 6.57 ms 26.6%. My chip run,
+    PR 33, two widths, copied
     (shares of the products at the widths built, each counted at its own):
     (8, 4096, 8, 256 / 128) causal 3.31 ms 63.3% | 10.89 ms 51.2%, where
     the same call with v zero-padded to 256 reads 4.21 | 13.60 ms and the
@@ -468,26 +497,38 @@ def widths_label(Dqk, Dv):
     return str(Dqk) if Dqk == Dv else f"{Dqk}/{Dv}"
 
 
-def _head_layout(H, Dqk, Dv):
-    """(pack, unpack, index) of one call: ``pack(x, pad)`` makes the array
-    the kernel reads of a (B, T, H, D) operand, rows padded; ``unpack(y, B,
-    T)`` the (B, T, H, D) result of what it wrote; ``index(bh, rows)`` the
-    block of either that holds row block ``rows`` of head ``bh`` of the
-    (B*H, ...) grid. The kernel sees a (1, rows, D) block either way, D the
-    operand's own width."""
+def _head_layout(H, Hkv, Dqk, Dv):
+    """(pack, unpack, head, kv_head) of one call: ``pack(x, pad)`` makes the
+    array the kernel reads of a (B, T, heads, D) operand, rows padded;
+    ``unpack(y, B, T)`` the (B, T, heads, D) result of what it wrote, either
+    at the operand's own count of heads (H for q, dO, O and dq, Hkv for k,
+    v, dk and dv: nothing is repeated); ``head(bh, rows)`` the block that
+    holds row block ``rows`` of query head ``bh`` of B*H, ``kv_head(b,
+    rows)`` of key/value head ``b`` of B*Hkv. The kernel sees a (1, rows, D)
+    block either way, D the operand's own width."""
     if not _in_place(Dqk, Dv):
-        return _to_bh, _from_bh, lambda bh, rows: (bh, rows, 0)
+        at = lambda bh, rows: (bh, rows, 0)
+        return _to_bh, _from_bh, at, at
     D = LANES
 
     def pack(x, pad):
         B, T = x.shape[:2]
-        x = x.reshape(B, T, H * D)
+        x = x.reshape(B, T, x.shape[2] * D)
         return jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
 
     def unpack(y, B, T):
-        return y[:, :T].reshape(B, T, H, D)
+        return y[:, :T].reshape(B, T, y.shape[2] // D, D)
 
-    return pack, unpack, lambda bh, rows: (bh // H, rows, bh % H)
+    def lane_block(heads):
+        return lambda b, rows: (b // heads, rows, b % heads)
+
+    return pack, unpack, lane_block(H), lane_block(Hkv)
+
+
+def _kv_of(group):
+    """Query head bh of B*H -> its key/value head of B*Hkv: bh // group (H
+    is Hkv * group, so the batch's part divides too)."""
+    return (lambda bh: bh) if group == 1 else (lambda bh: bh // group)
 
 
 def _walked_block(causal, resident, walked, n_walked, first):
@@ -508,7 +549,7 @@ def _walked_block(causal, resident, walked, n_walked, first):
 _CALL_STATICS = ("blocks", "causal", "scale", "masked", "interpret")
 
 
-def _schedule(kernel, Dqk, Dv, causal, Tq, Tk, block_q, block_k):
+def _schedule(kernel, Dqk, Dv, causal, Tq, Tk, block_q, block_k, group=1):
     """(block_q, block_k, sub) of one call and whether it holds a masked
     sub-tile, from the wider of its two widths; counts the call and its
     sub-tiles. The three ``_*_call`` below are jitted on these, so the calls
@@ -524,7 +565,7 @@ def _schedule(kernel, Dqk, Dv, causal, Tq, Tk, block_q, block_k):
     _m_calls.labels(
         kernel=kernel,
         layout="in_place" if _in_place(Dqk, Dv) else "transposed",
-        widths=widths_label(Dqk, Dv)).inc()
+        widths=widths_label(Dqk, Dv), group=str(group)).inc()
     return (bq, bk, sub), counts[2] > 0
 
 
@@ -544,17 +585,18 @@ def _dq_call(q, k, v, do, lse, dvec, *, blocks, causal, scale, masked,
              interpret):
     bq, bk, sub = blocks
     B, Tq, H, D = q.shape
-    Dv = v.shape[-1]
-    pack, unpack, head = _head_layout(H, D, Dv)
+    Hkv, Dv = k.shape[2], v.shape[-1]
+    pack, unpack, head, kv_head = _head_layout(H, Hkv, D, Dv)
+    kv_of = _kv_of(H // Hkv)
     (qb, kb, vb, dob), rows, seq_k = _bwd_operands(pack, q, k, v, do, lse,
                                                    dvec, bq, bk)
     nq, nk = qb.shape[1] // bq, kb.shape[1] // bk
     k_block = _walked_block(causal, bq, bk, nk, first=False)
     qspec, dospec = (pl.BlockSpec((1, bq, w), lambda b, i, j: head(b, i))
                      for w in (D, Dv))
-    kspec, vspec = (pl.BlockSpec((1, bk, w),
-                                 lambda b, i, j: head(b, k_block(i, j)))
-                    for w in (D, Dv))
+    kspec, vspec = (pl.BlockSpec(
+        (1, bk, w), lambda b, i, j: kv_head(kv_of(b), k_block(i, j)))
+        for w in (D, Dv))
     qrow = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0))
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, block_q=bq, block_k=bk,
@@ -576,26 +618,35 @@ def _dkv_call(q, k, v, do, lse, dvec, *, blocks, causal, scale, masked,
               interpret):
     bq, bk, sub = blocks
     B, Tq, H, D = q.shape
-    Tk, Dv = k.shape[1], v.shape[-1]
-    pack, unpack, head = _head_layout(H, D, Dv)
+    Tk, Hkv, Dv = k.shape[1], k.shape[2], v.shape[-1]
+    g = H // Hkv
+    pack, unpack, head, kv_head = _head_layout(H, Hkv, D, Dv)
     (qb, kb, vb, dob), rows, seq_k = _bwd_operands(pack, q, k, v, do, lse,
                                                    dvec, bq, bk)
     nq, nk = qb.shape[1] // bq, kb.shape[1] // bk
-    # K blocks outer (the accumulators live per K block), Q blocks inner
+    # K blocks outer (the accumulators live per K block), Q blocks inner;
+    # where g query heads share a key/value head, the grid walks the Q
+    # blocks of each of the g in turn under the same resident K block:
+    # (b over B*Hkv, i, head of the group, j), and query head b * g + that
     q_block = _walked_block(causal, bk, bq, nq, first=True)
-    qspec, dospec = (pl.BlockSpec((1, bq, w),
-                                  lambda b, i, j: head(b, q_block(i, j)))
-                     for w in (D, Dv))
-    kspec, vspec = (pl.BlockSpec((1, bk, w), lambda b, i, j: head(b, i))
+    q_head = ((lambda b, gj: b) if g == 1 else
+              (lambda b, gj: b * g + gj[0]))
+    qspec, dospec = (pl.BlockSpec(
+        (1, bq, w),
+        lambda b, i, *gj: head(q_head(b, gj), q_block(i, gj[-1])))
+        for w in (D, Dv))
+    kspec, vspec = (pl.BlockSpec((1, bk, w),
+                                 lambda b, i, *gj: kv_head(b, i))
                     for w in (D, Dv))
     # lse and D as row vectors, one row a Q sub-tile
-    qrow = pl.BlockSpec((1, 1, bq // sub, sub),
-                        lambda b, i, j: (b, q_block(i, j), 0, 0))
+    qrow = pl.BlockSpec(
+        (1, 1, bq // sub, sub),
+        lambda b, i, *gj: (q_head(b, gj), q_block(i, gj[-1]), 0, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, block_q=bq, block_k=bk,
                           sub=sub, causal=causal, scale=scale, seq_k=seq_k,
-                          masked=masked),
-        grid=(B * H, nk, nq),
+                          masked=masked, group=g),
+        grid=(B * Hkv, nk) + ((g,) if g > 1 else ()) + (nq,),
         in_specs=[qspec, kspec, vspec, dospec, qrow, qrow],
         out_specs=(kspec, vspec),
         out_shape=(jax.ShapeDtypeStruct(kb.shape, k.dtype),
@@ -641,7 +692,7 @@ def _flash_attention_bwd(causal, scale, block_q, block_k, interpret,
 
     def grad(kernel, call):
         blocks, masked = _schedule(kernel, D, v.shape[-1], causal, Tq, Tk,
-                                   block_q, block_k)
+                                   block_q, block_k, H // k.shape[2])
         return call(q, k, v, g, lse, dvec, blocks=blocks, causal=causal,
                     scale=scale, masked=masked, interpret=interpret)
 
@@ -715,9 +766,10 @@ def _default_blocks(D, causal, Tq, Tk, block_q=None, block_k=None,
 def _fwd_call(q, k, v, *, blocks, causal, scale, masked, interpret):
     block_q, block_k, sub = blocks
     B, Tq, H, D = q.shape
-    Tk, Dv = k.shape[1], v.shape[-1]
+    Tk, Hkv, Dv = k.shape[1], k.shape[2], v.shape[-1]
     pq, pk = (-Tq) % block_q, (-Tk) % block_k
-    pack, unpack, head = _head_layout(H, D, Dv)
+    pack, unpack, head, kv_head = _head_layout(H, Hkv, D, Dv)
+    kv_of = _kv_of(H // Hkv)
     qb, kb, vb = pack(q, pq), pack(k, pk), pack(v, pk)
     nq, nk = qb.shape[1] // block_q, kb.shape[1] // block_k
     kernel = functools.partial(_flash_kernel, block_q=block_q,
@@ -727,17 +779,18 @@ def _fwd_call(q, k, v, *, blocks, causal, scale, masked, interpret):
     k_block = _walked_block(causal, block_q, block_k, nk, first=False)
     qspec, ospec = (pl.BlockSpec((1, block_q, w), lambda b, i, j: head(b, i))
                     for w in (D, Dv))
-    kspec, vspec = (pl.BlockSpec((1, block_k, w),
-                                 lambda b, i, j: head(b, k_block(i, j)))
-                    for w in (D, Dv))
+    kspec, vspec = (pl.BlockSpec(
+        (1, block_k, w), lambda b, i, j: kv_head(kv_of(b), k_block(i, j)))
+        for w in (D, Dv))
     out, lse = pl.pallas_call(
         kernel,
         grid=(B * H, nq, nk),
         in_specs=[qspec, kspec, vspec],
         out_specs=(ospec,
                    pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))),
-        out_shape=(jax.ShapeDtypeStruct(qb.shape[:2] + (vb.shape[2],),
-                                        q.dtype),
+        # as many heads as q, each as wide as v
+        out_shape=(jax.ShapeDtypeStruct(
+            qb.shape[:2] + (qb.shape[2] // D * Dv,), q.dtype),
                    jax.ShapeDtypeStruct((B * H, qb.shape[1], 1),
                                         jnp.float32)),
         scratch_shapes=[
@@ -755,11 +808,15 @@ def _fwd_call(q, k, v, *, blocks, causal, scale, masked, interpret):
 def _flash_attention_fwd_impl(q, k, v, causal, scale, block_q, block_k,
                               interpret):
     B, Tq, H, D = q.shape
-    Tk = k.shape[1]
+    Tk, Hkv = k.shape[1], k.shape[2]
+    if H % Hkv or v.shape[2] != Hkv:
+        raise ValueError(
+            f"flash_attention: {H} query heads over k with {Hkv} and v with "
+            f"{v.shape[2]} heads; k and v share one count that divides q's")
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
     interpret = _interpret() if interpret is None else interpret
     blocks, masked = _schedule("flash_fwd", D, v.shape[-1], causal, Tq, Tk,
-                               block_q, block_k)
+                               block_q, block_k, H // Hkv)
     return _fwd_call(q, k, v, blocks=blocks, causal=causal, scale=scale,
                      masked=masked, interpret=interpret)
 

@@ -315,3 +315,95 @@ def test_vocabulary_loss_walk_holds_no_whole_logits_on_v5e(one_chip):
     loops = [line for line in hlo.splitlines()
              if re.search(r"= \(.*\) while\(", line)]
     assert loops and all("[8,8,512,2048]" in line for line in loops)
+
+
+def _kernel_heads(hlo):
+    """{kernel: (first dimension of each operand, of each result)} of the
+    compiled program's flash calls: B * heads on the copied layout."""
+    def first(shapes):
+        return tuple(int(dims.split(",")[0])
+                     for dims in re.findall(r"\w+\[([\d,]+)\]", shapes))
+    return {name: (first(operands), first(results))
+            for name, results, operands in _KERNEL_CALL.findall(hlo)}
+
+
+# what the three kernels of a grouped call read and write, by B * heads:
+# q, dO, the row statistics, O and dq at the 8 query heads, k, v, dk and dv
+# at the 2 key/value heads
+_GROUPED = {"flash_fwd": ((64, 16, 16), (64, 64)),
+            "flash_dq": ((64, 16, 16, 64, 64, 64), (64,)),
+            "flash_dkv": ((64, 16, 16, 64, 64, 64), (16, 16))}
+
+
+def test_grouped_flash_kernels_compile_for_v5e(one_chip):
+    """`lfm2moe_train_stream`'s attention call at its own size, (8, 4096,
+    8 query heads over 2 key/value heads, 64): the three kernels lower to
+    Mosaic with the group walked inside them (flash_dkv's grid has one
+    dimension more), K, V, dK and dV cross them at 2 heads, and the
+    optimised program holds no K or V repeated to the 8 query heads."""
+    B, T, H, Hkv, D = 8, 4096, 8, 2, 64
+    q = jax.ShapeDtypeStruct((B, T, H * D), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((B, T, Hkv * D), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        q, k, v = (a.reshape(B, T, -1, D) for a in (q, k, v))
+        out = flash_attention(q, k, v, True, None, None, None, False)
+        return jnp.sum(out.reshape(B, T, H * D).astype(jnp.float32))
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, k)
+    assert lowered.as_text().count("tpu_custom_call") == 3
+    hlo = lowered.compile().as_text()
+    assert _kernel_heads(hlo) == _GROUPED
+    # what `jnp.repeat` of K or V to the query heads would have made
+    assert re.findall(rf"\[{B},{T},{Hkv},{H // Hkv},{D}\]", hlo) == []
+
+
+def test_lfm2_step_compiles_and_fits_v5e(one_chip, monkeypatch):
+    """`lfm2moe_train_stream`'s whole optimizer step as `fitStream` builds
+    it (the benchmark's configuration, 8 rows of 4,096 ids, AdamW, `remat`,
+    the per-token loss), compiled for a described v5e: the grouped flash
+    kernels once each (the attention block keeps the forward's residuals),
+    no (B * T, V) logits, and arguments + results + temporaries within the
+    15.9 GB the issue gives the cell (the chip's `bytes_limit` is 16.9 GB:
+    two generations of 12 bytes a parameter and one step's temporaries)."""
+    import json
+    import os
+    from mmlspark_tpu.models import build_model, trainer
+    from mmlspark_tpu.ops import pallas_kernels
+    monkeypatch.setattr(pallas_kernels, "_interpret", lambda: False)
+    root = os.path.join(os.path.dirname(__file__), os.pardir)
+    with open(os.path.join(root, "benchmark", "configs",
+                           "lfm2_8b_a1b.json")) as f:
+        config = json.load(f)
+    meta = ("source", "input", "learner", "published", "reduced",
+            "departures", "assumed", "rehearsal", "parameters")
+    module = build_model({**{k: v for k, v in config.items()
+                             if k not in meta}, "attn_impl": "flash"})
+    lp, T = config["learner"], config["input"]["seq_len"]
+    tx = trainer.make_optimizer(lp["optimizer"], lp["learningRate"], 0.9,
+                                lp["weightDecay"])
+    body = trainer._make_step_body(
+        module, tx, trainer.make_loss(lp["loss"], per_example=True), True,
+        0.0)
+    placed = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                            sharding=one_chip)
+    params = jax.tree.map(placed, jax.eval_shape(lambda: module.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, T), jnp.int32))))
+    opt = jax.tree.map(placed, jax.eval_shape(tx.init, params))
+    rows = jax.ShapeDtypeStruct((8,), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(body).lower(
+        params, opt, jax.ShapeDtypeStruct((8, T), jnp.int32,
+                                          sharding=one_chip),
+        rows, rows).compile()
+    hlo = compiled.as_text()
+    assert sorted(re.findall(r"%(flash_\w+?)(?:\.\d+)? = ", hlo)) == \
+        ["flash_dkv", "flash_dq", "flash_fwd"]
+    assert _kernel_heads(hlo) == _GROUPED
+    V = config["vocab_size"]
+    assert re.findall(rf"\[(?:{8 * T}|8,{T}),{V}\]", hlo) == []
+    m = compiled.memory_analysis()
+    held = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert 11.9e9 < m.argument_size_in_bytes + m.output_size_in_bytes \
+        < 12.1e9        # 499,955,968 parameters x 12 bytes, twice
+    assert held < 15.9e9, held
