@@ -124,8 +124,23 @@ def read_moe_aux_loss(intermediates) -> jnp.ndarray:
 
 # ------------------------------------------------ dropless, share-aware
 
-#: rows of one grouped product: a tile holds tokens of one expert
+#: rows of one grouped product at the least: a tile holds tokens of one
+#: expert. `tile_rows` sets a layer's own from its static load, this many
+#: times a power of two
 GROUP_TILE = 256
+#: a tile's rows double while a uniform share (what one held expert gets of
+#: evenly routed tokens) still fills this many tiles: a tile re-reads its
+#: expert's three matrices and, backward, rewrites their three float32
+#: gradient accumulators, so a held expert's assignments in few large tiles
+#: move a fraction of the bytes; a share in fewer tiles would leave more of
+#: its last one empty. One layer's walk alone at 32,768 tokens, 2048 x 1792,
+#: 4,096 assignments a held expert (`tools/time_grouped_mlp.py`, TPU v5e;
+#: PERF.md section 6, PR 35), forward / backward loop: 29.1 / 81.1 ms at 256
+#: rows, 25.4 / 53.4 at 512, 24.5 / 44.5 at 1,024, 24.1 / 43.4 at 2,048
+GROUP_SHARE_TILES = 4
+#: and the most rows of a tile: past 1,024 the same sweep gains 3% of the
+#: loops for twice a tile's float32 activations
+GROUP_TILE_MAX = 1024
 #: the tile walk's floor, in uniform shares: a layer walks at least the tiles
 #: that twice its share of evenly routed tokens would fill, so a step's time
 #: is the same whatever the routing until the load passes that. At a fresh
@@ -134,16 +149,35 @@ GROUP_TILE = 256
 GROUP_FLOOR_SHARES = 2
 
 
-def _tiles(token, weight, counts, min_tiles, n_tokens):
+def tile_rows(share: int) -> int:
+    """Rows of a layer's tiles from its static load, `share` = tokens a step
+    x experts a token // router outputs (what one held expert receives under
+    uniform routing): GROUP_TILE times the largest power of two that still
+    cuts a share into GROUP_SHARE_TILES tiles, never under GROUP_TILE and
+    never over GROUP_TILE_MAX."""
+    rows = GROUP_TILE
+    while (2 * rows <= GROUP_TILE_MAX
+           and 2 * rows * GROUP_SHARE_TILES <= share):
+        rows *= 2
+    return rows
+
+
+def floor_tiles(N: int, k: int, E: int, W: int, rows: int) -> int:
+    """The least tiles of `rows` a layer walks: what GROUP_FLOOR_SHARES
+    uniform shares of its E held experts fill, where N tokens take k of W
+    experts each."""
+    return -(-GROUP_FLOOR_SHARES * N * k * E // (W * rows))
+
+
+def _tiles(token, weight, counts, min_tiles, n_tokens, tile):
     """The walk over the assignments sorted by held expert, in tiles of
-    GROUP_TILE of one expert: (number of tiles, tile t -> (expert, first
+    `tile` rows of one expert: (number of tiles, tile t -> (expert, first
     row, which rows are real, their tokens, their weights or 0)). Every
     expert's group is cut into ceil(count / tile) tiles, expert after
     expert; the lists are padded by one tile, so a tile that begins at a
     real assignment never runs past their end. The walk has at least
     `min_tiles` tiles: those after the routing's own have no real row. A row
     that is not real carries the token `n_tokens`, one past the last."""
-    tile = GROUP_TILE
     tiles = (counts + tile - 1) // tile
     ends, first = jnp.cumsum(tiles), jnp.cumsum(counts) - counts
     token, weight = jnp.pad(token, (0, tile)), jnp.pad(weight, (0, tile))
@@ -176,35 +210,49 @@ def _expert(w, e):
     return lax.dynamic_index_in_dim(w, e, 0, keepdims=False)
 
 
-@jax.custom_vjp
 def grouped_expert_mlp(x, w_gate, w_up, w_down, token, weight, counts,
-                       min_tiles):
+                       min_tiles, rows=None):
     """SwiGLU experts over the tokens routed to them, nothing dropped.
 
     x: (N, d) tokens. w_gate, w_up: (E, d, f); w_down: (E, f, d): the E
     experts held here. `token` (R,) int32 and `weight` (R,) float32 list the
     assignments (token, combine weight) sorted by held expert, `counts` (E,)
     how many belong to each; entries after sum(counts) are ignored. Returns
-    (y, rows): y (N, d) float32 = sum over a token's assignments of weight *
-    expert(x), and the number of assignments computed (== sum(counts)).
+    (y, computed): y (N, d) float32 = sum over a token's assignments of
+    weight * expert(x), and the number of assignments computed
+    (== sum(counts)).
 
-    The work is a `while` over tiles of GROUP_TILE assignments of one expert
-    whose trip count is the number of tiles the routing needs, and at least
-    `min_tiles`: shapes are static (R is the worst case, every token on every
-    held expert), nothing is dropped however uneven the routing, and up to
+    The work is a `while` over tiles of `rows` assignments of one expert
+    (static, a shape of the program: GROUP_TILE unless the caller gives it;
+    `DroplessMoE` gives `tile_rows` of its layer's static load, so that a
+    held expert's matrices are read, and their gradients' accumulators
+    rewritten, a few times a layer whatever the load). The trip count is the
+    number of tiles the routing needs, and at least `min_tiles` (a value):
+    shapes are static (R is the worst case, every token on every held
+    expert), nothing is dropped however uneven the routing, and up to
     `min_tiles` the time does not follow the load (a tile with no real row
     costs what a full one does). The backward pass is the same walk (a
     dynamic trip count has no transpose of its own); it recomputes a tile's
-    hidden activations."""
+    hidden activations. The result does not depend on `rows` but for the
+    order in which float32 sums are added."""
+    return _grouped(x, w_gate, w_up, w_down, token, weight, counts,
+                    min_tiles, rows or GROUP_TILE)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
+def _grouped(x, w_gate, w_up, w_down, token, weight, counts, min_tiles,
+             rows):
     return _grouped_fwd(x, w_gate, w_up, w_down, token, weight, counts,
-                        min_tiles)[0]
+                        min_tiles, rows)[0]
 
 
-def _grouped_fwd(x, w_gate, w_up, w_down, token, weight, counts, min_tiles):
-    n_tiles, fetch = _tiles(token, weight, counts, min_tiles, x.shape[0])
+def _grouped_fwd(x, w_gate, w_up, w_down, token, weight, counts, min_tiles,
+                 rows):
+    n_tiles, fetch = _tiles(token, weight, counts, min_tiles, x.shape[0],
+                            rows)
 
     def body(c):
-        t, y, rows = c
+        t, y, computed = c
         e, _, valid, tok, w = fetch(t)
         xs = _take(x, tok)
         a = xs @ _expert(w_gate, e)
@@ -212,19 +260,20 @@ def _grouped_fwd(x, w_gate, w_up, w_down, token, weight, counts, min_tiles):
         o = jnp.dot((jax.nn.silu(a) * b), _expert(w_down, e),
                     preferred_element_type=jnp.float32)
         y = _add(y, tok, o * w[:, None])
-        return t + 1, y, rows + jnp.sum(valid, dtype=jnp.int32)
+        return t + 1, y, computed + jnp.sum(valid, dtype=jnp.int32)
 
-    _, y, rows = lax.while_loop(
+    _, y, computed = lax.while_loop(
         lambda c: c[0] < n_tiles, body,
         (jnp.int32(0), jnp.zeros(x.shape, jnp.float32), jnp.int32(0)))
-    return (y, rows), (x, w_gate, w_up, w_down, token, weight, counts,
-                       min_tiles)
+    return (y, computed), (x, w_gate, w_up, w_down, token, weight, counts,
+                           min_tiles)
 
 
-def _grouped_bwd(res, cts):
+def _grouped_bwd(rows, res, cts):
     x, w_gate, w_up, w_down, token, weight, counts, min_tiles = res
     dy = cts[0].astype(x.dtype)
-    n_tiles, fetch = _tiles(token, weight, counts, min_tiles, x.shape[0])
+    n_tiles, fetch = _tiles(token, weight, counts, min_tiles, x.shape[0],
+                            rows)
     f32 = jnp.float32
 
     def body(c):
@@ -255,7 +304,7 @@ def _grouped_bwd(res, cts):
     R = token.shape[0]
     init = (jnp.int32(0), jnp.zeros(x.shape, f32),
             jnp.zeros(w_gate.shape, f32), jnp.zeros(w_up.shape, f32),
-            jnp.zeros(w_down.shape, f32), jnp.zeros((R + GROUP_TILE,), f32))
+            jnp.zeros(w_down.shape, f32), jnp.zeros((R + rows,), f32))
     _, dx, dg, du, dd, dwt = lax.while_loop(lambda c: c[0] < n_tiles, body,
                                             init)
     return (dx.astype(x.dtype), dg.astype(w_gate.dtype),
@@ -263,7 +312,7 @@ def _grouped_bwd(res, cts):
             dwt[:R].astype(weight.dtype), None, None)
 
 
-grouped_expert_mlp.defvjp(_grouped_fwd, _grouped_bwd)
+_grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 
 _m_experts_held = telemetry.registry.counter(
@@ -274,10 +323,15 @@ _m_router_width = telemetry.registry.counter(
     "mmlspark_moe_router_width",
     "router outputs (experts routed over, here or elsewhere) of the dropless "
     "expert layers built (counted at trace time)", labels=("layer",))
+_m_tile_rows = telemetry.registry.gauge(
+    "mmlspark_moe_tile_rows",
+    "rows of a tile of the dropless expert layer last built (`tile_rows` of "
+    "its static load: set at trace time)", labels=("layer",))
 
 #: what a dropless expert layer reports a step, in this order
 MOE_STEP_STATS = ("moe_tokens_routed", "moe_expert_tokens_max",
-                  "moe_tokens_dropped")
+                  "moe_tokens_dropped", "moe_tiles_needed",
+                  "moe_tiles_walked")
 
 
 _m_step = {
@@ -338,8 +392,10 @@ class DroplessMoE(nn.Module):
     parameter that starts at zero and takes no gradient: its update rule is
     the trainer's to bring).
 
-    `stats` is int32[3], `MOE_STEP_STATS`: assignments routed to the held
-    experts, the fullest held expert's, and routed minus computed (0)."""
+    `stats` is int32[5], `MOE_STEP_STATS`: assignments routed to the held
+    experts, the fullest held expert's, routed minus computed (0), the tiles
+    the routing filled and the tiles walked (those or the floor, the
+    greater)."""
     num_experts: int              # held here
     router_width: int             # routed over
     d_hidden: int
@@ -392,15 +448,18 @@ class DroplessMoE(nn.Module):
             for n, shape in (("expert_gate", (E, d, self.d_hidden)),
                              ("expert_up", (E, d, self.d_hidden)),
                              ("expert_down", (E, self.d_hidden, d))))
-        min_tiles = -(-GROUP_FLOOR_SHARES * N * k * E // (W * GROUP_TILE))
+        rows = tile_rows(N * k // W)
+        _m_tile_rows.labels(layer="/".join(self.path)).set(rows)
+        min_tiles = floor_tiles(N, k, E, W, rows)
         y, computed = grouped_expert_mlp(
             xf.astype(self.dtype), w_gate, w_up, w_down,
             (order // k).astype(jnp.int32), weight.reshape(-1)[order], counts,
-            min_tiles)
+            min_tiles, rows)
         y = y.astype(self.dtype)
         for i in range(self.num_shared):
             y = y + SwiGLU(self.d_hidden, self.dtype,
                            name=f"shared{i}")(xf)
-        routed = jnp.sum(counts)
-        stats = jnp.stack([routed, jnp.max(counts), routed - computed])
+        routed, needed = jnp.sum(counts), jnp.sum(-(-counts // rows))
+        stats = jnp.stack([routed, jnp.max(counts), routed - computed,
+                           needed, jnp.maximum(needed, min_tiles)])
         return y.reshape(B, T, d).astype(x.dtype), stats

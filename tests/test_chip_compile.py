@@ -365,10 +365,13 @@ def test_lfm2_step_compiles_and_fits_v5e(one_chip, monkeypatch):
     kernels once each (the attention block keeps the forward's residuals),
     no (B * T, V) logits, and arguments + results + temporaries within the
     15.9 GB the issue gives the cell (the chip's `bytes_limit` is 16.9 GB:
-    two generations of 12 bytes a parameter and one step's temporaries)."""
+    two generations of 12 bytes a parameter and one step's temporaries).
+    The four expert layers walk tiles of 1,024 rows (`moe.tile_rows` of the
+    deployment's 4,096 assignments a held expert): one forward and one
+    backward tile loop a layer, `remat`'s second forward loop dead code."""
     import json
     import os
-    from mmlspark_tpu.models import build_model, trainer
+    from mmlspark_tpu.models import build_model, moe, trainer
     from mmlspark_tpu.ops import pallas_kernels
     monkeypatch.setattr(pallas_kernels, "_interpret", lambda: False)
     root = os.path.join(os.path.dirname(__file__), os.pardir)
@@ -399,6 +402,19 @@ def test_lfm2_step_compiles_and_fits_v5e(one_chip, monkeypatch):
     assert sorted(re.findall(r"%(flash_\w+?)(?:\.\d+)? = ", hlo)) == \
         ["flash_dkv", "flash_dq", "flash_fwd"]
     assert _kernel_heads(hlo) == _GROUPED
+    # the tile loops carry the (tokens, hidden) accumulator after the counter
+    # (`lfm2_moe_grouped_ms`'s rule); the backward ones then the float32
+    # gradients of the three weight stacks
+    N, d, f = 8 * T, config["hidden_size"], config["moe_intermediate_size"]
+    E, k = config["num_experts"], config["num_experts_per_tok"]
+    carried = re.compile(rf"= \(s32\[\], f32\[{N},{d}\], (\w+\[[\d,]*\])")
+    loops = [m.group(1) for line in hlo.splitlines() if " while(" in line
+             for m in [carried.search(re.sub(r"\{[^}]*\}", "", line))] if m]
+    assert sorted(loops) == [f"f32[{E},{d},{f}]"] * 4 + ["s32[]"] * 4
+    # the assignment lists are padded by one tile: 1,024 rows, not 256
+    assert moe.tile_rows(N * k // config["router_width"]) == 1024
+    assert re.findall(rf"\[{N * k + 1024}\]", hlo)
+    assert not re.findall(rf"\[{N * k + 256}\]", hlo)
     V = config["vocab_size"]
     assert re.findall(rf"\[(?:{8 * T}|8,{T}),{V}\]", hlo) == []
     m = compiled.memory_analysis()

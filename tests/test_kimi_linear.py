@@ -299,7 +299,7 @@ def test_experts_match_reference_and_drop_nothing(routing):
     if routing in forced:
         p = with_bias(p, forced[routing], 16)
     y, stats = jax.jit(layer.apply)(p, x)
-    routed, fullest, dropped = (int(s) for s in stats)
+    routed, fullest, dropped = (int(s) for s in stats[:3])
     N = B * T
     want = {"all_to_one_held": (N, N), "none_held": (0, 0),
             "all_held_chosen": (4 * N, N)}.get(routing)
@@ -353,14 +353,17 @@ def test_grouped_experts_ignore_the_tail_and_padded_rows():
     close(y_masked[::2], y_two, 2e-5)
 
 
+@pytest.mark.parametrize("tile", [4, 8])
 @pytest.mark.parametrize("counts", [(5, 0, 7), (0, 0, 0), (0, 20, 0)])
-def test_grouped_experts_floor_of_tiles_changes_nothing(counts, monkeypatch):
+def test_grouped_experts_floor_of_tiles_changes_nothing(counts, tile,
+                                                        monkeypatch):
     """A walk held to more tiles than the routing needs (`min_tiles`: the
     tiles past the routing's own have no real row) gives the same result,
     the same gradients and the same count of assignments computed, bit for
-    bit: what it adds is zeros. Tiles of 4, so that an expert has several."""
+    bit: what it adds is zeros. Tiles of 4 or 8 (the default an unnamed
+    `rows` takes), so that an expert has several."""
     from mmlspark_tpu.models import moe
-    monkeypatch.setattr(moe, "GROUP_TILE", 4)
+    monkeypatch.setattr(moe, "GROUP_TILE", tile)
     ks = jax.random.split(jax.random.PRNGKey(8), 7)
     N, d, f, E = 40, 16, 8, 3
     x = jax.random.normal(ks[0], (N, d), F32)
@@ -389,6 +392,139 @@ def test_grouped_experts_floor_of_tiles_changes_nothing(counts, monkeypatch):
         close(a, b, 0)
     # the weights' gradient past the assignments computed stays zero
     assert not np.any(np.asarray(grads2[4])[int(counts.sum()):])
+
+
+@pytest.mark.parametrize("cell,want", [
+    ("kimilinear", (16384, 8, 256, 256)),
+    ("joyai", (32768, 8, 256, 256)),
+    ("lfm2moe", (32768, 4, 32, 1024)),
+    # the layers this file, test_joyai_llm_flash and test_lfm2_moe build
+    ((300, 4, 16), 256), ((40, 4, 16), 256), ((42, 4, 32), 256),
+    ((128, 4, 16), 256), ((4 * 21, 4, 16), 256),
+    # the rule's steps: four tiles a share, doubling, 1,024 at the most
+    ((1, 1, 4), 256), ((2047, 1, 1), 256), ((2048, 1, 1), 512),
+    ((4095, 1, 1), 512), ((4096, 1, 1), 1024), ((1 << 20, 8, 8), 1024),
+])
+def test_tile_rows_follow_the_layers_static_load(cell, want, monkeypatch):
+    """`moe.tile_rows` of a uniform share N * k // W: at least four tiles a
+    share, 256 rows at the least and 1,024 at the most. The three expert
+    cells' shapes, read from their benchmark files as the walk's timer reads
+    them, give 256 / 256 / 1,024."""
+    from mmlspark_tpu.models import moe
+    if isinstance(cell, str):
+        monkeypatch.syspath_prepend(os.path.join(ROOT, "tools"))
+        import time_grouped_mlp
+        N, _, _, _, k, W = time_grouped_mlp.cell_shape(cell)
+        assert (N, k, W) == want[:3]
+        want = want[3]
+    else:
+        N, k, W = cell
+    rows = moe.tile_rows(N * k // W)
+    assert rows == want
+    assert rows == moe.GROUP_TILE or rows * moe.GROUP_SHARE_TILES <= N * k // W
+    assert moe.GROUP_FLOOR_SHARES == 2
+
+
+@pytest.mark.parametrize("counts", [
+    pytest.param((5, 0, 7), id="an_empty_expert"),
+    pytest.param((0, 33, 0), id="one_expert_holds_everything"),
+    pytest.param((7, 3, 13), id="no_multiple_of_any_tile"),
+    pytest.param((16, 8, 4), id="whole_tiles"),
+])
+def test_grouped_experts_are_the_same_at_every_tile_size(counts):
+    """`grouped_expert_mlp` at 4, 8 and 16 rows a tile (a static argument, no
+    module constant patched) against the loop over assignments: the same
+    result, the same count of assignments computed, gradients within 1e-5 of
+    each other, with and without a floor of tiles. Tokens repeat across
+    experts, as a token's several assignments do."""
+    ks = jax.random.split(jax.random.PRNGKey(9), 8)
+    N, d, f, E = 40, 16, 8, 3
+    x = jax.random.normal(ks[0], (N, d), F32)
+    wg, wu = (jax.random.normal(k, (E, d, f), F32) for k in ks[1:3])
+    wd = jax.random.normal(ks[3], (E, f, d), F32)
+    R = sum(counts)
+    token = jnp.concatenate(
+        [jax.random.permutation(k, N)[:c] for k, c in zip(ks[4:7], counts)]
+        + [jnp.full((N - R,), 3)]).astype(jnp.int32)
+    weight = jax.random.uniform(ks[7], (N,), F32)
+    ct = jax.random.normal(ks[6], (N, d), F32)
+    counts = jnp.asarray(counts, jnp.int32)
+
+    def run(rows, min_tiles):
+        def loss(x, wg, wu, wd, weight):
+            y, computed = grouped_expert_mlp(x, wg, wu, wd, token, weight,
+                                             counts, min_tiles, rows)
+            return jnp.sum(y * ct), (y, computed)
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(x, wg, wu, wd,
+                                                          weight)
+        return out, grads
+
+    want = np.zeros((N, d))
+    expert = np.repeat(np.arange(E), np.asarray(counts))
+    for j in range(R):
+        e = expert[j]
+        h = jax.nn.silu(x[token[j]] @ wg[e]) * (x[token[j]] @ wu[e])
+        want[int(token[j])] += float(weight[j]) * np.asarray(h @ wd[e])
+    (y4, n4), g4 = run(4, 0)
+    close(y4, want, 2e-5)
+    for rows, min_tiles in [(8, 0), (16, 0), (16, 7), (8, 40)]:
+        (y, n), g = run(rows, min_tiles)
+        assert int(n) == int(n4) == R
+        close(y, y4, 1e-6)
+        for a, b in zip(g, g4):
+            close(a, b, 1e-5)
+        assert not np.any(np.asarray(g[4])[R:])
+
+
+@pytest.mark.parametrize("tile", [4, 256])
+@pytest.mark.parametrize("routing", ["seeded", "all_to_one_held",
+                                     "none_held", "all_held_chosen"])
+def test_tiles_needed_and_walked_match_a_hand_count(routing, tile,
+                                                    monkeypatch):
+    """`moe_tiles_needed` is the sum over held experts of ceil(count / rows)
+    and `moe_tiles_walked` that or the floor of two uniform shares, the
+    greater, at the rows `tile_rows` gives the layer: 300 tokens, top 4 of
+    16, 4 held make a share of 75 (256 rows a tile, a floor of 3 tiles; with
+    GROUP_TILE at 4, 16 rows and 38). `mmlspark_moe_tile_rows` says the rows;
+    nothing is dropped."""
+    from mmlspark_tpu.models import moe
+    monkeypatch.setattr(moe, "GROUP_TILE", tile)
+    cfg = small_config()
+    N, k, E, W = 300, 4, 4, 16
+    rows = {4: 16, 256: 256}[tile]
+    assert moe.tile_rows(N * k // W) == rows
+    floor = -(-2 * N * k * E // (W * rows))
+    assert floor == {4: 38, 256: 3}[tile]
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, 150, 32), F32)
+    layer = make_experts(cfg)
+    p = layer.init(jax.random.PRNGKey(5), x)
+    forced = {"all_to_one_held": [1, 9, 10, 11], "none_held": [8, 9, 10, 11],
+              "all_held_chosen": [0, 1, 2, 3]}
+    if routing in forced:
+        p = with_bias(p, forced[routing], W)
+    was = telemetry.enabled()
+    telemetry.enable()
+    try:
+        _, stats = jax.jit(layer.apply)(p, x)
+        gauge, = [s for s in telemetry.snapshot()[
+            "mmlspark_moe_tile_rows"]["series"] if s["labels"]["layer"] == ""]
+        assert gauge["value"] == rows
+    finally:
+        (telemetry.enable if was else telemetry.disable)()
+    # the routing by hand: the top 4 of sigmoid(x . router) + bias
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.reshape(N, -1), p["params"]["router"],
+        precision=jax.lax.Precision.HIGHEST))
+    _, chosen = jax.lax.top_k(scores + p["params"]["selection_bias"], k)
+    counts = np.bincount(np.asarray(chosen).ravel(), minlength=W)[:E]
+    needed = int(sum(-(-int(c) // rows) for c in counts))
+    want = {"all_to_one_held": -(-N // rows), "none_held": 0,
+            "all_held_chosen": E * -(-N // rows)}.get(routing, needed)
+    assert needed == want
+    routed, fullest, dropped, got_needed, walked = (int(s) for s in stats)
+    assert (routed, fullest, dropped) == (counts.sum(), counts.max(), 0)
+    assert (got_needed, walked) == (needed, max(needed, floor))
 
 
 # ------------------------------------------------------- the share test
@@ -666,6 +802,11 @@ def test_step_counts_reach_the_ring_only_with_telemetry_on(on):
         assert all(s["moe_tokens_dropped"] == 0 for s in stats)
         assert all(0 < s["moe_expert_tokens_max"] < s["moe_tokens_routed"]
                    for s in stats)
+        # four expert layers of 128 tokens, top 4 of 16, 4 held: 256 rows a
+        # tile, a floor of 2 * 128 * 4 * 4 / (16 * 256) -> 1 tile a layer,
+        # and a layer's four experts need one tile each
+        assert all(s["moe_tiles_needed"] == s["moe_tiles_walked"] == 4 * 4
+                   for s in stats)
         assert routed.value - before == sum(s["moe_tokens_routed"]
                                               for s in stats)
         assert dropped.value == 0
@@ -680,6 +821,9 @@ def test_step_counts_reach_the_ring_only_with_telemetry_on(on):
                    for layer, n in chunks.items() if layer.startswith("block"))
         held = snap["mmlspark_moe_experts_held"]["series"]
         width = snap["mmlspark_moe_router_width"]["series"]
+        tile = {s["labels"]["layer"]: s["value"]
+                for s in snap["mmlspark_moe_tile_rows"]["series"]}
+        assert all(tile[f"block{i}/mlp"] == 256 for i in range(1, 5))
         assert {s["labels"]["layer"] for s in held} >= {"block1/mlp"}
         by_layer = {s["labels"]["layer"]: s["value"] for s in width}
         for s in held:

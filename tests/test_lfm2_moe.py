@@ -738,3 +738,128 @@ def test_the_new_readers_return_none_on_another_familys_cell(name,
     empty = dict(trace, op_s={}, op_n={}, op_label={})
     if name != "lfm2_expert_tokens_max":
         assert reader.read(empty, counters, own) is None
+
+
+# --------------------------------- the tile's rows, its counts, their readers
+
+def _loop(carried):
+    return (f"(s32[], {carried}{{1,0}}, s32[]) while((s32[], "
+            f"{carried}{{1,0}}, s32[]) %tuple), condition=%c, body=%b")
+
+
+@pytest.mark.parametrize("tile", [256, 1024])
+def test_the_tile_loops_are_read_whatever_a_tiles_rows(tile):
+    """`lfm2_moe_grouped_ms` sums the `while` operations that carry the
+    float32 (tokens, hidden) accumulator after the counter and the sort over
+    one entry an assignment; what a tile gathers (`bf16[rows, hidden]` in the
+    body) is not part of the rule, the tied head's walks and the router's
+    top-k sort are not counted, another family's cell reads None."""
+    from benchmark.layer_metrics import lfm2_moe_grouped_ms as reader
+    labels = {
+        "while.19": _loop("f32[32768,2048]"), "while.23": _loop(
+            "f32[32768,2048]"),
+        "while.27": _loop("f32[8]"),            # the head's forward walk
+        "sort.3": "(s32[131072], s32[131072]) sort(s32[131072] %a)",
+        "sort.9": "(f32[32768,32], s32[32768,32]) sort(f32[32768,32] %s)",
+        f"gather_fusion.{tile}": f"bf16[{tile},2048] fusion(%x)"}
+    seconds = {"while.19": 0.06, "while.23": 0.15, "while.27": 0.03,
+               "sort.3": 0.004, "sort.9": 0.002, f"gather_fusion.{tile}": 0.01}
+    trace = {"steps": 2, "op_s": seconds, "op_n": dict.fromkeys(seconds, 2),
+             "op_label": labels}
+    counters = {"batch_rows": 8}
+    own = {"chips": 1, "config": load_cell_config()}
+    assert abs(reader.read(trace, counters, own) - 107.0) < 1e-9
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "joyai_llm_flash_48b_a3b.json")) as f:
+        assert reader.read(trace, counters, dict(own, config=json.load(f))) \
+            is None
+    assert reader.read(dict(trace, op_s={}, op_n={}, op_label={}), counters,
+                       own) is None
+    assert reader.read(trace, {}, own) is None
+
+
+@pytest.mark.parametrize("stats,want", [
+    ([], None),                                         # telemetry off
+    ([{"moe_expert_tokens_max": 4660, "moe_tokens_dropped": 0}] * 3, None),
+    ([{"moe_tiles_needed": 140, "moe_tiles_walked": 256},
+      {"moe_tiles_needed": 128, "moe_tiles_walked": 256},
+      {"moe_tiles_needed": 300, "moe_tiles_walked": 300}], 54.6875),
+])
+def test_the_needed_share_is_the_median_of_needed_over_walked(stats, want,
+                                                              monkeypatch):
+    """`lfm2_moe_tiles_needed_share`: per cent of the walked tiles that the
+    routing filled, the median over the window's steps; None (never 0, and
+    no exception) on a program that records neither count, as the parent of
+    the PR that brought them, and on another family's cell."""
+    from benchmark.layer_metrics import (lfm2_moe_tiles_needed_share as
+                                         reader, moe_expert_tokens_max)
+    monkeypatch.setattr(moe_expert_tokens_max, "window_stats",
+                        lambda counters: stats)
+    own = {"chips": 1, "config": load_cell_config()}
+    assert reader.read({}, {"window_steps": 3}, own) == want
+    assert reader.read({}, {"window_steps": 3},
+                       {"chips": 1, "config": {"type": "kimi_linear"}}) is None
+
+
+def test_the_models_step_counts_carry_the_tiles():
+    """The model's `step_stats` sum the expert layers' tiles: two expert
+    layers of 84 tokens, top 4 of 16 with 4 held (a share of 21: 256 rows a
+    tile, a floor of one tile a layer), so every held expert that got a
+    token needs one tile and the walk is what is needed."""
+    cfg = small_config()
+    model = build_model(cfg)
+    x = tokens()
+    p = model.init(jax.random.PRNGKey(0), x)
+    _, stats = jax.jit(functools.partial(model.apply, step_stats=True,
+                                         row_losses=True))(p, x)
+    needed, walked = (int(stats[n]) for n in ("moe_tiles_needed",
+                                              "moe_tiles_walked"))
+    assert 2 <= needed == walked <= 2 * 4
+    assert int(stats["moe_tokens_dropped"]) == 0
+
+
+@pytest.mark.parametrize("argv,want", [
+    ([], ((32768, 2048, 1792, 8, 4, 32), (256, 512, 1024, 2048), 0)),
+    (["--cell", "kimilinear", "--rows", "256"],
+     ((16384, 2304, 1024, 8, 8, 256), (256,), 0)),
+    (["--cell", "joyai", "--ops", "5"],
+     ((32768, 2048, 768, 8, 8, 256), (256, 512, 1024, 2048), 5)),
+    (["--shape", "64,16,8,3,2,6", "--rows", "8,16"],
+     ((64, 16, 8, 3, 2, 6), (8, 16), 0)),
+    (["--shape", "64,16,8,7,2,6"], SystemExit),       # more held than routed
+    (["--shape", "64,16,8"], SystemExit),
+    (["--rows", "100"], SystemExit),
+    (["--cell", "cgpt"], SystemExit),
+])
+def test_the_walks_timer_reads_its_arguments(argv, want, monkeypatch, capsys):
+    """`tools/time_grouped_mlp.py` on the CPU: a cell's shape comes from its
+    two benchmark files, a shape or tile sizes that cannot be are refused,
+    the seeded routing's lists are `DroplessMoE`'s (every assignment to a
+    held expert once, sorted), its two programs give the same numbers at two
+    tile sizes, and it times nothing where there is no chip."""
+    monkeypatch.syspath_prepend(os.path.join(ROOT, "tools"))
+    import time_grouped_mlp as timer
+    if want is SystemExit:
+        with pytest.raises(SystemExit):
+            timer.parse(argv)
+        return
+    shape, sizes, ops = timer.parse(argv)
+    assert (shape, sizes, ops) == want
+    if shape[0] > 64:
+        return
+    N, d, f, E, k, W = shape
+    data = timer.inputs(shape)
+    token, counts = np.asarray(data[4]), np.asarray(data[6])
+    assert token.shape == (N * k,) and 0 < counts.sum() < N * k
+    assert np.bincount(token[:counts.sum()], minlength=N).max() <= E
+    outs = []
+    for rows in sizes:
+        fns, floor = timer.programs(shape, rows)
+        assert floor == -(-2 * N * k * E // (W * rows))
+        outs.append(jax.tree_util.tree_leaves(
+            (fns["fwd"](*data), fns["fwd_bwd"](*data))))
+    for a, b in zip(*outs):
+        close(a.astype(F32), b.astype(F32), 1e-2)     # bfloat16 results
+    monkeypatch.setattr(sys, "argv", ["time_grouped_mlp.py"] + argv)
+    with pytest.raises(SystemExit, match="needs a TPU"):
+        timer.main()
