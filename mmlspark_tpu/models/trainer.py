@@ -109,14 +109,15 @@ def _observe_step_stats(step: int, stats: dict):
     """A finished step's counts and loss terms (outputs of the step program,
     on the device until here): the expert layers' counts into the registry
     and all of them, numbered by `step`, onto the ring, whole numbers as ints
-    and the rest as floats."""
+    and the rest as floats. The event `fit/step_stats` spans the read, the
+    one round trip to the device the loop makes a step: time of the loop's
+    thread that neither `fit/feed_wait` nor `fit/dispatch` covers."""
     from .moe import observe_step_stats
+    start = time.perf_counter_ns()
     values = {k: (int(v) if np.issubdtype(v.dtype, np.integer) else float(v))
               for k, v in jax.device_get(stats).items()}
+    telemetry.trace.complete("fit/step_stats", start, step=step, **values)
     observe_step_stats(values)
-    now = time.perf_counter_ns()
-    telemetry.trace.complete("fit/step_stats", now, end_ns=now, step=step,
-                             **values)
 
 
 def _dispatch_step(train_step, params, opt_state, scale_state, xb, yb, wb, *,

@@ -67,7 +67,11 @@ def enabled() -> bool:
 
 
 def enable():
-    _state.enabled = True
+    if not _state.enabled:
+        _state.enabled = True
+        # the ring's clock against the Unix clock, from the first moment a
+        # span can be recorded (tracer.py, "Two clocks, two routes")
+        trace.anchor()
 
 
 def disable():

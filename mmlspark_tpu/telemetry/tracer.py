@@ -20,14 +20,28 @@ makes the span cover real device work at the cost of draining the dispatch
 queue (only ever paid when telemetry is enabled; a disabled span is a no-op
 context manager and never touches jax).
 
-Two clocks, one mechanism: an enabled span also opens a
-``jax.profiler.TraceAnnotation`` of the same name and attributes around its
-body, so while a profiler session runs (``jax.profiler.start_trace`` with
-``host_tracer_level >= 1``) every span lands in the capture's host plane,
-on the clock of the device planes' ``XLA Modules`` line — the program's
-side of a step beside the device's. With no session running the annotation
-costs one atomic load. ``instant()`` and ``complete()`` record after the
-fact; an annotation cannot be back-dated, so they stay ring-only.
+Two clocks, two routes onto the profiler's. (1) An enabled span also opens
+a ``jax.profiler.TraceAnnotation`` of the same name and attributes around
+its body, so while a profiler session runs (``jax.profiler.start_trace``
+with ``host_tracer_level >= 1``) every span lands in the capture's host
+plane, on the clock of the device planes' ``XLA Modules`` line — the
+program's side of a step beside the device's, for an operator's capture.
+With no session running the annotation costs one atomic load.
+``instant()`` and ``complete()`` record after the fact; an annotation cannot
+be back-dated, so they stay ring-only. (2) A capture at
+``host_tracer_level`` 0 keeps no annotation and is the one to take of a
+loop of many short steps, for its size (3 s of the ResNet stream: 224 MB at
+level 1, 20 MB at level 0; a loop of two long steps a second pays nothing,
+34.5 against 34.2 MB; the slow steps that follow ``stop_trace`` follow it at
+either level: PERF.md section 6, PR 36), so the ring carries its own way
+across: ``clock/anchor`` events, each one
+reading of ``time.time_ns()`` bracketed by two of ``time.perf_counter_ns()``
+(``perf_ns`` their midpoint, ``slack_ns`` half the bracket), taken when
+telemetry is enabled and then by the first event recorded more than a
+second after the newest. A capture's plane ``Task Environment`` gives its
+``profile_start_time`` in Unix nanoseconds and its device events count
+from there, so ``to_unix_ns(ts)`` less that start is a ring timestamp on
+the capture's clock (``benchmark/host_timeline.py`` reads both).
 
 Export is JSON-lines — one event object per line — which Perfetto loads
 directly; for legacy chrome://tracing pass ``array=True`` to wrap the same
@@ -66,6 +80,19 @@ _m_tail_dropped = REGISTRY.counter(
 #: set by telemetry.flight when the flight recorder is armed; every
 #: recorded event is forwarded (one None-check when disarmed)
 _flight_hook = None
+
+#: the event that ties the ring's clock to the Unix clock, and how old the
+#: newest may grow before `_record` takes another
+ANCHOR_EVENT = "clock/anchor"
+ANCHOR_EVERY_NS = 1_000_000_000
+
+
+def _mark(span: str, ts_ns: int, **numbers) -> dict:
+    """An instant event at ``ts_ns`` whose attributes stay the numbers they
+    are (`Tracer.instant` makes strings of them)."""
+    return {"name": span, "ph": "i", "ts": ts_ns // 1000, "s": "p",
+            "pid": os.getpid(), "tid": threading.get_ident(),
+            "args": numbers}
 
 
 class _NoopSpan:
@@ -218,6 +245,8 @@ class Tracer:
         self._lock = threading.Lock()
         self._dropped = 0   # guarded-by: _lock
         self._tail = None   # guarded-by: _lock (a _TailState when armed)
+        #: `perf_ns` of the newest `clock/anchor`; none yet, so due
+        self._anchored_ns = -ANCHOR_EVERY_NS   # guarded-by: _lock
 
     def span(self, name: str, sync=None, **attrs):
         """Context manager timing its body as one Chrome-trace event.
@@ -275,8 +304,56 @@ class Tracer:
         self._record(ev)
         return ctx
 
+    def anchor(self):
+        """Record a `clock/anchor` now, whatever the newest one's age (the
+        enable switch does): a no-op with telemetry off."""
+        if not _state.enabled:
+            return
+        with self._lock:
+            self._anchor()
+
+    def _anchor(self):   # requires-lock: _lock
+        """One reading of the Unix clock between two of the ring's, onto
+        the ring. The name is spelled out where it is recorded, as every
+        span's is (graftlint's span catalogue reads the call)."""
+        before = time.perf_counter_ns()
+        unix = time.time_ns()
+        after = time.perf_counter_ns()
+        perf = (before + after) // 2
+        self._anchored_ns = perf
+        self._append(_mark(span="clock/anchor", ts_ns=perf, perf_ns=perf,
+                           unix_ns=unix,
+                           slack_ns=(after - before + 1) // 2))
+
+    def anchors(self) -> list[dict]:
+        """The ring's clock anchors, oldest first: `perf_ns`, `unix_ns` and
+        `slack_ns` of each, as whole numbers."""
+        with self._lock:
+            return [e["args"] for e in self._events
+                    if e["name"] == ANCHOR_EVENT]
+
+    def to_unix_ns(self, ts_us):
+        """A ring timestamp (`ts`, microseconds of `perf_counter_ns`) in
+        Unix nanoseconds through the anchor nearest to it; None on a ring
+        without one."""
+        perf = int(ts_us) * 1000
+        near = min(self.anchors(), key=lambda a: abs(a["perf_ns"] - perf),
+                   default=None)
+        return None if near is None else near["unix_ns"] + perf - near[
+            "perf_ns"]
+
+    def _append(self, ev: dict):   # requires-lock: _lock
+        if (self._events.maxlen is not None
+                and len(self._events) == self._events.maxlen):
+            self._dropped += 1
+            _m_dropped.inc()
+        self._events.append(ev)
+
     def _record(self, ev: dict):
         with self._lock:
+            if (time.perf_counter_ns() - self._anchored_ns
+                    > ANCHOR_EVERY_NS):
+                self._anchor()
             tail = self._tail
             if tail is not None:
                 tid = (ev.get("args") or {}).get("trace_id")
@@ -285,11 +362,7 @@ class Tracer:
                     if _flight_hook is not None:
                         _flight_hook(ev)
                     return
-            if (self._events.maxlen is not None
-                    and len(self._events) == self._events.maxlen):
-                self._dropped += 1
-                _m_dropped.inc()
-            self._events.append(ev)
+            self._append(ev)
         if _flight_hook is not None:
             _flight_hook(ev)
 
@@ -436,6 +509,8 @@ class Tracer:
         with self._lock:
             self._events.clear()
             self._dropped = 0
+            # the anchors went with the rest: the next event brings one
+            self._anchored_ns = -ANCHOR_EVERY_NS
             tail = self._tail
             if tail is not None:
                 tail.pending.clear()

@@ -16,6 +16,7 @@ from mmlspark_tpu.core.dataframe import DataFrame
 from mmlspark_tpu.core.utils import object_column
 from mmlspark_tpu.models import trainer as tr
 from mmlspark_tpu.models.trainer import TpuLearner
+from mmlspark_tpu.telemetry.tracer import ANCHOR_EVENT
 
 ROWS, BATCH, EPOCHS = 96, 32, 2
 STEPS = ROWS // BATCH * EPOCHS
@@ -73,6 +74,12 @@ def fit(path):
 def spans(name):
     return [e for e in telemetry.trace.events()
             if e["name"] == name and e["ph"] == "X"]
+
+
+def recorded_names():
+    """The ring's events in order, less its `clock/anchor`s."""
+    return [e["name"] for e in telemetry.trace.events()
+            if e["name"] != ANCHOR_EVENT]
 
 
 def attr(events, key):
@@ -169,6 +176,70 @@ def test_telemetry_off_records_nothing_and_keeps_no_loss(
     assert flights == []
 
 
+@pytest.mark.parametrize("path", PATHS)
+def test_telemetry_off_reaches_no_record_and_takes_no_anchor(
+        quiet, monkeypatch, path):
+    """Off, every span is the one shared no-op, `_record` is never reached
+    and so no `clock/anchor` is taken: nothing new executes."""
+    from mmlspark_tpu.telemetry import tracer
+    reached, opened = [], []
+    monkeypatch.setattr(tracer.Tracer, "_record",
+                        lambda self, ev: reached.append(ev))
+    real = tracer.Tracer.span
+
+    def span(self, name, **kw):
+        opened.append(real(self, name, **kw))
+        return opened[-1]
+
+    monkeypatch.setattr(tracer.Tracer, "span", span)
+    fit(path)
+    assert reached == [] and telemetry.trace.anchors() == []
+    assert len(opened) >= 3 * STEPS
+    assert all(sp is tracer._NOOP_SPAN for sp in opened)
+
+
+EXPERTS = {"type": "kimi_linear", "vocab_size": 64, "hidden_size": 32,
+           "num_hidden_layers": 2, "first_k_dense_replace": 1,
+           "num_attention_heads": 2,
+           "linear_attn_config": {"kda_layers": [1], "full_attn_layers": [2],
+                                  "head_dim": 8, "num_heads": 2,
+                                  "short_conv_kernel_size": 4},
+           "kv_lora_rank": 16, "qk_nope_head_dim": 8, "qk_rope_head_dim": 4,
+           "v_head_dim": 8, "intermediate_size": 48,
+           "moe_intermediate_size": 16, "num_experts": 4, "router_width": 16,
+           "first_expert_held": 0, "num_experts_per_token": 4,
+           "num_shared_experts": 1, "moe_renormalize": True,
+           "routed_scaling_factor": 2.446, "rms_norm_eps": 1e-5,
+           "kda_chunk_size": 8, "num_classes": 2, "pool": "mean"}
+
+
+def test_step_stats_spans_the_read_of_a_finished_steps_counts(tel):
+    """An expert model's per-step counts come off the device in the loop's
+    thread, between `fit/feed_wait` and `fit/dispatch`: `fit/step_stats` has
+    that read's duration, the step's number and the counts as before, and
+    lies outside every `fit/dispatch`."""
+    rng = np.random.default_rng(2)
+    batches = [(rng.integers(0, 64, (8, 16)).astype(np.int32),
+                rng.integers(0, 2, (8,)).astype(np.int32)) for _ in range(4)]
+    (TpuLearner().setModelConfig(EXPERTS).setBatchSize(8).setEpochs(1)
+     .setLearningRate(1e-3).setLoss("cross_entropy").setSeed(3)
+     .fitStream(lambda: iter(batches)))
+    stats = spans("fit/step_stats")
+    assert attr(stats, "step") == [0, 1, 2, 3]
+    assert all(e["dur"] > 0 for e in stats)
+    for e in stats:
+        assert {"moe_expert_tokens_max", "moe_tokens_routed",
+                "moe_tokens_dropped", "moe_tiles_needed",
+                "moe_tiles_walked"} <= set(e["args"])
+        assert all(type(v) is int for v in e["args"].values())
+    (loop_tid,) = {e["tid"] for e in spans("fit/dispatch")}
+    assert {e["tid"] for e in stats} == {loop_tid}
+    ends = lambda e: (e["ts"], e["ts"] + e["dur"])
+    for lo, hi in map(ends, stats):
+        assert not any(a < hi and lo < b
+                       for a, b in map(ends, spans("fit/dispatch")))
+
+
 class FakeAnnotation:
     log = []
 
@@ -200,8 +271,7 @@ def test_a_span_opens_and_closes_an_annotation_of_its_name(tel, annotations):
                            ("enter", "fit/inner", {}),
                            ("exit", "fit/inner", {}),
                            ("exit", "fit/outer", {"step": 3})]
-    assert [e["name"] for e in telemetry.trace.events()] == [
-        "fit/inner", "fit/outer"]
+    assert recorded_names() == ["fit/inner", "fit/outer"]
 
 
 def test_an_annotation_closes_when_the_body_raises(tel, annotations):
@@ -216,7 +286,7 @@ def test_a_discarded_span_records_no_event(tel, annotations):
     with telemetry.trace.span("fit/kept"):
         with telemetry.trace.span("fit/nothing_to_do") as sp:
             sp.discard()
-    assert [e["name"] for e in telemetry.trace.events()] == ["fit/kept"]
+    assert recorded_names() == ["fit/kept"]
     assert [a[:2] for a in annotations][1:3] == [
         ("enter", "fit/nothing_to_do"), ("exit", "fit/nothing_to_do")]
 
@@ -234,8 +304,7 @@ def test_instant_and_complete_stay_ring_only(tel, annotations):
     telemetry.trace.instant("fit/mark")
     telemetry.trace.complete("fit/late", time.perf_counter_ns())
     assert annotations == []
-    assert [e["name"] for e in telemetry.trace.events()] == [
-        "fit/mark", "fit/late"]
+    assert recorded_names() == ["fit/mark", "fit/late"]
 
 
 @pytest.mark.parametrize("path", PATHS)

@@ -13,6 +13,13 @@ import pytest
 
 from mmlspark_tpu import telemetry
 from mmlspark_tpu.telemetry import context
+from mmlspark_tpu.telemetry.tracer import ANCHOR_EVENT
+
+
+def recorded(tracer):
+    """The ring less its `clock/anchor` events (the first event recorded
+    brings one, and the first of every later second)."""
+    return [e for e in tracer.events() if e["name"] != ANCHOR_EVENT]
 
 
 @pytest.fixture
@@ -97,7 +104,7 @@ class TestSpanContext:
     def test_span_without_context_stays_plain(self, tel):
         with tel.trace.span("plain"):
             pass
-        (ev,) = tel.trace.events()
+        (ev,) = recorded(tel.trace)
         assert "trace_id" not in ev.get("args", {})
 
     def test_complete_records_explicit_duration_child(self, tel):
@@ -105,7 +112,7 @@ class TestSpanContext:
         t0 = time.perf_counter_ns()
         time.sleep(0.003)
         tel.trace.complete("hop", t0, parent=ctx.to_traceparent(), code=200)
-        (ev,) = tel.trace.events()
+        (ev,) = recorded(tel.trace)
         assert ev["ph"] == "X" and ev["dur"] >= 2000
         assert ev["args"]["parent_span_id"] == ctx.span_id
         assert ev["args"]["code"] == 200
@@ -127,13 +134,14 @@ class TestMergeTraces:
         tel.trace.export_chrome_trace(p2, array=True)   # both forms load
         merged = telemetry.merge_traces([p1, p2],
                                         str(tmp_path / "merged.jsonl"))
-        assert {e["name"] for e in merged} == {"a", "unrelated", "b"}
+        assert {e["name"] for e in merged} == {"a", "unrelated", "b",
+                                               ANCHOR_EVENT}
         only = telemetry.merge_traces([p1, p2], trace_id=ctx.trace_id)
         assert {e["name"] for e in only} == {"a", "b"}
         # merged file is valid JSONL
         lines = [json.loads(line)
                  for line in open(tmp_path / "merged.jsonl")]
-        assert len(lines) == 3
+        assert len(lines) == 5     # a cleared ring anchors afresh
 
 
 # -------------------------------------------- server -> worker -> reply hop
@@ -187,8 +195,14 @@ class TestDistributedRequestTrace:
         try:
             code, _ = _post(src.url, "x")
             assert code == 200
-            reqs = [e for e in tel.trace.events()
-                    if e["name"] == "http/request"]
+            # the handler closes its span after the reply has gone out:
+            # wait for the event, as the fleet test above does
+            deadline = time.monotonic() + 2
+            reqs = []
+            while not reqs and time.monotonic() < deadline:
+                reqs = [e for e in tel.trace.events()
+                        if e["name"] == "http/request"]
+                time.sleep(0.02)
             assert reqs and "trace_id" in reqs[0]["args"]
         finally:
             loop.stop()
@@ -467,7 +481,7 @@ class TestExpositionCorrectness:
     def test_tracer_drop_counter_and_truncated_metadata(self, tel,
                                                         tmp_path):
         small = telemetry.Tracer(max_events=5)
-        for i in range(9):
+        for i in range(8):      # and the anchor the first of them brings
             with small.span("s", i=i):
                 pass
         assert small.dropped() == 4
@@ -487,6 +501,7 @@ class TestExpositionCorrectness:
         path2 = str(tmp_path / "ok.jsonl")
         ok.export_chrome_trace(path2)
         evs2 = [json.loads(line) for line in open(path2)]
+        assert [e["name"] for e in evs2] == [ANCHOR_EVENT, "fine"]
         assert all(e["ph"] != "M" for e in evs2)
         # clear resets the drop accounting
         small.clear()

@@ -371,14 +371,23 @@ class TestPipelineServingComposite:
         step.compile_buckets()
         want = step(_payloads(x[:5]))
         save_bundle(str(tmp_path), step)
+
+        def loads():
+            series = telemetry.snapshot().get(
+                "mmlspark_serving_bundle_loads_total", {"series": []})
+            return {s["labels"]["result"]: s["value"]
+                    for s in series["series"]}
+        before = loads()
         loaded = load_bundle(str(tmp_path))
         assert loaded.warm_buckets() == step.policy.buckets
         assert loaded.compiles() == 0
         assert loaded(_payloads(x[:5])) == want
         assert loaded.compiles() == 0            # first request was warm
-        snap = telemetry.snapshot()
-        series = snap["mmlspark_serving_bundle_loads_total"]["series"]
-        assert {s["labels"]["result"] for s in series} == {"warm"}
+        # the loads this test made, whatever series the worker's earlier
+        # test files left in the registry
+        after = loads()
+        assert {k: v - before.get(k, 0) for k, v in after.items()
+                if v != before.get(k, 0)} == {"warm": 1}
 
     def test_torn_exec_shard_degrades_to_cold_compile(self, tel, tmp_path):
         pm, x = _fit_serving_pipeline()
