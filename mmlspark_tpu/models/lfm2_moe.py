@@ -19,11 +19,11 @@ the published ``layer_types``, one entry a layer:
   and read at Hkv heads.
 
 Its MLP is a dense SwiGLU in the ``num_dense_layers`` leading layers and
-`moe.DroplessMoE` after them, with no shared expert and the family's 1e-6 in
-the renormalisation. The configuration's keys are those of the model's
-public `config.json`; the counts of heads, key/value heads, routed experts
-and vocabulary rows are what is held *here* (one chip's share of a layer),
-while `router_width` stays the deployment's expert count and ``head_dim``
+`moe.DroplessMoE` after them, with no shared expert, the family's 1e-6 in
+the renormalisation and its floor of tiles (`EXPERT_FLOOR_SHARES`). The
+configuration's keys are those of the model's public `config.json`; the
+counts of heads, key/value heads, routed experts and vocabulary rows are
+what is held *here* (one chip's share of a layer), while `router_width` stays the deployment's expert count and ``head_dim``
 the published hidden_size / num_attention_heads.
 
 Two results, as `joyai_llm_flash` has them. Without ``row_losses`` the model
@@ -43,6 +43,7 @@ and key/value cache, experts across chips with their all-to-all.
 from __future__ import annotations
 
 import functools
+from fractions import Fraction
 from typing import Any, Optional, Sequence
 
 import flax.linen as nn
@@ -62,6 +63,20 @@ LM_STEP_STATS = ("lm_loss_main", "lm_tokens_scored")
 
 #: the scope the conv mixers' gate, convolution and gate are traced under
 SHORT_CONV_SCOPE = "short_conv"
+
+#: the expert layers' floor of tiles, in uniform shares (`moe.DroplessMoE`'s
+#: `floor_shares`; the field's default, `moe.GROUP_FLOOR_SHARES` = 2, is set
+#: for routers of 256 outputs whose held experts swing 0.5-1.9 shares at a
+#: fresh init). This family's router of 32 outputs, top 4, hardly swings: at
+#: 32,768 tokens a step, 4,096 assignments a held expert, the fullest held
+#: expert read 4,161-4,952 (at most 1.21 shares) and a layer's need 33-40
+#: tiles of 1,024 over 12 seeds x 8 batches x 4 layers at the fresh init
+#: (`tools/count_tiles_needed.py`, TPU v5e; PERF.md section 6), 4,479-4,704
+#: over the cell's traced training windows. 5/4 shares, 5 tiles of 1,024 a
+#: held expert and 40 a layer, stand above every expert up to 5,120
+#: assignments, so the walk's length stays the same from step to step; the
+#: result does not depend on it
+EXPERT_FLOOR_SHARES = Fraction(5, 4)
 
 _m_conv_mixers = telemetry.registry.counter(
     "mmlspark_short_conv_mixers_total",
@@ -201,7 +216,8 @@ class Lfm2MoeModel(nn.Module):
     def _mlp(self, dense):
         mlp = block_mlp(self, dense)
         return mlp if dense else functools.partial(
-            mlp, renorm_eps=self.renorm_eps)
+            mlp, renorm_eps=self.renorm_eps,
+            floor_shares=EXPERT_FLOOR_SHARES)
 
     @nn.compact
     def __call__(self, tokens, output_layer: Optional[str] = None,

@@ -14,6 +14,7 @@ Routing/auxiliary math runs in float32; expert matmuls in bfloat16.
 from __future__ import annotations
 
 import functools
+from fractions import Fraction
 from typing import Any
 
 import flax.linen as nn
@@ -141,7 +142,8 @@ GROUP_SHARE_TILES = 4
 #: and the most rows of a tile: past 1,024 the same sweep gains 3% of the
 #: loops for twice a tile's float32 activations
 GROUP_TILE_MAX = 1024
-#: the tile walk's floor, in uniform shares: a layer walks at least the tiles
+#: the tile walk's floor, in uniform shares, where the family states none of
+#: its own (`DroplessMoE.floor_shares`): a layer walks at least the tiles
 #: that twice its share of evenly routed tokens would fill, so a step's time
 #: is the same whatever the routing until the load passes that. At a fresh
 #: init the held experts' load swings 0.5-1.9 shares by the seed (PERF.md
@@ -162,11 +164,14 @@ def tile_rows(share: int) -> int:
     return rows
 
 
-def floor_tiles(N: int, k: int, E: int, W: int, rows: int) -> int:
-    """The least tiles of `rows` a layer walks: what GROUP_FLOOR_SHARES
-    uniform shares of its E held experts fill, where N tokens take k of W
-    experts each."""
-    return -(-GROUP_FLOOR_SHARES * N * k * E // (W * rows))
+def floor_tiles(N: int, k: int, E: int, W: int, rows: int,
+                shares=GROUP_FLOOR_SHARES) -> int:
+    """The least tiles of `rows` a layer walks: what `shares` (a whole
+    number or a `Fraction`) uniform shares of its E held experts fill, where
+    N tokens take k of W experts each. Whole-number arithmetic throughout."""
+    shares = Fraction(shares)
+    return -(-shares.numerator * N * k * E
+             // (shares.denominator * W * rows))
 
 
 def _tiles(token, weight, counts, min_tiles, n_tokens, tile):
@@ -327,6 +332,11 @@ _m_tile_rows = telemetry.registry.gauge(
     "mmlspark_moe_tile_rows",
     "rows of a tile of the dropless expert layer last built (`tile_rows` of "
     "its static load: set at trace time)", labels=("layer",))
+_m_floor_tiles = telemetry.registry.gauge(
+    "mmlspark_moe_floor_tiles",
+    "the least tiles the walk of the dropless expert layer last built takes "
+    "(`floor_tiles` of its static load and floor: set at trace time)",
+    labels=("layer",))
 
 #: what a dropless expert layer reports a step, in this order
 MOE_STEP_STATS = ("moe_tokens_routed", "moe_expert_tokens_max",
@@ -382,6 +392,9 @@ class DroplessMoE(nn.Module):
     scores, renormalised to sum 1 where `renormalize`, times
     `routed_scale`; the renormalisation divides by the sum + `renorm_eps`,
     the family's own: 1e-20 where its source adds that, 1e-6 in `lfm2_moe`).
+    The tile walk takes at least `floor_tiles` of `floor_shares` uniform
+    shares, also the family's own: GROUP_FLOOR_SHARES where it states none,
+    5/4 in `lfm2_moe`.
     The layer holds the `num_experts` experts from
     `first_expert` on and computes their part of the result, for however
     many tokens chose them (`grouped_expert_mlp`); what the experts held
@@ -406,6 +419,7 @@ class DroplessMoE(nn.Module):
     routed_scale: float = 1.0
     dtype: Any = jnp.bfloat16
     renorm_eps: float = 1e-20
+    floor_shares: Any = GROUP_FLOOR_SHARES     # int or Fraction
 
     @nn.compact
     def __call__(self, x, row_mask=None):
@@ -449,8 +463,9 @@ class DroplessMoE(nn.Module):
                              ("expert_up", (E, d, self.d_hidden)),
                              ("expert_down", (E, self.d_hidden, d))))
         rows = tile_rows(N * k // W)
+        min_tiles = floor_tiles(N, k, E, W, rows, self.floor_shares)
         _m_tile_rows.labels(layer="/".join(self.path)).set(rows)
-        min_tiles = floor_tiles(N, k, E, W, rows)
+        _m_floor_tiles.labels(layer="/".join(self.path)).set(min_tiles)
         y, computed = grouped_expert_mlp(
             xf.astype(self.dtype), w_gate, w_up, w_down,
             (order // k).astype(jnp.int32), weight.reshape(-1)[order], counts,

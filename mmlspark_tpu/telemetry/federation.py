@@ -93,6 +93,7 @@ GAUGE_POLICIES = {
     "mmlspark_trainer_loss_scale": "last",
     "mmlspark_moe_expert_tokens_max": "max",
     "mmlspark_moe_tile_rows": "max",
+    "mmlspark_moe_floor_tiles": "max",
     "mmlspark_breaker_state": "max",
     "mmlspark_serving_pad_waste": "max",
     "mmlspark_graftlint_findings": "last",

@@ -395,9 +395,9 @@ def test_grouped_experts_floor_of_tiles_changes_nothing(counts, tile,
 
 
 @pytest.mark.parametrize("cell,want", [
-    ("kimilinear", (16384, 8, 256, 256)),
-    ("joyai", (32768, 8, 256, 256)),
-    ("lfm2moe", (32768, 4, 32, 1024)),
+    ("kimilinear", (16384, 8, 256, 256, 32)),
+    ("joyai", (32768, 8, 256, 256, 64)),
+    ("lfm2moe", (32768, 4, 32, 1024, 40)),
     # the layers this file, test_joyai_llm_flash and test_lfm2_moe build
     ((300, 4, 16), 256), ((40, 4, 16), 256), ((42, 4, 32), 256),
     ((128, 4, 16), 256), ((4 * 21, 4, 16), 256),
@@ -409,20 +409,56 @@ def test_tile_rows_follow_the_layers_static_load(cell, want, monkeypatch):
     """`moe.tile_rows` of a uniform share N * k // W: at least four tiles a
     share, 256 rows at the least and 1,024 at the most. The three expert
     cells' shapes, read from their benchmark files as the walk's timer reads
-    them, give 256 / 256 / 1,024."""
+    them, give 256 / 256 / 1,024 rows, and their models, built from the same
+    files as the benchmark builds them and traced at a step's batch (nothing
+    runs), walk at least 32 / 64 tiles of 256 (two uniform shares, the
+    default) and 40 of 1,024 (lfm2_moe's own 5/4) in every expert layer."""
     from mmlspark_tpu.models import moe
     if isinstance(cell, str):
         monkeypatch.syspath_prepend(os.path.join(ROOT, "tools"))
         import time_grouped_mlp
-        N, _, _, _, k, W = time_grouped_mlp.cell_shape(cell)
+        N, _, _, E, k, W = time_grouped_mlp.cell_shape(cell)
         assert (N, k, W) == want[:3]
-        want = want[3]
+        want, floor = want[3:]
+        shares = time_grouped_mlp.CELLS[cell][2]
+        assert moe.floor_tiles(N, k, E, W, want, shares) == floor
+        assert traced_floors(cell, monkeypatch) == {(want, floor)}
     else:
         N, k, W = cell
     rows = moe.tile_rows(N * k // W)
     assert rows == want
     assert rows == moe.GROUP_TILE or rows * moe.GROUP_SHARE_TILES <= N * k // W
     assert moe.GROUP_FLOOR_SHARES == 2
+
+
+def traced_floors(cell, monkeypatch):
+    """{(tile rows, floor tiles)} the expert layers of the cell's model ask
+    for when its init is traced at one step's batch, the model built from
+    the cell's configuration file as `train_stream.build_learner` builds it."""
+    import json
+    from benchmark.drivers import train_stream
+    from mmlspark_tpu.models import moe
+    import time_grouped_mlp
+    config, traffic, _ = time_grouped_mlp.CELLS[cell]
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           config + ".json")) as f:
+        config = json.load(f)
+    with open(os.path.join(ROOT, "benchmark", "traffic",
+                           traffic + ".json")) as f:
+        traffic = json.load(f)
+    module = build_model(dict(train_stream.build_learner(
+        config, traffic, 0).getModelConfig()))
+    seen, floor_tiles = [], moe.floor_tiles
+
+    def spy(N, k, E, W, rows, shares):
+        seen.append((rows, floor_tiles(N, k, E, W, rows, shares)))
+        return seen[-1][1]
+
+    monkeypatch.setattr(moe, "floor_tiles", spy)
+    jax.eval_shape(module.init, jax.random.PRNGKey(0), jax.ShapeDtypeStruct(
+        (traffic["batch_rows"], config["input"]["seq_len"]), jnp.int32))
+    assert len(seen) >= 4
+    return set(seen)
 
 
 @pytest.mark.parametrize("counts", [
@@ -486,8 +522,8 @@ def test_tiles_needed_and_walked_match_a_hand_count(routing, tile,
     and `moe_tiles_walked` that or the floor of two uniform shares, the
     greater, at the rows `tile_rows` gives the layer: 300 tokens, top 4 of
     16, 4 held make a share of 75 (256 rows a tile, a floor of 3 tiles; with
-    GROUP_TILE at 4, 16 rows and 38). `mmlspark_moe_tile_rows` says the rows;
-    nothing is dropped."""
+    GROUP_TILE at 4, 16 rows and 38). `mmlspark_moe_tile_rows` says the rows
+    and `mmlspark_moe_floor_tiles` the floor; nothing is dropped."""
     from mmlspark_tpu.models import moe
     monkeypatch.setattr(moe, "GROUP_TILE", tile)
     cfg = small_config()
@@ -507,9 +543,12 @@ def test_tiles_needed_and_walked_match_a_hand_count(routing, tile,
     telemetry.enable()
     try:
         _, stats = jax.jit(layer.apply)(p, x)
-        gauge, = [s for s in telemetry.snapshot()[
-            "mmlspark_moe_tile_rows"]["series"] if s["labels"]["layer"] == ""]
-        assert gauge["value"] == rows
+        snap = telemetry.snapshot()
+        gauge, least = ([s for s in snap[name]["series"]
+                         if s["labels"]["layer"] == ""]
+                        for name in ("mmlspark_moe_tile_rows",
+                                     "mmlspark_moe_floor_tiles"))
+        assert [gauge[0]["value"], least[0]["value"]] == [rows, floor]
     finally:
         (telemetry.enable if was else telemetry.disable)()
     # the routing by hand: the top 4 of sigmoid(x . router) + bias

@@ -427,6 +427,53 @@ def test_the_renormalisations_constant_is_the_familys():
     assert np.max(np.abs(as_it_was - y)) > 1e-3 * np.max(np.abs(as_it_was))
 
 
+@pytest.mark.parametrize("tile", [4, 8])
+def test_the_familys_floor_of_tiles_changes_nothing(tile, monkeypatch):
+    """The model's expert layer walks its family's floor, 5/4 uniform shares
+    (`EXPERT_FLOOR_SHARES`), and gives what the same layer at the default
+    two shares gives: the same result, the same count of assignments
+    computed and the same five gradients (input, router, the three expert
+    stacks), bit for bit, though the two walk different numbers of tiles.
+    Tiles of 4 or 8 rows, so that both floors stand above the routing's
+    need: 84 tokens, top 4 of 16 with 4 held, a share of 21, floors of 27
+    and 42 tiles of 4 (14 and 21 of 8)."""
+    from mmlspark_tpu.models import moe
+    monkeypatch.setattr(moe, "GROUP_TILE", tile)
+    own = build_model(small_config())._mlp(False)()
+    assert own.floor_shares == lf.EXPERT_FLOOR_SHARES == 1.25
+    default = own.clone(floor_shares=moe.GROUP_FLOOR_SHARES)
+    x = jax.random.normal(jax.random.PRNGKey(17), (2, 42, 32), F32)
+    ct = jax.random.normal(jax.random.PRNGKey(18), (2, 42, 32), F32)
+    p = own.init(jax.random.PRNGKey(19), x)
+
+    def run(layer):
+        def loss(p, x):
+            y, stats = layer.apply(p, x)
+            return jnp.sum(y * ct), (y, stats)
+        (_, (y, stats)), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True))(p, x)
+        return y, [int(s) for s in stats], grads
+
+    y, stats, grads = run(own)
+    y2, stats2, grads2 = run(default)
+    N, k, E, W = 84, 4, 4, 16
+    rows = moe.tile_rows(N * k // W)
+    assert rows == tile
+    floors = {4: (27, 42), 8: (14, 21)}[tile]
+    assert floors == tuple(moe.floor_tiles(N, k, E, W, rows, s) for s in
+                           (lf.EXPERT_FLOOR_SHARES, moe.GROUP_FLOOR_SHARES))
+    # routed, fullest, dropped and needed the same; walked the two floors
+    assert stats[:4] == stats2[:4] and stats[2] == 0
+    assert stats[3] <= floors[0] and (stats[4], stats2[4]) == floors
+    close(y, y2, 0)
+
+    def five(g):
+        return [g[1]] + [g[0]["params"][n] for n in
+                         ("router", "expert_gate", "expert_up", "expert_down")]
+    for a, b in zip(five(grads), five(grads2)):
+        assert np.any(np.asarray(a)) and np.array_equal(a, b)
+
+
 def test_the_slices_logits_are_the_slice_of_the_whole_vocabularys():
     """A chip that holds rows 0-15 of a 64-row embedding (which is the head
     too), on ids drawn from its slice, gives the first 16 of the 64 logits
@@ -819,13 +866,16 @@ def test_the_models_step_counts_carry_the_tiles():
 
 
 @pytest.mark.parametrize("argv,want", [
-    ([], ((32768, 2048, 1792, 8, 4, 32), (256, 512, 1024, 2048), 0)),
+    ([], ((32768, 2048, 1792, 8, 4, 32), lf.EXPERT_FLOOR_SHARES,
+          (256, 512, 1024, 2048), 0)),
     (["--cell", "kimilinear", "--rows", "256"],
-     ((16384, 2304, 1024, 8, 8, 256), (256,), 0)),
+     ((16384, 2304, 1024, 8, 8, 256), 2, (256,), 0)),
     (["--cell", "joyai", "--ops", "5"],
-     ((32768, 2048, 768, 8, 8, 256), (256, 512, 1024, 2048), 5)),
+     ((32768, 2048, 768, 8, 8, 256), 2, (256, 512, 1024, 2048), 5)),
     (["--shape", "64,16,8,3,2,6", "--rows", "8,16"],
-     ((64, 16, 8, 3, 2, 6), (8, 16), 0)),
+     ((64, 16, 8, 3, 2, 6), lf.EXPERT_FLOOR_SHARES, (8, 16), 0)),
+    (["--cell", "joyai", "--shape", "64,16,8,3,2,6", "--rows", "8,16"],
+     ((64, 16, 8, 3, 2, 6), 2, (8, 16), 0)),
     (["--shape", "64,16,8,7,2,6"], SystemExit),       # more held than routed
     (["--shape", "64,16,8"], SystemExit),
     (["--rows", "100"], SystemExit),
@@ -833,18 +883,20 @@ def test_the_models_step_counts_carry_the_tiles():
 ])
 def test_the_walks_timer_reads_its_arguments(argv, want, monkeypatch, capsys):
     """`tools/time_grouped_mlp.py` on the CPU: a cell's shape comes from its
-    two benchmark files, a shape or tile sizes that cannot be are refused,
-    the seeded routing's lists are `DroplessMoE`'s (every assignment to a
-    held expert once, sorted), its two programs give the same numbers at two
-    tile sizes, and it times nothing where there is no chip."""
+    two benchmark files and its floor from its family (lfm2moe's 5/4
+    uniform shares, the others' two; `--shape` keeps the cell's floor), a
+    shape or tile sizes that cannot be are refused, the seeded routing's
+    lists are `DroplessMoE`'s (every assignment to a held expert once,
+    sorted), its two programs give the same numbers at two tile sizes and
+    walk the floor's tiles, and it times nothing where there is no chip."""
     monkeypatch.syspath_prepend(os.path.join(ROOT, "tools"))
     import time_grouped_mlp as timer
     if want is SystemExit:
         with pytest.raises(SystemExit):
             timer.parse(argv)
         return
-    shape, sizes, ops = timer.parse(argv)
-    assert (shape, sizes, ops) == want
+    shape, shares, sizes, ops = timer.parse(argv)
+    assert (shape, shares, sizes, ops) == want
     if shape[0] > 64:
         return
     N, d, f, E, k, W = shape
@@ -854,8 +906,8 @@ def test_the_walks_timer_reads_its_arguments(argv, want, monkeypatch, capsys):
     assert np.bincount(token[:counts.sum()], minlength=N).max() <= E
     outs = []
     for rows in sizes:
-        fns, floor = timer.programs(shape, rows)
-        assert floor == -(-2 * N * k * E // (W * rows))
+        fns, floor = timer.programs(shape, shares, rows)
+        assert floor == -(-shares * N * k * E // (W * rows))
         outs.append(jax.tree_util.tree_leaves(
             (fns["fwd"](*data), fns["fwd_bwd"](*data))))
     for a, b in zip(*outs):
@@ -863,3 +915,54 @@ def test_the_walks_timer_reads_its_arguments(argv, want, monkeypatch, capsys):
     monkeypatch.setattr(sys, "argv", ["time_grouped_mlp.py"] + argv)
     with pytest.raises(SystemExit, match="needs a TPU"):
         timer.main()
+
+
+def test_the_tile_counter_reads_every_expert_layer(monkeypatch):
+    """`tools/count_tiles_needed.py` on the CPU at a small size: for each
+    seed and each batch of its pool, the fullest expert and the tiles needed
+    of every expert layer at the seed's fresh init; their maximum and sum
+    are the model's own step counts for that batch, and the summary's
+    candidate floors are `moe.floor_tiles` of their shares. It refuses the
+    cell's own sizes where there is no chip."""
+    monkeypatch.syspath_prepend(os.path.join(ROOT, "tools"))
+    import count_tiles_needed as counter
+    from benchmark.drivers import train_stream
+    from mmlspark_tpu.models import moe
+    config = dict(small_config(), num_classes=2,
+                  input={"kind": "token_ids_int32", "seq_len": 21},
+                  learner={"optimizer": "adamw", "learningRate": 1e-3,
+                           "precision": "f32", "loss": "next_token"})
+    traffic = {"batch_rows": 4, "pool_batches": 2, "prefetch_depth": 1,
+               "label_rule": "sum_mod_classes"}
+    records = list(counter.layer_counts(config, traffic, [5, 6]))
+    assert [seed for seed, _ in records] == [5, 6]
+    for seed, batches in records:
+        assert len(batches) == 2
+        pool = train_stream.make_pool(config, traffic, seed)
+        learner = train_stream.build_learner(config, traffic, seed)
+        model = build_model(dict(learner.getModelConfig()))
+        p = model.init(jax.random.PRNGKey(learner.getSeed()),
+                       jnp.asarray(pool[0][0][:1]))
+        for (x, _), layers in zip(pool, batches):
+            assert [c[0] for c in layers] == ["block1/mlp", "block2/mlp"]
+            _, stats = model.apply(p, jnp.asarray(x), step_stats=True,
+                                   row_losses=True)
+            assert max(c[1] for c in layers) == int(
+                stats["moe_expert_tokens_max"])
+            assert sum(c[2] for c in layers) == int(stats["moe_tiles_needed"])
+    got = counter.summary(config, traffic, records)
+    assert (got["share"], got["rows"], got["layer_steps"]) == (21, 256, 8)
+    assert got["fullest"] == max(c[1] for _, b in records for bb in b
+                                 for c in bb)
+    assert {s: f["tiles"] for s, f in got["floors"].items()} == {
+        str(s): moe.floor_tiles(84, 4, 4, 16, 256, s)
+        for s in counter.FLOORS}
+    needed = [c[2] for _, b in records for bb in b for c in bb]
+    assert {s: f["layer_steps_past"] for s, f in got["floors"].items()} == {
+        s: sum(n > f["tiles"] for n in needed)
+        for s, f in got["floors"].items()}
+    assert got["floors"]["1"]["layer_steps_past"] > 0    # 4 tiles for 1
+    assert counter.parse(["--workload", "w", "--seeds", "3-5"])[1] == \
+        range(3, 6)
+    with pytest.raises(SystemExit, match="needs a TPU"):
+        counter.main(["--workload", "lfm2moe_train_stream", "--seeds", "1"])

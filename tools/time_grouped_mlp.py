@@ -8,9 +8,12 @@
 width, E experts held, k experts a token, W router outputs), over seeded
 uniform routing (every token takes k of the W experts; the assignments to
 the first E, sorted by expert, as `DroplessMoE` lists them) and under the
-floor of `GROUP_FLOOR_SHARES` uniform shares, once forward only and once
-forward + backward (the gradient of a weighted sum of the result in x, the
-three weight stacks and the combine weights). Each line is one traced
+cell's own floor of tiles (its family's uniform shares: `lfm2_moe`'s
+`EXPERT_FLOOR_SHARES` in lfm2moe, `moe.GROUP_FLOOR_SHARES` in the other two;
+`--shape` keeps `--cell`'s floor), so a loop walks what the cell's walks,
+once forward only and once forward + backward (the gradient of a weighted
+sum of the result in x, the three weight stacks and the combine weights).
+Each line is one traced
 program at one tile size (`--rows`; `shipped` marks what `moe.tile_rows`
 gives the shape): milliseconds a call of the whole program and of its tile
 loops, from the device trace's `XLA Ops` line, read with the benchmark's own
@@ -35,20 +38,23 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmark.layer_metrics import kda_core_ms
-from mmlspark_tpu.models import moe
+from mmlspark_tpu.models import lfm2_moe, moe
 from time_delta_rule import trace_of
 
-CELLS = {           # a manifest cell's configuration and traffic files
-    "kimilinear": ("kimi_linear_48b_a3b", "stream_b8_t2048_kimi"),
-    "joyai": ("joyai_llm_flash_48b_a3b", "stream_b8_t4096_joyai"),
-    "lfm2moe": ("lfm2_8b_a1b", "stream_b8_t4096_lfm2"),
+CELLS = {   # a manifest cell's configuration and traffic files, its floor
+    "kimilinear": ("kimi_linear_48b_a3b", "stream_b8_t2048_kimi",
+                   moe.GROUP_FLOOR_SHARES),
+    "joyai": ("joyai_llm_flash_48b_a3b", "stream_b8_t4096_joyai",
+              moe.GROUP_FLOOR_SHARES),
+    "lfm2moe": ("lfm2_8b_a1b", "stream_b8_t4096_lfm2",
+                lfm2_moe.EXPERT_FLOOR_SHARES),
 }
 ROWS = (256, 512, 1024, 2048)
 
 
 def cell_shape(name):
     """(N, d, f, E, k, W) of an expert layer of the cell `name`."""
-    config, traffic = CELLS[name]
+    config, traffic, _ = CELLS[name]
     with open(os.path.join(ROOT, "benchmark", "configs",
                            config + ".json")) as f:
         cfg = json.load(f)
@@ -62,7 +68,8 @@ def cell_shape(name):
 
 
 def parse(argv):
-    """(shape, tile sizes, ops) from the command line."""
+    """(shape, floor in uniform shares, tile sizes, ops) from the command
+    line."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--cell", choices=sorted(CELLS), default="lfm2moe")
     ap.add_argument("--shape", default="", help="N,d,f,E,k,W in --cell's "
@@ -79,7 +86,7 @@ def parse(argv):
     rows = tuple(int(r) for r in args.rows.split(",") if r)
     if not rows or min(rows) < 8 or any(r % 8 for r in rows):
         ap.error(f"--rows are multiples of 8: {args.rows}")
-    return shape, rows, args.ops
+    return shape, CELLS[args.cell][2], rows, args.ops
 
 
 def inputs(shape, seed=0):
@@ -100,11 +107,11 @@ def inputs(shape, seed=0):
             jnp.asarray(np.bincount(local, minlength=E + 1)[:E], jnp.int32))
 
 
-def programs(shape, rows):
+def programs(shape, shares, rows):
     """{pass: jitted program of `inputs`} at `rows` a tile, and the tiles a
-    call's loop walks at the least (the floor)."""
+    call's loop walks at the least (the floor of `shares` uniform shares)."""
     N, d, f, E, k, W = shape
-    floor = moe.floor_tiles(N, k, E, W, rows)
+    floor = moe.floor_tiles(N, k, E, W, rows, shares)
     ct = jnp.asarray(np.random.default_rng(1).normal(size=(N, d)),
                      jnp.float32)
 
@@ -139,7 +146,7 @@ def report(trace, shape, walked, ops=0, **tags):
 
 
 def main():
-    shape, sizes, ops = parse(sys.argv[1:])
+    shape, shares, sizes, ops = parse(sys.argv[1:])
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         sys.exit(f"needs a TPU, found {dev.platform}")
@@ -148,9 +155,10 @@ def main():
     counts = np.asarray(data[-1])
     print(json.dumps({"device": dev.device_kind, "shape": shape,
                       "share": N * k // W, "counts": counts.tolist(),
-                      "shipped": moe.tile_rows(N * k // W)}), flush=True)
+                      "shipped": moe.tile_rows(N * k // W),
+                      "floor_shares": str(shares)}), flush=True)
     for rows in sizes:
-        fns, floor = programs(shape, rows)
+        fns, floor = programs(shape, shares, rows)
         walked = max(floor, int(np.sum(-(-counts // rows))))
         for name, fn in fns.items():
             report(trace_of(fn, data), shape, walked, ops, rows=rows,
