@@ -31,7 +31,8 @@ Its MLP is a dense SwiGLU in the layers ``mlp_layer_types`` calls dense
 float32, the top ``num_experts_per_tok``, renormalised where
 ``norm_topk_prob`` and times ``moe_routed_scaling_factor``, plus one
 ungated shared expert of ``shared_expert_intermediate_size`` (which is
-``moe_intermediate_size``: the layer has one width for both). The
+``moe_intermediate_size``: the layer has one width for both), under the
+family's floor of tiles (`EXPERT_FLOOR_SHARES`). The
 configuration's keys are those of the model's public `config.json`; the
 counts of query heads (``num_attention_heads_per_layer``), key/value heads,
 routed experts and vocabulary rows are what is held *here* (one chip's
@@ -56,6 +57,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
+from fractions import Fraction
 from typing import Any, Optional, Sequence
 
 import flax.linen as nn
@@ -76,6 +78,20 @@ HEAD_GATE_SCOPE = "head_gate"
 
 #: the two kinds of attention layer the published ``layer_types`` name
 LAYER_TYPES = ("full_attention", "sliding_attention")
+
+#: the expert layers' floor of tiles, in uniform shares (`moe.DroplessMoE`'s
+#: `floor_shares`; the field's default, `moe.GROUP_FLOOR_SHARES` = 2, is set
+#: for sigmoid routers whose held experts swing 0.5-1.9 shares at a fresh
+#: init). This family's softmax top 10 of 256 fills its held experts more
+#: evenly: at 32,768 tokens a step, 1,280 assignments a held expert, the
+#: fullest held expert read 1,268-2,136 (at most 1.67 shares) but a layer's
+#: need only 39-50 tiles of 256 (3 of 384 layer-steps at 50, none past) over
+#: 12 seeds x 8 batches x 4 layers at the fresh init
+#: (`tools/count_tiles_needed.py`, TPU v5e; PERF.md section 6). 5/4 shares,
+#: 50 tiles of 256 a layer, is the least floor no layer-step read passed, so
+#: the walk's length stays the same from step to step; a step whose routing
+#: passes it walks the tiles it needs. The result does not depend on it
+EXPERT_FLOOR_SHARES = Fraction(5, 4)
 
 _m_head_gates = telemetry.registry.counter(
     "mmlspark_attn_head_gates_total",
@@ -270,7 +286,8 @@ class LagunaModel(nn.Module):
 
     def _mlp(self, dense):
         mlp = block_mlp(self, dense)
-        return mlp if dense else functools.partial(mlp, scoring="softmax")
+        return mlp if dense else functools.partial(
+            mlp, scoring="softmax", floor_shares=EXPERT_FLOOR_SHARES)
 
     @nn.compact
     def __call__(self, tokens, output_layer: Optional[str] = None,

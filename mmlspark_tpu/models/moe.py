@@ -397,7 +397,7 @@ class DroplessMoE(nn.Module):
     the family's own: 1e-20 where its source adds that, 1e-6 in `lfm2_moe`.
     The tile walk takes at least `floor_tiles` of `floor_shares` uniform
     shares, also the family's own: GROUP_FLOOR_SHARES where it states none,
-    5/4 in `lfm2_moe`.
+    5/4 in `lfm2_moe` and in `laguna`.
     The layer holds the `num_experts` experts from
     `first_expert` on and computes their part of the result, for however
     many tokens chose them (`grouped_expert_mlp`); what the experts held

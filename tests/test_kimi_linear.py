@@ -398,6 +398,7 @@ def test_grouped_experts_floor_of_tiles_changes_nothing(counts, tile,
     ("kimilinear", (16384, 8, 256, 256, 32)),
     ("joyai", (32768, 8, 256, 256, 64)),
     ("lfm2moe", (32768, 4, 32, 1024, 40)),
+    ("laguna", (32768, 10, 256, 256, 50)),
     # the layers this file, test_joyai_llm_flash and test_lfm2_moe build
     ((300, 4, 16), 256), ((40, 4, 16), 256), ((42, 4, 32), 256),
     ((128, 4, 16), 256), ((4 * 21, 4, 16), 256),
@@ -407,12 +408,13 @@ def test_grouped_experts_floor_of_tiles_changes_nothing(counts, tile,
 ])
 def test_tile_rows_follow_the_layers_static_load(cell, want, monkeypatch):
     """`moe.tile_rows` of a uniform share N * k // W: at least four tiles a
-    share, 256 rows at the least and 1,024 at the most. The three expert
+    share, 256 rows at the least and 1,024 at the most. The four expert
     cells' shapes, read from their benchmark files as the walk's timer reads
-    them, give 256 / 256 / 1,024 rows, and their models, built from the same
-    files as the benchmark builds them and traced at a step's batch (nothing
-    runs), walk at least 32 / 64 tiles of 256 (two uniform shares, the
-    default) and 40 of 1,024 (lfm2_moe's own 5/4) in every expert layer."""
+    them, give 256 / 256 / 1,024 / 256 rows, and their models, built from
+    the same files as the benchmark builds them and traced at a step's batch
+    (nothing runs), walk at least 32 / 64 tiles of 256 (two uniform shares,
+    the default), 40 of 1,024 (lfm2_moe's own 5/4) and 50 of 256 (laguna's
+    own 5/4) in every expert layer."""
     from mmlspark_tpu.models import moe
     if isinstance(cell, str):
         monkeypatch.syspath_prepend(os.path.join(ROOT, "tools"))

@@ -248,6 +248,54 @@ def test_softmax_scoring_against_a_plain_top_k():
         layer.clone(scoring="sparsemax").init(jax.random.PRNGKey(4), x)
 
 
+@pytest.mark.parametrize("tile", [4, 8])
+def test_the_familys_floor_of_tiles_changes_nothing(tile, monkeypatch):
+    """The model's expert layer (softmax scoring, one shared expert) walks
+    its family's floor, 5/4 uniform shares (`EXPERT_FLOOR_SHARES`), and
+    gives what the same layer at the default two shares gives: the same
+    result, the same count of assignments computed and the same five
+    gradients (input, router, the three expert stacks), bit for bit, though
+    the two walk different numbers of tiles. Tiles of 4 or 8 rows, so that
+    both floors stand above the routing's need: 84 tokens, top 4 of 16 with
+    4 held, a share of 21, floors of 27 and 42 tiles of 4 (14 and 21 of 8)."""
+    from mmlspark_tpu.models import moe
+    monkeypatch.setattr(moe, "GROUP_TILE", tile)
+    own = build_model(small_config())._mlp(False)()
+    assert own.floor_shares == lg.EXPERT_FLOOR_SHARES == 1.25
+    assert own.scoring == "softmax" and own.num_shared == 1
+    default = own.clone(floor_shares=moe.GROUP_FLOOR_SHARES)
+    x = jax.random.normal(jax.random.PRNGKey(17), (2, 42, 32), F32)
+    ct = jax.random.normal(jax.random.PRNGKey(18), (2, 42, 32), F32)
+    p = own.init(jax.random.PRNGKey(19), x)
+
+    def run(layer):
+        def loss(p, x):
+            y, stats = layer.apply(p, x)
+            return jnp.sum(y * ct), (y, stats)
+        (_, (y, stats)), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True))(p, x)
+        return y, [int(s) for s in stats], grads
+
+    y, stats, grads = run(own)
+    y2, stats2, grads2 = run(default)
+    N, k, E, W = 84, 4, 4, 16
+    rows = moe.tile_rows(N * k // W)
+    assert rows == tile
+    floors = {4: (27, 42), 8: (14, 21)}[tile]
+    assert floors == tuple(moe.floor_tiles(N, k, E, W, rows, s) for s in
+                           (lg.EXPERT_FLOOR_SHARES, moe.GROUP_FLOOR_SHARES))
+    # routed, fullest, dropped and needed the same; walked the two floors
+    assert stats[:4] == stats2[:4] and stats[2] == 0
+    assert stats[3] <= floors[0] and (stats[4], stats2[4]) == floors
+    assert np.array_equal(y, y2)
+
+    def five(g):
+        return [g[1]] + [g[0]["params"][n] for n in
+                         ("router", "expert_gate", "expert_up", "expert_down")]
+    for a, b in zip(five(grads), five(grads2)):
+        assert np.any(np.asarray(a)) and np.array_equal(a, b)
+
+
 # ---------------------------------------------------------- the shares
 
 def test_shares_add_up_to_the_uncut_layer():

@@ -16,6 +16,7 @@ import functools
 import json
 import os
 import sys
+from fractions import Fraction
 
 import jax
 import jax.numpy as jnp
@@ -872,6 +873,8 @@ def test_the_models_step_counts_carry_the_tiles():
      ((16384, 2304, 1024, 8, 8, 256), 2, (256,), 0)),
     (["--cell", "joyai", "--ops", "5"],
      ((32768, 2048, 768, 8, 8, 256), 2, (256, 512, 1024, 2048), 5)),
+    (["--cell", "laguna", "--rows", "256"],
+     ((32768, 3072, 1024, 8, 10, 256), Fraction(5, 4), (256,), 0)),
     (["--shape", "64,16,8,3,2,6", "--rows", "8,16"],
      ((64, 16, 8, 3, 2, 6), lf.EXPERT_FLOOR_SHARES, (8, 16), 0)),
     (["--cell", "joyai", "--shape", "64,16,8,3,2,6", "--rows", "8,16"],
@@ -883,12 +886,13 @@ def test_the_models_step_counts_carry_the_tiles():
 ])
 def test_the_walks_timer_reads_its_arguments(argv, want, monkeypatch, capsys):
     """`tools/time_grouped_mlp.py` on the CPU: a cell's shape comes from its
-    two benchmark files and its floor from its family (lfm2moe's 5/4
-    uniform shares, the others' two; `--shape` keeps the cell's floor), a
-    shape or tile sizes that cannot be are refused, the seeded routing's
-    lists are `DroplessMoE`'s (every assignment to a held expert once,
-    sorted), its two programs give the same numbers at two tile sizes and
-    walk the floor's tiles, and it times nothing where there is no chip."""
+    two benchmark files and its floor from its family (lfm2moe's and
+    laguna's 5/4 uniform shares, the others' two; `--shape` keeps the
+    cell's floor), a shape or tile sizes that cannot be are refused, the
+    seeded routing's lists are `DroplessMoE`'s (every assignment to a held
+    expert once, sorted), its two programs give the same numbers at two tile
+    sizes and walk the floor's tiles, and it times nothing where there is no
+    chip."""
     monkeypatch.syspath_prepend(os.path.join(ROOT, "tools"))
     import time_grouped_mlp as timer
     if want is SystemExit:

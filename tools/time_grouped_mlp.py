@@ -8,12 +8,12 @@
 width, E experts held, k experts a token, W router outputs), over seeded
 uniform routing (every token takes k of the W experts; the assignments to
 the first E, sorted by expert, as `DroplessMoE` lists them) and under the
-cell's own floor of tiles (its family's uniform shares: `lfm2_moe`'s
-`EXPERT_FLOOR_SHARES` in lfm2moe, `moe.GROUP_FLOOR_SHARES` in the other two;
-`--shape` keeps `--cell`'s floor), so a loop walks what the cell's walks,
-once forward only and once forward + backward (the gradient of a weighted
-sum of the result in x, the three weight stacks and the combine weights).
-Each line is one traced
+cell's own floor of tiles (its family's uniform shares: the
+`EXPERT_FLOOR_SHARES` of `lfm2_moe` in lfm2moe and of `laguna` in laguna,
+`moe.GROUP_FLOOR_SHARES` in the other two; `--shape` keeps `--cell`'s
+floor), so a loop walks what the cell's walks, once forward only and once
+forward + backward (the gradient of a weighted sum of the result in x, the
+three weight stacks and the combine weights). Each line is one traced
 program at one tile size (`--rows`; `shipped` marks what `moe.tile_rows`
 gives the shape): milliseconds a call of the whole program and of its tile
 loops, from the device trace's `XLA Ops` line, read with the benchmark's own
@@ -38,7 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmark.layer_metrics import kda_core_ms
-from mmlspark_tpu.models import lfm2_moe, moe
+from mmlspark_tpu.models import laguna, lfm2_moe, moe
 from time_delta_rule import trace_of
 
 CELLS = {   # a manifest cell's configuration and traffic files, its floor
@@ -48,6 +48,8 @@ CELLS = {   # a manifest cell's configuration and traffic files, its floor
               moe.GROUP_FLOOR_SHARES),
     "lfm2moe": ("lfm2_8b_a1b", "stream_b8_t4096_lfm2",
                 lfm2_moe.EXPERT_FLOOR_SHARES),
+    "laguna": ("laguna_s_2p1", "stream_b8_t4096_laguna",
+               laguna.EXPERT_FLOOR_SHARES),
 }
 ROWS = (256, 512, 1024, 2048)
 
